@@ -2,7 +2,8 @@
 
 Public API:
   - PULConfig, IssueStrategy, MemoryTier, PEModel (pul.py)
-  - PreloadStream, UnloadStream, pul_loop, ring_scratch (pipeline.py)
+  - PreloadStream, UnloadStream, pul_loop, ring_scratch, interpret_mode
+    (pipeline.py)
   - DMAEngine, StreamStats, speedup (dma.py)
   - plan_stream, optimal_distance, predicted_speedup (planner.py)
 """
@@ -29,6 +30,7 @@ from repro.core.pul import (
 from repro.core.pipeline import (
     VMEM_BUDGET_BYTES,
     PreloadStream,
+    interpret_mode,
     UnloadStream,
     pul_loop,
     pul_streams,
@@ -60,6 +62,7 @@ __all__ = [
     "MICROBLAZE", "UPMEM_DPU", "TPU_V5E_VPU", "TPU_V5E_MXU",
     "TPU_LANE", "TPU_SUBLANE", "VMEM_BUDGET_BYTES",
     "PreloadStream", "UnloadStream", "pul_loop", "pul_streams", "ring_scratch",
+    "interpret_mode",
     "DMAEngine", "StreamStats", "speedup",
     "KVPageWorkload", "run_kv_page_workload", "kv_page_latency_hidden",
     "Plan", "plan_stream", "optimal_distance", "choose_block_rows",

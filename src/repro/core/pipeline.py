@@ -21,7 +21,8 @@ classes below package that into *streams*:
 
 Kernels in `repro.kernels` build on these; nothing here is kernel-specific.
 All of it runs under `interpret=True` on CPU (how this repo validates) and
-lowers to real TPU DMA ops on hardware.
+lowers to real TPU DMA ops on hardware; :func:`interpret_mode` is the one
+place that picks between the two.
 """
 from __future__ import annotations
 
@@ -38,6 +39,15 @@ from repro.core.pul import IssueStrategy, PULConfig
 # Default VMEM budget we allow a kernel's PUL rings to claim. v5e VMEM is
 # ~128 MiB; leave headroom for the compute body's operands and XLA spills.
 VMEM_BUDGET_BYTES = 96 * 2**20
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Resolve a kernel's `interpret` flag: compiled Mosaic on a TPU
+    backend, the Pallas interpreter on any other. An explicit bool wins, so
+    an AOT compile for a described TPU from a CPU process passes False."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
 
 
 def ring_scratch(cfg: PULConfig, block_shape: Sequence[int], dtype) -> Tuple:

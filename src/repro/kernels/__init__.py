@@ -1,7 +1,8 @@
 """PUL Pallas kernels: the paper's technique at TPU compute hot-spots.
 
-Each kernel pairs with a pure-jnp oracle in ref.py; ops.py exposes jit'd
-wrappers that interpret on CPU and lower to Mosaic on TPU.
+Each kernel pairs with a pure-jnp oracle in ref.py. Every kernel runs in
+the Pallas interpreter on CPU and lowers to Mosaic on TPU
+(`repro.core.interpret_mode`); ops.py exposes jit'd wrappers.
 """
 from repro.kernels import ref
 from repro.kernels.ops import (

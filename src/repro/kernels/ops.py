@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the PUL kernels.
 
-`interpret` auto-detects the backend: interpret=True on CPU (validation
-mode — the kernel body runs through the Pallas interpreter), False on real
-TPU (lowers to Mosaic with actual DMA engines).
+`interpret=None` leaves the choice to `repro.core.interpret_mode`: the
+Pallas interpreter on CPU (validation mode), Mosaic with the real DMA
+engines on TPU.
 """
 from __future__ import annotations
 
@@ -20,14 +20,9 @@ from repro.kernels.pul_attention import pul_attention
 from repro.kernels.pul_filter import pul_filter
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "rows_per_req", "interpret"))
 def sum_op(data, trace, *, cfg: PULConfig = PULConfig(),
            rows_per_req: int = 1, interpret: Optional[bool] = None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return pul_sum(data, trace, cfg=cfg, rows_per_req=rows_per_req,
                    interpret=interpret)
 
@@ -35,7 +30,6 @@ def sum_op(data, trace, *, cfg: PULConfig = PULConfig(),
 @functools.partial(jax.jit, static_argnames=("cfg", "rows_per_req", "interpret"))
 def gather_op(table, trace, *, cfg: PULConfig = PULConfig(),
               rows_per_req: int = 1, interpret: Optional[bool] = None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return pul_gather(table, trace, cfg=cfg, rows_per_req=rows_per_req,
                       interpret=interpret)
 
@@ -45,7 +39,6 @@ def gather_op(table, trace, *, cfg: PULConfig = PULConfig(),
 def matmul_op(a, b, *, cfg: PULConfig = PULConfig(), bm: int = 128,
               bk: int = 128, bn: int = 128, out_dtype=jnp.float32,
               interpret: Optional[bool] = None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return pul_matmul(a, b, cfg=cfg, bm=bm, bk=bk, bn=bn,
                       out_dtype=out_dtype, interpret=interpret)
 
@@ -58,7 +51,6 @@ def attention_op(q, k, v, *, cfg: PULConfig = PULConfig(), bt: int = 128,
                  softcap: Optional[float] = None,
                  window: Optional[int] = None,
                  interpret: Optional[bool] = None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return pul_attention(q, k, v, cfg=cfg, bt=bt, bs=bs, causal=causal,
                          scale=scale, softcap=softcap, window=window,
                          interpret=interpret)
@@ -69,6 +61,5 @@ def attention_op(q, k, v, *, cfg: PULConfig = PULConfig(), bt: int = 128,
 def filter_op(data, threshold: float, *, cfg: PULConfig = PULConfig(),
               rows_per_block: int = 128, materialize: bool = False,
               interpret: Optional[bool] = None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return pul_filter(data, threshold, cfg=cfg, rows_per_block=rows_per_block,
                       materialize=materialize, interpret=interpret)

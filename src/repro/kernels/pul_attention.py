@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, pul_loop, ring_scratch, interpret_mode)
 
 NEG_INF = -2.0e38
 
@@ -76,6 +77,26 @@ def _kernel(q_vmem, k_hbm, v_hbm, o_vmem, kbuf, ksems, vbuf, vsems,
     pul_loop(ns, [k_st, v_st], body, 0, cfg)
     out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
     o_vmem[0, 0] = out.astype(o_vmem.dtype)
+
+
+def _pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the minor dim of `x` to `width`. Page planes store their
+    features lane-padded (`serving.kv_pages.KVStoreLayout`); the zero lanes
+    add exact zeros to every dot product, so padding changes no result."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _merge_row(page_ref, row, offset):
+    """Overwrite row `offset` of a (P, W) VMEM page with `row` ((1, W)
+    float32). A whole-tile select: Mosaic neither slices one row out of a
+    packed bf16 tile nor DMAs one, so the fused commit rewrites the page."""
+    page = page_ref[...]
+    at = jax.lax.broadcasted_iota(jnp.int32, page.shape, 0) == offset
+    page_ref[...] = jnp.where(at, row, page.astype(jnp.float32)).astype(
+        page_ref.dtype)
 
 
 def _paged_decode_kernel(pt_smem, len_smem, q_vmem, *rest, cfg: PULConfig,
@@ -157,13 +178,13 @@ def pul_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                                window: Optional[int] = None,
                                k_new: Optional[jax.Array] = None,
                                v_new: Optional[jax.Array] = None,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: Optional[bool] = None) -> jax.Array:
     """Decode attention straight over a paged KV store (serving hot path).
 
-    q: (B, H, hd) one query token per slot; k_pages/v_pages: (NP, K, P, hd)
-    physical page frames (P tokens per page); page_tables: (B, n_pages)
-    int32 physical page id of each slot's logical page; lengths: (B,) valid
-    tokens per slot. Returns (B, H, hd).
+    q: (B, H, hd) one query token per slot; k_pages/v_pages: (NP, K, P, W)
+    physical page frames (P tokens per page, W >= hd lanes, zero beyond
+    hd); page_tables: (B, n_pages) int32 physical page id of each slot's
+    logical page; lengths: (B,) valid tokens per slot. Returns (B, H, hd).
 
     `window` bounds the visible range to the last `window` tokens relative to
     the incoming query at position `lengths[b]` (sliding-window layers).
@@ -176,7 +197,7 @@ def pul_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     page table — software paging *is* the trace-driven preload of the paper.
     """
     B, H, hd = q.shape
-    NP, K, P, _ = k_pages.shape
+    NP, K, P, W = k_pages.shape
     _, n_pages = page_tables.shape
     assert H % K == 0
     G = H // K
@@ -184,36 +205,37 @@ def pul_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     assert (v_new is not None) == has_new, "k_new/v_new come as a pair"
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-    qg = q.reshape(B, K, G, hd)
+    qg = _pad_lanes(q, W).reshape(B, K, G, W)
     kern = functools.partial(_paged_decode_kernel, cfg=cfg, P=P,
                              n_pages=n_pages, scale=scale, softcap=softcap,
                              window=window, has_new=has_new)
     new_specs, new_args = [], []
     if has_new:
-        new_specs = [pl.BlockSpec((1, 1, 1, hd), lambda b, h: (b, h, 0, 0)),
-                     pl.BlockSpec((1, 1, 1, hd), lambda b, h: (b, h, 0, 0))]
-        new_args = [k_new.reshape(B, K, 1, hd), v_new.reshape(B, K, 1, hd)]
+        new_specs = [pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0)),
+                     pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0))]
+        new_args = [_pad_lanes(k_new, W).reshape(B, K, 1, W),
+                    _pad_lanes(v_new, W).reshape(B, K, 1, W)]
     out = pl.pallas_call(
         kern,
         grid=(B, K),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, W), q.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
             *new_specs,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
         scratch_shapes=[
-            *ring_scratch(cfg, (1, 1, P, hd), k_pages.dtype),
-            *ring_scratch(cfg, (1, 1, P, hd), v_pages.dtype),
+            *ring_scratch(cfg, (1, 1, P, W), k_pages.dtype),
+            *ring_scratch(cfg, (1, 1, P, W), v_pages.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(page_tables.astype(jnp.int32), lengths, qg, *new_args,
       k_pages, v_pages)
-    return out.reshape(B, H, hd)
+    return out.reshape(B, H, W)[..., :hd]
 
 
 def _paged_mla_decode_kernel(pt_smem, len_smem, qa_vmem, qr_vmem, cnew_vmem,
@@ -230,13 +252,13 @@ def _paged_mla_decode_kernel(pt_smem, len_smem, qa_vmem, qr_vmem, cnew_vmem,
                          index_map=lambda t: (pt_smem[b, t], 0, 0),
                          cfg=cfg, n_blocks=n_pages)
 
-    qa = qa_vmem[0].astype(jnp.float32)                  # (H, kvr)
-    qr = qr_vmem[0].astype(jnp.float32)                  # (H, dr)
+    qa = qa_vmem[0].astype(jnp.float32)                  # (H, C)
+    qr = qr_vmem[0].astype(jnp.float32)                  # (H, R)
 
     def body(t, views, carry):
         m, l, acc = carry
-        ct = views[0][0].astype(jnp.float32)             # (P, kvr)
-        rt = views[1][0].astype(jnp.float32)             # (P, dr)
+        ct = views[0][0].astype(jnp.float32)             # (P, C)
+        rt = views[1][0].astype(jnp.float32)             # (P, R)
         logits = (jnp.dot(qa, ct.T, preferred_element_type=jnp.float32)
                   + jnp.dot(qr, rt.T, preferred_element_type=jnp.float32)
                   ) * scale                              # (H, P)
@@ -251,14 +273,14 @@ def _paged_mla_decode_kernel(pt_smem, len_smem, qa_vmem, qr_vmem, cnew_vmem,
         acc = acc * corr + jnp.dot(p, ct, preferred_element_type=jnp.float32)
         return new_m, l, acc
 
-    H, kvr = qa.shape
+    H, C = qa.shape
     init = (jnp.full((H, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, kvr), jnp.float32))
+            jnp.zeros((H, C), jnp.float32))
     m, l, acc = pul_loop(n_pages, [c_st, r_st], body, init, cfg)
     # current token's compressed KV, not yet paged
-    cn = cnew_vmem[0].astype(jnp.float32)                # (1, kvr)
-    rn = rnew_vmem[0].astype(jnp.float32)                # (1, dr)
+    cn = cnew_vmem[0].astype(jnp.float32)                # (1, C)
+    rn = rnew_vmem[0].astype(jnp.float32)                # (1, R)
     ls = (jnp.dot(qa, cn.T, preferred_element_type=jnp.float32)
           + jnp.dot(qr, rn.T, preferred_element_type=jnp.float32)) * scale
     new_m = jnp.maximum(m, ls)
@@ -275,59 +297,73 @@ def pul_paged_mla_decode_attention(q_abs: jax.Array, q_rope: jax.Array,
                                    c_new: jax.Array, r_new: jax.Array, *,
                                    scale: float,
                                    cfg: PULConfig = PULConfig(),
-                                   interpret: bool = True) -> jax.Array:
+                                   interpret: Optional[bool] = None) -> jax.Array:
     """Absorbed MLA decode attention straight over compressed-KV pages.
 
     q_abs: (B, H, kvr) queries absorbed into the compressed space; q_rope:
-    (B, H, dr) rope-carrying queries; ckv_pages: (NP, P, kvr) and kr_pages:
-    (NP, P, dr) physical page frames (one row per token — MLA's cache is
-    head-shared); page_tables: (B, n_pages); lengths: (B,) cached tokens per
-    slot; c_new/r_new: (B, kvr)/(B, dr) the current token's compressed KV.
-    Returns o_c (B, H, kvr) — the caller applies the absorbed v up-projection.
+    (B, H, dr) rope-carrying queries; ckv_pages: (NP, P, C) and kr_pages:
+    (NP, P, R) physical page frames (one row per token — MLA's cache is
+    head-shared; C >= kvr and R >= dr lanes, zero beyond); page_tables:
+    (B, n_pages); lengths: (B,) cached tokens per slot; c_new/r_new:
+    (B, kvr)/(B, dr) the current token's compressed KV. Returns o_c
+    (B, H, kvr) — the caller applies the absorbed v up-projection.
     """
     B, H, kvr = q_abs.shape
-    NP, P, _ = ckv_pages.shape
-    dr = q_rope.shape[-1]
+    NP, P, C = ckv_pages.shape
+    R = kr_pages.shape[-1]
     _, n_pages = page_tables.shape
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
     kern = functools.partial(_paged_mla_decode_kernel, cfg=cfg, P=P,
                              n_pages=n_pages, scale=scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=(B,),
-        out_shape=jax.ShapeDtypeStruct((B, H, kvr), q_abs.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, C), q_abs.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, H, kvr), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H, dr), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, kvr), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, dr), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, H, R), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, C), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, R), lambda b: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, kvr), lambda b: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
         scratch_shapes=[
-            *ring_scratch(cfg, (1, P, kvr), ckv_pages.dtype),
-            *ring_scratch(cfg, (1, P, dr), kr_pages.dtype),
+            *ring_scratch(cfg, (1, P, C), ckv_pages.dtype),
+            *ring_scratch(cfg, (1, P, R), kr_pages.dtype),
         ],
-        interpret=interpret,
-    )(page_tables.astype(jnp.int32), lengths, q_abs, q_rope,
-      c_new.reshape(B, 1, kvr), r_new.reshape(B, 1, dr),
-      ckv_pages, kr_pages)
+        interpret=interpret_mode(interpret),
+    )(page_tables.astype(jnp.int32), lengths, _pad_lanes(q_abs, C),
+      _pad_lanes(q_rope, R), _pad_lanes(c_new, C).reshape(B, 1, C),
+      _pad_lanes(r_new, R).reshape(B, 1, R), ckv_pages, kr_pages)
+    return out[..., :kvr]
 
 
 def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
                                layer_smem, q_vmem, knew_vmem, vnew_vmem,
                                k_hbm, v_hbm, o_vmem, kp_out, vp_out,
-                               kbuf, ksems, vbuf, vsems, wsem, *,
-                               cfg: PULConfig, P: int, n_pages: int,
+                               kbuf, ksems, vbuf, vsems, ktail, vtail, tsems,
+                               *, cfg: PULConfig, P: int, n_pages: int,
                                scale: float, softcap: Optional[float],
                                window: Optional[int]):
     b = pl.program_id(0)
     kv_h = pl.program_id(1)
     g = layer_smem[0]
     length = len_smem[b]
+
+    # fused commit, part 1: fetch the tail page (layer, frames[b], kv_h)
+    # the current token lands in; the fetch flies under the page stream.
+    # The epilogue merges the new row into it and writes the page back
+    # whole. Only this program writes that page (inactive slots all point
+    # at the pool's TRASH sink, which nothing reads).
+    def tail(ref):
+        return ref.at[g, frames_smem[b], kv_h]
+    k_fetch = pltpu.make_async_copy(tail(k_hbm), ktail, tsems.at[0])
+    v_fetch = pltpu.make_async_copy(tail(v_hbm), vtail, tsems.at[1])
+    k_fetch.start()
+    v_fetch.start()
 
     # same page-table-driven stream as the per-layer kernel, with the layer
     # scalar prepended: block t is plane row (g, pt[b, t], kv_h) — the sweep
@@ -340,7 +376,7 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
                          index_map=lambda t: (g, pt_smem[b, t], kv_h, 0, 0),
                          cfg=cfg, n_blocks=n_pages)
 
-    q = q_vmem[0, 0].astype(jnp.float32)                 # (G, hd)
+    q = q_vmem[0, 0].astype(jnp.float32)                 # (G, W)
 
     def _cap(logits):
         if softcap is not None:
@@ -349,7 +385,7 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
 
     def body(t, views, carry):
         m, l, acc = carry
-        kt = views[0][0, 0, 0].astype(jnp.float32)       # (P, hd)
+        kt = views[0][0, 0, 0].astype(jnp.float32)       # (P, W)
         vt = views[1][0, 0, 0].astype(jnp.float32)
         logits = _cap(
             jnp.dot(q, kt.T, preferred_element_type=jnp.float32) * scale)
@@ -366,15 +402,15 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
         acc = acc * corr + jnp.dot(p, vt, preferred_element_type=jnp.float32)
         return new_m, l, acc
 
-    G, hd = q.shape
+    G, W = q.shape
     init = (jnp.full((G, 1), NEG_INF, jnp.float32),
             jnp.zeros((G, 1), jnp.float32),
-            jnp.zeros((G, hd), jnp.float32))
+            jnp.zeros((G, W), jnp.float32))
     m, l, acc = pul_loop(n_pages, [k_st, v_st], body, init, cfg)
     # the current token (position `length`, not yet paged) is always
     # causally visible and always inside the window
-    kn = knew_vmem[0, 0, 0].astype(jnp.float32)          # (1, hd)
-    vn = vnew_vmem[0, 0, 0].astype(jnp.float32)
+    kn = knew_vmem[0, 0].astype(jnp.float32)             # (1, W)
+    vn = vnew_vmem[0, 0].astype(jnp.float32)
     ls = _cap(jnp.dot(q, kn.T, preferred_element_type=jnp.float32) * scale)
     new_m = jnp.maximum(m, ls)
     corr = jnp.exp(m - new_m)
@@ -383,24 +419,22 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     acc = acc * corr + jnp.dot(p, vn, preferred_element_type=jnp.float32)
     o_vmem[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_vmem.dtype)
 
-    # fused commit epilogue: write the current token's K/V row into its tail
-    # page at (layer, frame, kv_h, offset). The attention stream above only
-    # reads positions < length and this row IS position length, so the write
-    # can never race a read of itself; inactive slots' frames point at the
-    # pool's TRASH sink. The host side accounts/validates this commit via
-    # KVPagePool.note_fused_commit BEFORE the launch.
-    f = frames_smem[b]
-    o = offs_smem[b]
-    kdst = kp_out.at[pl.ds(g, 1), pl.ds(f, 1), pl.ds(kv_h, 1),
-                     pl.ds(o, 1), :]
-    vdst = vp_out.at[pl.ds(g, 1), pl.ds(f, 1), pl.ds(kv_h, 1),
-                     pl.ds(o, 1), :]
-    kcp = pltpu.make_async_copy(knew_vmem.at[...], kdst, wsem)
-    kcp.start()
-    kcp.wait()
-    vcp = pltpu.make_async_copy(vnew_vmem.at[...], vdst, wsem)
-    vcp.start()
-    vcp.wait()
+    # fused commit, part 2: the current token's K/V row lands at in-page
+    # row offsets[b] of the fetched tail page, which goes back whole. The
+    # stream above only read positions < length and this row IS position
+    # length, so the write can never race a read of itself. The host side
+    # accounts/validates this commit via KVPagePool.note_fused_commit
+    # BEFORE the launch.
+    k_fetch.wait()
+    v_fetch.wait()
+    _merge_row(ktail, kn, offs_smem[b])
+    _merge_row(vtail, vn, offs_smem[b])
+    k_put = pltpu.make_async_copy(ktail, tail(kp_out), tsems.at[0])
+    v_put = pltpu.make_async_copy(vtail, tail(vp_out), tsems.at[1])
+    k_put.start()
+    v_put.start()
+    k_put.wait()
+    v_put.wait()
 
 
 def pul_paged_sweep_decode_attention(
@@ -408,27 +442,28 @@ def pul_paged_sweep_decode_attention(
         page_tables: jax.Array, lengths, k_new: jax.Array, v_new: jax.Array,
         frames, offsets, *, cfg: PULConfig = PULConfig(),
         scale: Optional[float] = None, softcap: Optional[float] = None,
-        window: Optional[int] = None, interpret: bool = True):
+        window: Optional[int] = None, interpret: Optional[bool] = None):
     """One layer step of the single-sweep paged decode over per-layer planes.
 
     Reads layer `layer` of the full stacked planes and fuses the commit of
     the current token's K/V into the kernel epilogue — the in-kernel half of
     the `KVStoreLayout` commit contract.
 
-    q: (B, H, hd); k_planes/v_planes: (L, NF, K, P, hd) the ENTIRE per-layer
-    page store (never sliced on the host — the zero-copy point); layer: ()
-    int32 scalar (prefetched to SMEM; a scan-carried layer index); k_new /
-    v_new: (B, K, hd) the current token's K/V, merged into the online
-    softmax AND written to plane position (layer, frames[b], kv_h,
-    offsets[b]); frames/offsets: (B,) int32 tail-page frame and in-page row
-    per slot (TRASH frame for inactive slots — never the zero frame).
+    q: (B, H, hd); k_planes/v_planes: (L, NF, K, P, W) the ENTIRE per-layer
+    page store (never sliced on the host — the zero-copy point; W >= hd
+    lanes, zero beyond hd); layer: () int32 scalar (prefetched to SMEM; a
+    scan-carried layer index); k_new / v_new: (B, K, hd) the current
+    token's K/V, merged into the online softmax AND written to plane
+    position (layer, frames[b], kv_h, offsets[b]); frames/offsets: (B,)
+    int32 tail-page frame and in-page row per slot (TRASH frame for
+    inactive slots — never the zero frame).
 
     Returns (out (B, H, hd), k_planes, v_planes) where the plane outputs are
     input/output-aliased: XLA updates the store in place, the caller threads
     them forward (the engine donates them through the jitted step).
     """
     B, H, hd = q.shape
-    L, NF, K, P, _ = k_planes.shape
+    L, NF, K, P, W = k_planes.shape
     _, n_pages = page_tables.shape
     assert H % K == 0
     G = H // K
@@ -437,7 +472,7 @@ def pul_paged_sweep_decode_attention(
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     frames = jnp.asarray(frames, jnp.int32).reshape(B)
     offsets = jnp.asarray(offsets, jnp.int32).reshape(B)
-    qg = q.reshape(B, K, G, hd)
+    qg = _pad_lanes(q, W).reshape(B, K, G, W)
     kern = functools.partial(_paged_sweep_decode_kernel, cfg=cfg, P=P,
                              n_pages=n_pages, scale=scale, softcap=softcap,
                              window=window)
@@ -445,7 +480,7 @@ def pul_paged_sweep_decode_attention(
         kern,
         grid=(B, K),
         out_shape=[
-            jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, K, G, W), q.dtype),
             jax.ShapeDtypeStruct(k_planes.shape, k_planes.dtype),
             jax.ShapeDtypeStruct(v_planes.shape, v_planes.dtype),
         ],
@@ -455,15 +490,14 @@ def pul_paged_sweep_decode_attention(
             pl.BlockSpec(memory_space=pltpu.SMEM),   # commit frames
             pl.BlockSpec(memory_space=pltpu.SMEM),   # commit offsets
             pl.BlockSpec(memory_space=pltpu.SMEM),   # layer scalar
-            pl.BlockSpec((1, 1, G, hd), lambda b, h: (b, h, 0, 0)),
-            # new-token rows, rank-matched to the plane for the epilogue DMA
-            pl.BlockSpec((1, 1, 1, 1, hd), lambda b, h: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, hd), lambda b, h: (b, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -471,27 +505,38 @@ def pul_paged_sweep_decode_attention(
         # k_planes (8), v_planes (9) -> aliased to outputs 1 and 2
         input_output_aliases={8: 1, 9: 2},
         scratch_shapes=[
-            *ring_scratch(cfg, (1, 1, 1, P, hd), k_planes.dtype),
-            *ring_scratch(cfg, (1, 1, 1, P, hd), v_planes.dtype),
-            pltpu.SemaphoreType.DMA,
+            *ring_scratch(cfg, (1, 1, 1, P, W), k_planes.dtype),
+            *ring_scratch(cfg, (1, 1, 1, P, W), v_planes.dtype),
+            pltpu.VMEM((P, W), k_planes.dtype),     # tail pages of the
+            pltpu.VMEM((P, W), v_planes.dtype),     # fused commit
+            pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(page_tables.astype(jnp.int32), lengths, frames, offsets, layer, qg,
-      k_new.astype(k_planes.dtype).reshape(B, K, 1, 1, hd),
-      v_new.astype(v_planes.dtype).reshape(B, K, 1, 1, hd),
+      _pad_lanes(k_new.astype(k_planes.dtype), W).reshape(B, K, 1, W),
+      _pad_lanes(v_new.astype(v_planes.dtype), W).reshape(B, K, 1, W),
       k_planes, v_planes)
-    return out.reshape(B, H, hd), kp, vp
+    return out.reshape(B, H, W)[..., :hd], kp, vp
 
 
 def _paged_sweep_mla_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
                                    layer_smem, qa_vmem, qr_vmem, cnew_vmem,
                                    rnew_vmem, ckv_hbm, kr_hbm, o_vmem,
                                    cp_out, rp_out, cbuf, csems, rbuf, rsems,
-                                   wsem, *, cfg: PULConfig, P: int,
-                                   n_pages: int, scale: float):
+                                   ctail, rtail, tsems, *, cfg: PULConfig,
+                                   P: int, n_pages: int, scale: float):
     b = pl.program_id(0)
     g = layer_smem[0]
     length = len_smem[b]
+
+    # fused commit, part 1 (see _paged_sweep_decode_kernel): fetch the
+    # tail page (layer, frames[b]) of both planes under the page stream
+    def tail(ref):
+        return ref.at[g, frames_smem[b]]
+    c_fetch = pltpu.make_async_copy(tail(ckv_hbm), ctail, tsems.at[0])
+    r_fetch = pltpu.make_async_copy(tail(kr_hbm), rtail, tsems.at[1])
+    c_fetch.start()
+    r_fetch.start()
 
     c_st = PreloadStream(ckv_hbm, cbuf, csems,
                          index_map=lambda t: (g, pt_smem[b, t], 0, 0),
@@ -500,13 +545,13 @@ def _paged_sweep_mla_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
                          index_map=lambda t: (g, pt_smem[b, t], 0, 0),
                          cfg=cfg, n_blocks=n_pages)
 
-    qa = qa_vmem[0].astype(jnp.float32)                  # (H, kvr)
-    qr = qr_vmem[0].astype(jnp.float32)                  # (H, dr)
+    qa = qa_vmem[0].astype(jnp.float32)                  # (H, C)
+    qr = qr_vmem[0].astype(jnp.float32)                  # (H, R)
 
     def body(t, views, carry):
         m, l, acc = carry
-        ct = views[0][0, 0].astype(jnp.float32)          # (P, kvr)
-        rt = views[1][0, 0].astype(jnp.float32)          # (P, dr)
+        ct = views[0][0, 0].astype(jnp.float32)          # (P, C)
+        rt = views[1][0, 0].astype(jnp.float32)          # (P, R)
         logits = (jnp.dot(qa, ct.T, preferred_element_type=jnp.float32)
                   + jnp.dot(qr, rt.T, preferred_element_type=jnp.float32)
                   ) * scale
@@ -520,13 +565,13 @@ def _paged_sweep_mla_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
         acc = acc * corr + jnp.dot(p, ct, preferred_element_type=jnp.float32)
         return new_m, l, acc
 
-    H, kvr = qa.shape
+    H, C = qa.shape
     init = (jnp.full((H, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, kvr), jnp.float32))
+            jnp.zeros((H, C), jnp.float32))
     m, l, acc = pul_loop(n_pages, [c_st, r_st], body, init, cfg)
-    cn = cnew_vmem[0, 0].astype(jnp.float32)             # (1, kvr)
-    rn = rnew_vmem[0, 0].astype(jnp.float32)             # (1, dr)
+    cn = cnew_vmem[0].astype(jnp.float32)                # (1, C)
+    rn = rnew_vmem[0].astype(jnp.float32)                # (1, R)
     ls = (jnp.dot(qa, cn.T, preferred_element_type=jnp.float32)
           + jnp.dot(qr, rn.T, preferred_element_type=jnp.float32)) * scale
     new_m = jnp.maximum(m, ls)
@@ -536,37 +581,38 @@ def _paged_sweep_mla_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     acc = acc * corr + jnp.dot(p, cn, preferred_element_type=jnp.float32)
     o_vmem[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_vmem.dtype)
 
-    # fused commit epilogue (see _paged_sweep_decode_kernel): the current
-    # token's compressed KV lands at (layer, frame, offset) of both planes
-    f = frames_smem[b]
-    o = offs_smem[b]
-    cdst = cp_out.at[pl.ds(g, 1), pl.ds(f, 1), pl.ds(o, 1), :]
-    rdst = rp_out.at[pl.ds(g, 1), pl.ds(f, 1), pl.ds(o, 1), :]
-    ccp = pltpu.make_async_copy(cnew_vmem.at[...], cdst, wsem)
-    ccp.start()
-    ccp.wait()
-    rcp = pltpu.make_async_copy(rnew_vmem.at[...], rdst, wsem)
-    rcp.start()
-    rcp.wait()
+    # fused commit, part 2: the current token's compressed KV lands at row
+    # offsets[b] of both tail pages, which go back whole
+    c_fetch.wait()
+    r_fetch.wait()
+    _merge_row(ctail, cn, offs_smem[b])
+    _merge_row(rtail, rn, offs_smem[b])
+    c_put = pltpu.make_async_copy(ctail, tail(cp_out), tsems.at[0])
+    r_put = pltpu.make_async_copy(rtail, tail(rp_out), tsems.at[1])
+    c_put.start()
+    r_put.start()
+    c_put.wait()
+    r_put.wait()
 
 
 def pul_paged_sweep_mla_decode_attention(
         q_abs: jax.Array, q_rope: jax.Array, ckv_planes: jax.Array,
         kr_planes: jax.Array, layer, page_tables: jax.Array, lengths,
         c_new: jax.Array, r_new: jax.Array, frames, offsets, *, scale: float,
-        cfg: PULConfig = PULConfig(), interpret: bool = True):
+        cfg: PULConfig = PULConfig(), interpret: Optional[bool] = None):
     """Absorbed-MLA layer step of the single-sweep paged decode.
 
-    ckv_planes: (L, NF, P, kvr), kr_planes: (L, NF, P, dr) — the entire
-    per-layer compressed page store; `layer` selects the plane row in-kernel
-    via the prefetched SMEM scalar. c_new/r_new ((B, kvr)/(B, dr)) are merged
-    into the online softmax AND committed to (layer, frames[b], offsets[b])
-    in the fused epilogue. Returns (o_c (B, H, kvr), ckv_planes, kr_planes)
-    with the planes input/output-aliased for in-place update.
+    ckv_planes: (L, NF, P, C), kr_planes: (L, NF, P, R) — the entire
+    per-layer compressed page store (C >= kvr and R >= dr lanes, zero
+    beyond); `layer` selects the plane row in-kernel via the prefetched
+    SMEM scalar. c_new/r_new ((B, kvr)/(B, dr)) are merged into the online
+    softmax AND committed to (layer, frames[b], offsets[b]) in the fused
+    epilogue. Returns (o_c (B, H, kvr), ckv_planes, kr_planes) with the
+    planes input/output-aliased for in-place update.
     """
     B, H, kvr = q_abs.shape
-    L, NF, P, _ = ckv_planes.shape
-    dr = q_rope.shape[-1]
+    L, NF, P, C = ckv_planes.shape
+    R = kr_planes.shape[-1]
     _, n_pages = page_tables.shape
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
@@ -574,11 +620,11 @@ def pul_paged_sweep_mla_decode_attention(
     offsets = jnp.asarray(offsets, jnp.int32).reshape(B)
     kern = functools.partial(_paged_sweep_mla_decode_kernel, cfg=cfg, P=P,
                              n_pages=n_pages, scale=scale)
-    return pl.pallas_call(
+    out, cp, rp = pl.pallas_call(
         kern,
         grid=(B,),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, kvr), q_abs.dtype),
+            jax.ShapeDtypeStruct((B, H, C), q_abs.dtype),
             jax.ShapeDtypeStruct(ckv_planes.shape, ckv_planes.dtype),
             jax.ShapeDtypeStruct(kr_planes.shape, kr_planes.dtype),
         ],
@@ -588,16 +634,15 @@ def pul_paged_sweep_mla_decode_attention(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, H, kvr), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H, dr), lambda b: (b, 0, 0)),
-            # new-token rows, rank-matched to the planes for the epilogue DMA
-            pl.BlockSpec((1, 1, 1, kvr), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, dr), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, H, R), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, C), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, R), lambda b: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, kvr), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -605,16 +650,19 @@ def pul_paged_sweep_mla_decode_attention(
         # r_new, ckv_planes (9), kr_planes (10) -> aliased to outputs 1, 2
         input_output_aliases={9: 1, 10: 2},
         scratch_shapes=[
-            *ring_scratch(cfg, (1, 1, P, kvr), ckv_planes.dtype),
-            *ring_scratch(cfg, (1, 1, P, dr), kr_planes.dtype),
-            pltpu.SemaphoreType.DMA,
+            *ring_scratch(cfg, (1, 1, P, C), ckv_planes.dtype),
+            *ring_scratch(cfg, (1, 1, P, R), kr_planes.dtype),
+            pltpu.VMEM((P, C), ckv_planes.dtype),   # tail pages of the
+            pltpu.VMEM((P, R), kr_planes.dtype),    # fused commit
+            pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(page_tables.astype(jnp.int32), lengths, frames, offsets, layer,
-      q_abs, q_rope,
-      c_new.astype(ckv_planes.dtype).reshape(B, 1, 1, kvr),
-      r_new.astype(kr_planes.dtype).reshape(B, 1, 1, dr),
+      _pad_lanes(q_abs, C), _pad_lanes(q_rope, R),
+      _pad_lanes(c_new.astype(ckv_planes.dtype), C).reshape(B, 1, C),
+      _pad_lanes(r_new.astype(kr_planes.dtype), R).reshape(B, 1, R),
       ckv_planes, kr_planes)
+    return out[..., :kvr], cp, rp
 
 
 def pul_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -622,7 +670,7 @@ def pul_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, scale: Optional[float] = None,
                   softcap: Optional[float] = None,
                   window: Optional[int] = None,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     B, H, T, hd = q.shape
     _, K, S, _ = k.shape
     assert H % K == 0
@@ -657,5 +705,5 @@ def pul_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bt, 1), jnp.float32),
             pltpu.VMEM((bt, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

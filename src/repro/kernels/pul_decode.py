@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, pul_loop, ring_scratch, interpret_mode)
 
 NEG_INF = -2.0e38
 
@@ -72,7 +73,7 @@ def pul_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          length, *, cfg: PULConfig = PULConfig(),
                          bs: int = 128, scale: Optional[float] = None,
                          softcap: Optional[float] = None,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: (B,H,hd); k,v: (B,K,S,hd); length: (B,) valid cache entries.
     Returns (B,H,hd)."""
     B, H, hd = q.shape
@@ -105,6 +106,6 @@ def pul_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             *ring_scratch(cfg, (1, 1, bs, hd), k.dtype),
             *ring_scratch(cfg, (1, 1, bs, hd), v.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(length, qg, k, v)
     return out.reshape(B, H, hd)

@@ -14,13 +14,16 @@ Predicate: row[0] > threshold.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch,
+    interpret_mode)
 
 
 def _kernel_bitvec(thr_smem, data_hbm, out_hbm, pbuf, psems, ubuf, usems, *,
@@ -72,7 +75,7 @@ def _kernel_materialize(thr_smem, data_hbm, out_hbm, pbuf, psems, ubuf, usems,
 
 def pul_filter(data: jax.Array, threshold: float, *,
                cfg: PULConfig = PULConfig(), rows_per_block: int = 128,
-               materialize: bool = False, interpret: bool = True) -> jax.Array:
+               materialize: bool = False, interpret: Optional[bool] = None) -> jax.Array:
     N, W = data.shape
     rows = rows_per_block
     assert N % rows == 0 and rows % 32 == 0
@@ -98,6 +101,6 @@ def pul_filter(data: jax.Array, threshold: float, *,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[*ring_scratch(cfg, (rows, W), data.dtype),
                         *ring_scratch(cfg, ublock, udtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(thr, data)
     return out[:, 0] if not materialize else out

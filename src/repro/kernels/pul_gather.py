@@ -8,13 +8,16 @@ synchronize independently).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch,
+    interpret_mode)
 
 
 def _kernel(trace_smem, table_hbm, out_hbm, pbuf, psems, ubuf, usems, *,
@@ -39,7 +42,7 @@ def _kernel(trace_smem, table_hbm, out_hbm, pbuf, psems, ubuf, usems, *,
 
 def pul_page_gather(store: jax.Array, page_table: jax.Array, *,
                     cfg: PULConfig = PULConfig(),
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Assemble sequences from a paged KV store (the serving gather path).
 
     store: (n_pages, page_tokens, feat) physical page frames.
@@ -59,7 +62,7 @@ def pul_page_gather(store: jax.Array, page_table: jax.Array, *,
 
 def pul_gather(table: jax.Array, trace: jax.Array, *,
                cfg: PULConfig = PULConfig(), rows_per_req: int = 1,
-               interpret: bool = True) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     n_req = trace.shape[0]
     W = table.shape[1]
     block = (rows_per_req, W)
@@ -73,5 +76,5 @@ def pul_gather(table: jax.Array, trace: jax.Array, *,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[*ring_scratch(cfg, block, table.dtype),
                         *ring_scratch(cfg, block, table.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(trace, table)

@@ -12,14 +12,16 @@ Block shapes are PULConfig knobs; defaults are MXU-aligned (128 multiples).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, UnloadStream, pul_loop, ring_scratch,
+    interpret_mode)
 
 
 def _kernel(a_hbm, b_hbm, c_hbm, abuf, asems, bbuf, bsems, cacc, ubuf, usems,
@@ -61,7 +63,7 @@ def _kernel(a_hbm, b_hbm, c_hbm, abuf, asems, bbuf, bsems, cacc, ubuf, usems,
 
 def pul_matmul(a: jax.Array, b: jax.Array, *, cfg: PULConfig = PULConfig(),
                bm: int = 128, bk: int = 128, bn: int = 128,
-               out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+               out_dtype=jnp.float32, interpret: Optional[bool] = None) -> jax.Array:
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
@@ -83,5 +85,5 @@ def pul_matmul(a: jax.Array, b: jax.Array, *, cfg: PULConfig = PULConfig(),
             pltpu.VMEM((bm, bn), jnp.float32),
             *ring_scratch(ucfg, (bm, bn), out_dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a, b)

@@ -11,13 +11,15 @@ transfer size (Exp. 4), BATCH vs SEQUENTIAL issue (Fig. 5-D).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import PULConfig, PreloadStream, pul_loop, ring_scratch
+from repro.core import (
+    PULConfig, PreloadStream, pul_loop, ring_scratch, interpret_mode)
 
 
 def _kernel(trace_smem, data_hbm, out_smem, buf, sems, *, cfg: PULConfig,
@@ -38,7 +40,7 @@ def _kernel(trace_smem, data_hbm, out_smem, buf, sems, *, cfg: PULConfig,
 
 
 def pul_sum(data: jax.Array, trace: jax.Array, *, cfg: PULConfig = PULConfig(),
-            rows_per_req: int = 1, interpret: bool = True) -> jax.Array:
+            rows_per_req: int = 1, interpret: Optional[bool] = None) -> jax.Array:
     """sum over data[trace[i]*rows_per_req : +rows_per_req] for all i.
 
     data: (R, W) float; trace: (n_req,) int32 of block indices.
@@ -55,6 +57,6 @@ def pul_sum(data: jax.Array, trace: jax.Array, *, cfg: PULConfig = PULConfig(),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         scratch_shapes=list(ring_scratch(cfg, block, data.dtype)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(trace, data)
     return out[0]

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import CONFIGS, SHAPES, get_config
-from repro.launch import mesh as mesh_lib
 from repro.launch import steps as S
 from repro.launch.mesh import make_production_mesh, mesh_chips
 
@@ -156,7 +155,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool,
     elif variant == "cache_seq_data":   # §Perf C baseline
         rules = ShardingRules().with_overrides(cache_seq=("data", None))
     t0 = time.time()
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = S.cell_specs(cfg, shape, mesh, rules)
         if shape.kind == "train":
             # microbatch so activations fit HBM; recorded for §Perf

@@ -12,25 +12,23 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def set_mesh(mesh):
-    """Version-compat mesh context: `jax.set_mesh` landed after 0.4.37;
-    on older jax the Mesh object itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes):
+    """Every mesh in the repo is built here. Axes are `Auto`: the sharding
+    rules (`runtime.sharding`) place weights and constrain activations, and
+    the partitioner propagates the rest. `jax.make_mesh` defaults to
+    `Explicit` axes, under which `with_sharding_constraint` turns into a
+    type assertion and unannotated gathers raise `ShardingTypeError`."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(n_data: int = 2, n_model: int = 2):
-    """Tiny mesh for in-test dry-runs (requires >= n_data*n_model devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def mesh_chips(mesh) -> int:

@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import zoo
 from repro.obs import Tracer, validate_chrome_trace
 from repro.serving import (
@@ -61,6 +62,7 @@ def main(argv=None):
         ap.error("--trace/--metrics instrument the paged engine; "
                  "drop --dense")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -134,11 +134,8 @@ def constrain(x, logical: Sequence[Optional[str]], rules: ShardingRules = Shardi
     Identity when tracing outside any mesh (CPU unit tests); inside
     jax.set_mesh / Mesh context it resolves the same way weights do.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:      # very old jax
-        return x
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = logical_to_spec(logical, x.shape, mesh, rules)
     return jax.lax.with_sharding_constraint(x, spec)

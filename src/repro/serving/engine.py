@@ -118,6 +118,19 @@ def mean(xs) -> float:
     return float(np.mean(np.asarray(xs, np.float64)))
 
 
+def _set_idx(tree, idx: np.ndarray):
+    """Overwrite every cache `idx` leaf with per-slot fill levels."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    vec = jnp.asarray(idx, jnp.int32)
+    out = []
+    for path, leaf in flat:
+        keys = _path_keys(path)
+        if keys[-1] == "idx":
+            leaf = jnp.broadcast_to(vec, leaf.shape).astype(leaf.dtype)
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
 def _drain_results(requests: Dict[int, Request]) -> Dict[int, List[int]]:
     """Collect every tracked request's output and prune the completed ones
     (a long-lived engine must not accumulate historical requests)."""
@@ -143,16 +156,18 @@ class EngineConfig:
 
 
 class ServingEngine:
-    """Dense-cache slot engine (left-padded bucket prefill, batch re-prefill
+    """Dense-cache slot engine (right-padded bucket prefill, batch re-prefill
     on admission). The differential-test oracle for the paged engine."""
 
     def __init__(self, cfg: ModelConfig, params, engine_cfg: EngineConfig = EngineConfig()):
         from repro.serving.config import ServingConfig
         if isinstance(engine_cfg, ServingConfig):
             engine_cfg = engine_cfg.dense()
-        self.model_cfg = cfg
+        # token-indexed caches, as in the paged engine: right-padded rows of
+        # any length share one layout, and sliding windows are a mask term
+        self.model_cfg = dataclasses.replace(cfg, paged_kv=True)
         self.cfg = engine_cfg
-        self.model = zoo.build_model(cfg)
+        self.model = zoo.build_model(self.model_cfg)
         self.params = params
         B, S = engine_cfg.batch_slots, engine_cfg.max_seq
         self._prefill = jax.jit(
@@ -188,17 +203,23 @@ class ServingEngine:
         self._prefill_all()
 
     def _prefill_all(self):
+        """Right-padded prefill of every occupied slot: a row's logits come
+        from its last real token, and its cache fill level and next
+        position are its own length (padding rows are masked, then
+        overwritten by decode)."""
         B, bucket = self.cfg.batch_slots, self.cfg.prefill_bucket
         toks = np.zeros((B, bucket), np.int32)
+        lengths = np.ones((B,), np.int32)
         for i, r in enumerate(self.slot_req):
             if r is None:
                 continue
             prompt = (r.prompt + r.out_tokens)[-bucket:]
-            toks[i, -len(prompt):] = prompt       # left-pad
-            self.slot_pos[i] = bucket
-        batch = {"tokens": jnp.asarray(toks)}
+            toks[i, :len(prompt)] = prompt
+            lengths[i] = len(prompt)
+        batch = {"tokens": jnp.asarray(toks), "lengths": jnp.asarray(lengths)}
         logits, caches = self._prefill(self.params, batch)
-        self.caches = caches
+        self.caches = _set_idx(caches, lengths)
+        self.slot_pos = lengths
         self._emit(np.asarray(logits))
 
     def _emit(self, logits: np.ndarray):
@@ -836,22 +857,10 @@ class PagedServingEngine:
     # ------------------------------------------------------------------ #
     # decode
     # ------------------------------------------------------------------ #
-    def _set_idx(self, tree, idx: np.ndarray):
-        """Overwrite every cache `idx` leaf with per-slot fill levels."""
-        flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-        vec = jnp.asarray(idx, jnp.int32)
-        out = []
-        for path, leaf in flat:
-            keys = _path_keys(path)
-            if keys[-1] == "idx":
-                leaf = jnp.broadcast_to(vec, leaf.shape).astype(leaf.dtype)
-            out.append(leaf)
-        return jax.tree_util.tree_unflatten(treedef, out)
-
     def _assemble(self) -> Any:
         """Build the decode cache tree: pages -> dense token-indexed view."""
         if not self.layout.features:
-            return self._set_idx(self.resident, self.slot_len)
+            return _set_idx(self.resident, self.slot_len)
         B, P = self.cfg.batch_slots, self.cfg.page_tokens
         frames = np.full((B, self.n_pages_per_slot), ZERO_FRAME, np.int32)
         for i in self._live_slots():
@@ -869,7 +878,7 @@ class PagedServingEngine:
             packed = store[jnp.asarray(frames)].reshape(
                 B, self.cfg.max_seq, -1)
         tree = self.layout.unpack_into(self.resident, packed)
-        return self._set_idx(tree, self.slot_len)
+        return _set_idx(tree, self.slot_len)
 
     def _ensure_tail_pages(self):
         """Every live slot needs a writable page for the incoming token.
@@ -929,7 +938,7 @@ class PagedServingEngine:
             pids = self.slot_pages[i]
             page_table[i, :len(pids)] = self.pool.frames_of(pids)
         if self.cfg.sweep_decode:
-            tree = self._set_idx(self._sweep_cache_tree(), self.slot_len)
+            tree = _set_idx(self._sweep_cache_tree(), self.slot_len)
             # account + lifecycle-trace the fused commit BEFORE the launch
             # (events must precede the write they describe)
             self.pool.note_fused_commit(frames, offs)
@@ -943,7 +952,7 @@ class PagedServingEngine:
             self.pool.planes = planes
             return logits, new_tree
         tree = self.layout.page_view_tree(self.resident, self.pool.planes)
-        tree = self._set_idx(tree, self.slot_len)
+        tree = _set_idx(tree, self.slot_len)
         return self._paged_decode(
             self.params, {"tokens": jnp.asarray(toks),
                           "pos0": jnp.asarray(pos0),

@@ -67,6 +67,7 @@ from repro.core.pul import (
     PEModel,
     HBM,
     REMOTE_HBM,
+    TPU_LANE,
     TPU_SUBLANE,
     TPU_V5E_VPU,
     TransferRequest,
@@ -84,6 +85,11 @@ KV_LAYOUT_VERSION = 2
 
 def _path_keys(path) -> Tuple[str, ...]:
     return tuple(getattr(p, "key", str(p)) for p in path)
+
+
+def _lanes(n: int) -> int:
+    """`n` rounded up to whole TPU lanes: the minor dim of a page plane."""
+    return -(-n // TPU_LANE) * TPU_LANE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,6 +130,14 @@ class KVStoreLayout:
 
     with ``L`` the leaf's layer extent (scan groups; 1 for unscanned
     leaves), ``NF`` the pool's hot-frame count, and ``P`` tokens per page.
+    The minor dim (``hd`` / ``feat``) is stored padded with zeros to whole
+    128-lane tiles: Mosaic DMAs whole tiles, so a page of a narrower
+    feature (MLA's 64-wide rope stream, a 64- or 112-wide head) cannot be
+    sliced out of its plane otherwise. The TPU's HBM layout tiles the
+    minor dim in 128-lane units too (the compiled kernel sees a 64-wide
+    plane as 128 lanes), so the padding adds no device memory. Kernels
+    pad their queries to the plane width; packed rows, the cold tier and
+    all byte accounting carry only the real features.
 
     Required interface (all pure jnp unless stated):
 
@@ -347,8 +361,10 @@ class PackedKVLayout(KVStoreLayout):
                     page_tokens: int) -> Tuple[int, ...]:
         feat = e.feat
         if len(feat) == 2:                  # attention: (L, NF, K, P, hd)
-            return (e.layers, n_frames, feat[0], page_tokens, feat[1])
-        return (e.layers, n_frames, page_tokens, *feat)   # MLA: (L, NF, P, f)
+            return (e.layers, n_frames, feat[0], page_tokens,
+                    _lanes(feat[1]))
+        return (e.layers, n_frames, page_tokens,       # MLA: (L, NF, P, f)
+                _lanes(feat[0]))
 
     def init_planes(self, n_frames: int, page_tokens: int,
                     dtype) -> Dict[str, jnp.ndarray]:
@@ -402,11 +418,12 @@ class PackedKVLayout(KVStoreLayout):
                 vals = cols.reshape(B, e.layers, *feat)       # (B, L, K, hd)
                 # advanced indices (frames @ axis 1, offsets @ axis 3) are
                 # separated by a slice, so the broadcast B axis leads
-                out[e.plane_key] = plane.at[:, frames, :, offsets, :].set(vals)
+                out[e.plane_key] = plane.at[
+                    :, frames, :, offsets, :feat[-1]].set(vals)
             else:
                 vals = cols.reshape(B, e.layers, *feat)       # (B, L, f)
                 # adjacent advanced indices keep their position: (L, B, f)
-                out[e.plane_key] = plane.at[:, frames, offsets, :].set(
+                out[e.plane_key] = plane.at[:, frames, offsets, :feat[-1]].set(
                     jnp.moveaxis(vals, 0, 1))
         return out
 
@@ -415,7 +432,7 @@ class PackedKVLayout(KVStoreLayout):
         """One frame's packed (P, F) rows (numpy; cold-tier spill format)."""
         cols = []
         for e in self.entries:
-            sl = np.asarray(planes[e.plane_key][:, frame])
+            sl = np.asarray(planes[e.plane_key][:, frame, ..., :e.feat[-1]])
             if len(e.feat) == 2:            # (L, K, P, hd) -> (P, L*K*hd)
                 sl = sl.transpose(2, 0, 1, 3)
             else:                           # (L, P, f) -> (P, L*f)
@@ -437,7 +454,8 @@ class PackedKVLayout(KVStoreLayout):
                 vals = cols.reshape(P, e.layers, *feat).transpose(1, 2, 0, 3)
             else:                           # (P, L, f) -> (L, P, f)
                 vals = cols.reshape(P, e.layers, *feat).transpose(1, 0, 2)
-            out[e.plane_key] = planes[e.plane_key].at[:, frame].set(vals)
+            out[e.plane_key] = planes[e.plane_key].at[
+                :, frame, ..., :feat[-1]].set(vals)
         return out
 
     def pack_planes(self, planes: Dict[str, jnp.ndarray]) -> jnp.ndarray:
@@ -445,7 +463,7 @@ class PackedKVLayout(KVStoreLayout):
         COPY; only the dense-assembly oracle path pays it."""
         cols = []
         for e in self.entries:
-            plane = planes[e.plane_key]
+            plane = planes[e.plane_key][..., :e.feat[-1]]
             if len(e.feat) == 2:            # (L,NF,K,P,hd) -> (NF,P,L,K,hd)
                 sl = jnp.transpose(plane, (1, 3, 0, 2, 4))
             else:                           # (L,NF,P,f) -> (NF,P,L,f)

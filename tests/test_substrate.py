@@ -242,20 +242,25 @@ def test_serving_engine_matches_manual_decode():
     out = eng.run()[0]
     assert len(out) == 4
 
-    # manual greedy decode with left-padded prompt (same as engine's bucket)
+    # manual greedy decode with the prompt right-padded to the engine's
+    # bucket: logits at the last real token, cache fill level = length
     bucket = 16
     toks = np.zeros((2, bucket), np.int32)
-    toks[0, -len(prompt):] = prompt
+    toks[0, :len(prompt)] = prompt
+    lengths = jnp.asarray([len(prompt), 1], jnp.int32)
     logits, caches = jax.jit(lambda p, b: m.prefill(p, b, max_seq=64))(
-        params, {"tokens": jnp.asarray(toks)})
+        params, {"tokens": jnp.asarray(toks), "lengths": lengths})
+    caches = jax.tree_util.tree_map_with_path(
+        lambda path, x: (jnp.broadcast_to(lengths, x.shape)
+                         if getattr(path[-1], "key", None) == "idx" else x),
+        caches)
     manual = [int(np.argmax(np.asarray(logits)[0]))]
-    pos = bucket
+    pos = lengths
     for _ in range(3):
         step = np.zeros((2, 1), np.int32)
         step[0, 0] = manual[-1]
         logits, caches = jax.jit(m.decode_step)(
-            params, {"tokens": jnp.asarray(step),
-                     "pos0": jnp.full((2,), pos, jnp.int32)}, caches)
+            params, {"tokens": jnp.asarray(step), "pos0": pos}, caches)
         manual.append(int(np.argmax(np.asarray(logits)[0])))
-        pos += 1
+        pos = pos + 1
     assert out == manual
