@@ -512,6 +512,7 @@ def pul_paged_sweep_decode_attention(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret_mode(interpret),
+        name="pul_paged_sweep",
     )(page_tables.astype(jnp.int32), lengths, frames, offsets, layer, qg,
       _pad_lanes(k_new.astype(k_planes.dtype), W).reshape(B, K, 1, W),
       _pad_lanes(v_new.astype(v_planes.dtype), W).reshape(B, K, 1, W),
@@ -657,6 +658,7 @@ def pul_paged_sweep_mla_decode_attention(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret_mode(interpret),
+        name="pul_paged_sweep_mla",
     )(page_tables.astype(jnp.int32), lengths, frames, offsets, layer,
       _pad_lanes(q_abs, C), _pad_lanes(q_rope, R),
       _pad_lanes(c_new.astype(ckv_planes.dtype), C).reshape(B, 1, C),

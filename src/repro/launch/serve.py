@@ -107,7 +107,8 @@ def main(argv=None):
         print(f"[serve] pages allocated {snap['pages_allocated']}, faults "
               f"{snap['page_faults']}, evictions {snap['evictions']}, "
               f"shared hits {snap['shared_page_hits']}, mean queue wait "
-              f"{snap['mean_queue_latency']:.1f} ticks")
+              f"{snap['mean_queue_latency']:.1f} ticks, "
+              f"{snap['mean_queue_wait_s']:.3f} s")
         print(f"[serve] policy {snap['policy']}: preemptions "
               f"{snap['preemptions']}, readmissions {snap['readmissions']}, "
               f"chunk passes {snap['chunk_passes']}, SLO violations "

@@ -10,13 +10,16 @@ stalls) — so a serving run produces ONE timeline that Perfetto / Chrome
 
 Design rules:
 
-  * **Zero overhead when off.** :data:`NULL_TRACER` (the default everywhere)
-    is ``enabled=False`` and every method is a no-op returning a shared null
-    context; no event object, dict, or string is ever allocated on the
-    untraced hot path. Callers that would build an args dict guard on
-    ``tracer.enabled`` first.
+  * **Zero overhead when off.** :data:`NULL_TRACER` is ``enabled=False``
+    and every method is a no-op returning a shared null context; no event
+    object, dict, or string is ever allocated on the untraced hot path.
+    Callers that would build an args dict for an instant, counter, async
+    span or page event guard on ``tracer.enabled`` first.
+    :data:`DEFAULT_TRACER` is ``enabled=False`` too, and costs one check
+    per span while no profiler session records.
   * **Two clocks.** Serving-side events carry a *wall* timestamp (µs since
-    the tracer was created, monotonic ``perf_counter``) plus the engine
+    the tracer was created, monotonic ``perf_counter``; :meth:`Tracer.spans`
+    turns it back into ``perf_counter`` seconds) plus the engine
     *tick* in ``args``; DMA-twin events carry *model* time (the discrete-
     event simulator's clock, µs) on their own process track, offset so
     successive restore batches lay out sequentially. Perfetto renders both;
@@ -35,15 +38,24 @@ Events are plain dataclasses with JSON-safe args (tuples become lists,
 replay; :func:`page_events_from_chrome` rebuilds the page-lifecycle
 ``PageEvent`` stream from an exported file, which the round-trip tests feed
 back through the sanitizer's ``LifecycleChecker``.
+
+While a ``jax.profiler`` session records, every ``with tracer.span(...)``
+also opens a ``jax.profiler.TraceAnnotation`` of the same name, so the span
+shows in the profile beside the device ops; the profile's host clock is
+``perf_counter`` less one constant. Components built without a tracer get
+:data:`DEFAULT_TRACER`, a :class:`Tracer` that records synchronous spans,
+and nothing else, exactly while such a session records, in a bounded
+buffer.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import json
 import math
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 # Chrome trace-event phases this tracer emits
 PH_BEGIN = "B"          # synchronous span open
@@ -101,15 +113,64 @@ class TraceEvent:
     args: Optional[Dict[str, Any]] = None
 
 
+class Span(NamedTuple):
+    """One closed synchronous span (:meth:`Tracer.spans`): `start` and `end`
+    in seconds of ``time.perf_counter()``, `parent` the name of the span
+    open around it (None at the top), `tick` the engine tick."""
+
+    name: str
+    start: float
+    end: float
+    parent: Optional[str]
+    tick: int
+    args: Optional[Dict[str, Any]]
+
+
+_NULL_CTX = contextlib.nullcontext()   # shared: a span that records nothing
+_TRACE_ANNOTATION: Any = None   # jax.profiler.TraceAnnotation; False: no jax
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    `repro.obs` imports without jax; None where jax is not installed."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION or None
+
+
+def profiler_recording() -> bool:
+    """True while a ``jax.profiler`` session records."""
+    ann = _trace_annotation()
+    return ann is not None and ann.is_enabled()
+
+
 class Tracer:
-    """Append-only event recorder with Chrome/Perfetto export."""
+    """Append-only event recorder with Chrome/Perfetto export.
+
+    `only_while_profiling` makes the tracer that components built without
+    one share (:data:`DEFAULT_TRACER`): `enabled` is False, so call sites
+    send it only synchronous spans, and it records them only while a
+    ``jax.profiler`` session records, keeping the newest
+    MAX_PROFILED_EVENTS (a 10 s profile of a benchmark cell on one TPU v5e
+    holds a few thousand)."""
 
     enabled: bool = True
+    MAX_PROFILED_EVENTS = 1 << 17
 
-    def __init__(self) -> None:
-        self.events: List[TraceEvent] = []
+    def __init__(self, *, only_while_profiling: bool = False) -> None:
+        self.events: "List[TraceEvent] | collections.deque[TraceEvent]" = (
+            collections.deque(maxlen=self.MAX_PROFILED_EVENTS)
+            if only_while_profiling else [])
         self._t0 = time.perf_counter()
         self._tick = -1
+        self.only_while_profiling = only_while_profiling
+        if only_while_profiling:
+            self.enabled = False
 
     # ------------------------------------------------------------------ #
     # clocks
@@ -143,14 +204,42 @@ class Tracer:
     def end_span(self, track: str, name: str = "") -> None:
         self._emit(PH_END, track, name)
 
-    @contextlib.contextmanager
     def span(self, track: str, name: str, **args):
-        """Synchronous span as a context manager (the preferred form)."""
-        self.begin_span(track, name, **args)
-        try:
-            yield
-        finally:
-            self.end_span(track, name)
+        """Synchronous span as a context manager (the preferred form).
+        While a profiler session records, a profiler annotation of the same
+        name opens around it."""
+        if self.only_while_profiling and not profiler_recording():
+            return _NULL_CTX
+        return self._span(track, name, args)
+
+    @contextlib.contextmanager
+    def _span(self, track: str, name: str, args: Dict[str, Any]):
+        ann = _trace_annotation()
+        with (ann(name, **args) if ann is not None and ann.is_enabled()
+              else _NULL_CTX):
+            self.begin_span(track, name, **args)
+            try:
+                yield
+            finally:
+                self.end_span(track, name)
+
+    def spans(self) -> List[Span]:
+        """The closed synchronous spans, by start, on ``perf_counter``.
+        Where the bounded buffer has dropped a span's begin, the span is
+        left out."""
+        out: List[Span] = []
+        stack: List[TraceEvent] = []
+        for ev in self.events:
+            if ev.ph == PH_BEGIN:
+                stack.append(ev)
+            elif ev.ph == PH_END and stack:
+                b = stack.pop()
+                out.append(Span(b.name, self._t0 + b.ts * 1e-6,
+                                self._t0 + ev.ts * 1e-6,
+                                stack[-1].name if stack else None,
+                                b.tick, b.args))
+        out.sort(key=lambda sp: sp.start)
+        return out
 
     def complete(self, track: str, name: str, *, ts: float, dur: float,
                  cat: str = "", **args) -> None:
@@ -271,7 +360,6 @@ class NullTracer(Tracer):
     allocation too."""
 
     enabled = False
-    _NULL_CTX = contextlib.nullcontext()
 
     def __init__(self) -> None:          # no event list, no clock
         self.events = ()                 # immutable + empty: nothing recorded
@@ -286,13 +374,17 @@ class NullTracer(Tracer):
         pass
 
     def span(self, track: str, name: str, **args):
-        return self._NULL_CTX
+        return _NULL_CTX
 
     def to_chrome(self, path: Optional[str] = None) -> Dict[str, Any]:
         raise RuntimeError("NullTracer records nothing; nothing to export")
 
 
 NULL_TRACER = NullTracer()
+
+
+# what components built without a tracer share
+DEFAULT_TRACER = Tracer(only_while_profiling=True)
 
 
 # ---------------------------------------------------------------------- #

@@ -62,7 +62,6 @@ zoo subset uses dense archs.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 import warnings
 from collections import OrderedDict
@@ -79,7 +78,7 @@ from repro.obs.metrics import (
     cache_economics,
     economics_into_registry,
 )
-from repro.obs.tracer import NULL_TRACER, Tracer
+from repro.obs.tracer import DEFAULT_TRACER, Tracer
 from repro.serving.kv_pages import (
     KVPagePool,
     PackedKVLayout,
@@ -170,9 +169,16 @@ class ServingEngine:
         self.model = zoo.build_model(self.model_cfg)
         self.params = params
         B, S = engine_cfg.batch_slots, engine_cfg.max_seq
-        self._prefill = jax.jit(
-            lambda p, b: self.model.prefill(p, b, max_seq=S))
-        self._decode = jax.jit(self.model.decode_step)
+        model = self.model
+
+        def prefill(p, b):
+            return model.prefill(p, b, max_seq=S)
+
+        def decode(p, b, caches):
+            return model.decode_step(p, b, caches)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
         self.caches = None
         self.slot_req: List[Optional[Request]] = [None] * B
         self.slot_pos: np.ndarray = np.zeros((B,), np.int32)  # next position
@@ -346,9 +352,9 @@ class PagedServingEngine:
         self.cfg = engine_cfg
         self.metrics_hook = metrics_hook
         # one tracer threaded through the whole stack (engine spans,
-        # scheduler decisions, page lifecycle, DMA twin); NULL_TRACER (the
-        # default) makes every emission site a no-op
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # scheduler decisions, page lifecycle, DMA twin); the default
+        # records only the spans, and only under a jax.profiler session
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         self.model = zoo.build_model(self.model_cfg)
         self.params = params
 
@@ -398,18 +404,29 @@ class PagedServingEngine:
 
         # compiled entry points: one prefill per bucket, one decode; the
         # kernel-true path binds the planner's d* as the in-kernel preload
-        # distance (static arg, so it is part of the compiled artifact)
+        # distance (static arg, so it is part of the compiled artifact).
+        # Each is a named function: the name is its program's name in a
+        # device profile ("jit_prefill", "jit_paged_decode", ...)
         self._prefill_fns: Dict[int, Callable] = {}
-        self._decode = jax.jit(self.model.decode_step)
+        model = self.model
         d = max(1, min(self.pool.distance, self.pool.cfg.fifo_depth))
-        self._paged_decode = jax.jit(functools.partial(
-            self.model.paged_decode_step, pul_distance=d))
+
+        def decode(p, batch, tree):
+            return model.decode_step(p, batch, tree)
+
+        def paged_decode(p, batch, tree):
+            return model.paged_decode_step(p, batch, tree, pul_distance=d)
+
+        def sweep_paged_decode(p, batch, tree, planes):
+            return model.paged_decode_step(p, batch, tree, planes,
+                                           pul_distance=d)
+
+        self._decode = jax.jit(decode)
+        self._paged_decode = jax.jit(paged_decode)
         # single-sweep decode: planes ride as a donated argument so the
         # fused in-kernel commit updates them in place (no copy of the
         # store per step); returns (logits, new_tree, planes)
-        self._sweep_decode = jax.jit(functools.partial(
-            self.model.paged_decode_step, pul_distance=d),
-            donate_argnums=(3,))
+        self._sweep_decode = jax.jit(sweep_paged_decode, donate_argnums=(3,))
 
         # slot state
         self.slot_req: List[Optional[Request]] = [None] * B
@@ -444,9 +461,11 @@ class PagedServingEngine:
     def _prefill_for(self, bucket: int) -> Callable:
         if bucket not in self._prefill_fns:
             model = self.model
-            self._prefill_fns[bucket] = jax.jit(
-                lambda p, b, _bucket=bucket: model.prefill(
-                    p, b, max_seq=_bucket))
+
+            def prefill(p, b):
+                return model.prefill(p, b, max_seq=bucket)
+
+            self._prefill_fns[bucket] = jax.jit(prefill)
         return self._prefill_fns[bucket]
 
     def submit(self, req: Request):
@@ -729,7 +748,8 @@ class PagedServingEngine:
         self.pool.note_deadline(pids, req.deadline_tick())
 
     def _prefill_group(self, bucket: int, group: List[Admission]):
-        with self.tracer.span("engine", f"prefill@{bucket}"):
+        with self.tracer.span("engine", "serve.prefill", bucket=bucket,
+                              rows=len(group)):
             self._prefill_group_inner(bucket, group)
 
     def _prefill_group_inner(self, bucket: int, group: List[Admission]):
@@ -788,7 +808,7 @@ class PagedServingEngine:
 
     def _advance_chunks(self):
         for slot in sorted(self._chunk):
-            with self.tracer.span("engine", f"chunk-pass@{slot}"):
+            with self.tracer.span("engine", "serve.chunk", slot=slot):
                 self._chunk_pass(slot)
 
     def _chunk_pass(self, slot: int):
@@ -972,9 +992,26 @@ class PagedServingEngine:
         self.resident = jax.tree_util.tree_unflatten(treedef, out)
 
     def _decode_step(self):
+        """One decode step of the live slots: prepare and launch it, fetch
+        its logits (the host waits for the device here), then emit."""
         live = self._live_slots()
         if not live:
             return
+        tr = self.tracer
+        with tr.span("engine", "serve.decode.prepare"):
+            faults, logits = self._launch_decode(live)
+        with tr.span("engine", "serve.decode.fetch"):
+            logits = np.asarray(logits)
+        with tr.span("engine", "serve.sample"):
+            for i in live:
+                self.slot_len[i] += 1
+                self._emit_token(i, logits[i])
+        return faults
+
+    def _launch_decode(self, live: List[int]):
+        """Tail pages, restores, page table and commit coordinates, then
+        the dispatch of the decode step; returns (page faults, the step's
+        logits on the device)."""
         B = self.cfg.batch_slots
         self._ensure_tail_pages()
         needed = sorted({pid for i in live for pid in self.slot_pages[i]})
@@ -1019,12 +1056,7 @@ class PagedServingEngine:
             self._merge_nonpageable(new_tree)
         else:
             self.resident = new_tree
-
-        logits = np.asarray(logits)
-        for i in live:
-            self.slot_len[i] += 1
-            self._emit_token(i, logits[i])
-        return faults
+        return faults, logits
 
     def _emit_token(self, slot: int, logits: np.ndarray):
         r = self.slot_req[slot]
@@ -1125,36 +1157,38 @@ class PagedServingEngine:
         t0 = time.perf_counter()
         tr = self.tracer
         tr.set_tick(self._tick)
-        with tr.span("engine", "tick"):
-            with tr.span("engine", "admit"):
+        with tr.span("engine", "serve.tick"):
+            with tr.span("engine", "serve.admit"):
                 self._admit()
             self._advance_chunks()
-            with tr.span("engine", "decode"):
+            with tr.span("engine", "serve.decode"):
                 faults = self._decode_step() or 0
-        self._tick += 1
-        self.metrics.ticks = self._tick
-        self.metrics.wall_time += time.perf_counter() - t0
-        if tr.enabled:
-            tr.counter("gauges", "live_slots", len(self._live_slots()))
-            tr.counter("gauges", "queued", len(self.scheduler))
-            tr.counter("gauges", "hot_pages_in_use", self.pool.hot_in_use())
-            tr.counter("gauges", "page_faults_step", faults)
-        if self._shadow_checker is not None:
-            self._run_shadow_check()
-        if self.metrics_hook:
-            # snapshot() runs OUTSIDE the guard: a PoolMetrics invariant
-            # violation must still crash loudly. Only the user-supplied
-            # observer is sandboxed — a broken hook must not take the tick
-            # loop down with it, so it is disabled after its first raise.
-            snap = self.snapshot(page_faults_step=faults)
-            try:
-                self.metrics_hook(snap)
-            except Exception as e:
-                warnings.warn(
-                    f"metrics_hook raised {e!r}; disabling the hook for the "
-                    "rest of this engine's life", RuntimeWarning,
-                    stacklevel=2)
-                self.metrics_hook = None
+            self._tick += 1
+            self.metrics.ticks = self._tick
+            self.metrics.wall_time += time.perf_counter() - t0
+            if tr.enabled:
+                tr.counter("gauges", "live_slots", len(self._live_slots()))
+                tr.counter("gauges", "queued", len(self.scheduler))
+                tr.counter("gauges", "hot_pages_in_use",
+                           self.pool.hot_in_use())
+                tr.counter("gauges", "page_faults_step", faults)
+            if self._shadow_checker is not None:
+                self._run_shadow_check()
+            if self.metrics_hook:
+                # snapshot() runs OUTSIDE the guard: a PoolMetrics
+                # invariant violation must still crash loudly. Only the
+                # user-supplied observer is sandboxed — a broken hook must
+                # not take the tick loop down with it, so it is disabled
+                # after its first raise.
+                snap = self.snapshot(page_faults_step=faults)
+                try:
+                    self.metrics_hook(snap)
+                except Exception as e:
+                    warnings.warn(
+                        f"metrics_hook raised {e!r}; disabling the hook for "
+                        "the rest of this engine's life", RuntimeWarning,
+                        stacklevel=2)
+                    self.metrics_hook = None
 
     def _run_shadow_check(self):
         """Feed the tick's new trace events through the lifecycle checker;
@@ -1191,6 +1225,7 @@ class PagedServingEngine:
             "preload_distance": self.pool.distance,
             "modeled_restore_latency_hidden": pm.modeled_latency_hidden,
             "mean_queue_latency": mean(lat),
+            "mean_queue_wait_s": mean(self.scheduler.queue_waits_s()),
         }
         snap.update(extra)
         return snap

@@ -58,7 +58,7 @@ import numpy as np
 
 from repro.analysis.events import EventKind, TraceLog
 from repro.configs.base import ModelConfig
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import DEFAULT_TRACER
 from repro.core.dma import DMAEngine, KVPageWorkload, run_kv_page_workload
 from repro.core.planner import kv_page_flops, plan_kv_page_stream
 from repro.core.pul import (
@@ -646,9 +646,9 @@ class KVPagePool:
         # untraced hot path never builds an event
         self.trace: Optional[TraceLog] = TraceLog() if pcfg.trace else None
         # unified tracer (repro.obs): page-lifecycle events are bridged into
-        # the same stream as engine spans and DMA descriptors; NULL_TRACER
-        # keeps every emission site a cheap attribute check when off
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # the same stream as engine spans and DMA descriptors; the default
+        # records only the pool's spans, and only under a profiler session
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         self._bridge_seq = 0    # event sequence when TraceLog is off
         self._next_id = 1
         self._clock = 0
@@ -799,7 +799,8 @@ class KVPagePool:
                 f"hot tier exhausted: {self.capacity} frames all needed this "
                 "step; raise PageConfig.hot_frames or admit fewer tokens")
         _, victim = victims[0]
-        self.evict(victim, cause="steal", pinned=needed)
+        with self.tracer.span("engine", "pool.evict", pages=1):
+            self.evict(victim, cause="steal", pinned=needed)
         return self.free_frames.pop()
 
     def evict(self, pid: int, *, cause: str = "explicit",
@@ -826,9 +827,10 @@ class KVPagePool:
         meta.frame = None
 
     def evict_pages(self, pids: Sequence[int]) -> None:
-        for pid in pids:
-            if self.pages[pid].frame is not None:
-                self.evict(pid)
+        with self.tracer.span("engine", "pool.evict", pages=len(pids)):
+            for pid in pids:
+                if self.pages[pid].frame is not None:
+                    self.evict(pid)
 
     def ensure_hot(self, pids: Sequence[int]) -> int:
         """Restore any cold page in `pids`; returns the page-fault count.
@@ -846,18 +848,21 @@ class KVPagePool:
             self._emit(EventKind.TOUCH, pid=pid)
             if meta.frame is None:
                 faults.append(pid)
-        for pid in faults:
-            meta = self.pages[pid]
-            frame = self._take_frame(needed=pids)
-            data = self.cold.pop(pid)
-            self._write_frame(frame, data)
-            meta.frame = frame
-            meta.pending_read = True
-            self._emit(EventKind.RESTORE, pid=pid, frame=frame)
-            self.metrics.descriptors.append(TransferRequest(
-                Direction.PRELOAD, src=pid * self.page_bytes,
-                dst=frame * self.page_bytes, nbytes=self.page_bytes, tag=pid))
         if faults:
+            with self.tracer.span("engine", "pool.restore",
+                                  pages=len(faults)):
+                for pid in faults:
+                    meta = self.pages[pid]
+                    frame = self._take_frame(needed=pids)
+                    data = self.cold.pop(pid)
+                    self._write_frame(frame, data)
+                    meta.frame = frame
+                    meta.pending_read = True
+                    self._emit(EventKind.RESTORE, pid=pid, frame=frame)
+                    self.metrics.descriptors.append(TransferRequest(
+                        Direction.PRELOAD, src=pid * self.page_bytes,
+                        dst=frame * self.page_bytes,
+                        nbytes=self.page_bytes, tag=pid))
             self.metrics.page_faults += len(faults)
             self.metrics.planned_preloads += len(faults)
             stats = run_kv_page_workload(
@@ -898,9 +903,10 @@ class KVPagePool:
                    n_valid=n_valid)
         P = self.cfg.page_tokens
         pad = P - n_valid
-        if pad:
-            rows = jnp.pad(rows[:n_valid], ((0, pad), (0, 0)))
-        self._write_frame(meta.frame, rows)
+        with self.tracer.span("engine", "pool.write_page", n_valid=n_valid):
+            if pad:
+                rows = jnp.pad(rows[:n_valid], ((0, pad), (0, 0)))
+            self._write_frame(meta.frame, rows)
         self.metrics.bytes_hot_written += self.page_bytes
 
     def write_rows(self, frames: np.ndarray, offsets: np.ndarray,

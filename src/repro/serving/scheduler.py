@@ -26,15 +26,17 @@ prompts that cannot fit a slot at all are rejected, not queued.
 
 Queue latency (submit tick -> admit tick) is recorded per request at FIRST
 admission (a preempted request's readmission wait is tracked separately via
-``Request.preemptions``) and surfaced through the engine's metrics hook.
+``Request.preemptions``) and surfaced through the engine's metrics hook, in
+ticks and, from ``Request.submit_t`` and ``admit_t``, in seconds.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import List, Optional, Sequence, Tuple
 
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import DEFAULT_TRACER
 
 POLICIES = ("fcfs", "priority", "slo-edf")
 
@@ -56,6 +58,8 @@ class Request:
     # paged-engine bookkeeping
     submit_tick: int = -1
     admit_tick: int = -1
+    submit_t: float = -1.0          # time.perf_counter() at submit and at
+    admit_t: float = -1.0           # the latest admission (-1: not yet)
     first_token_tick: int = -1
     bucket: int = 0
     preemptions: int = 0            # times swapped out mid-flight
@@ -118,7 +122,7 @@ class AdmissionScheduler:
         # repro.obs tracer: every admission outcome is an instant on the
         # "sched" track with its machine-readable reason — what
         # tools/trace_diff.py aligns two runs on
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         self.queue: List[Request] = []
         self.failed: List[Request] = []     # never-admittable rejections
         self.rejected = 0
@@ -126,6 +130,7 @@ class AdmissionScheduler:
         # retained here forever (prompt/out_tokens would leak in a
         # long-lived engine)
         self._latencies: List[int] = []
+        self._waits_s: List[float] = []
         self._arrivals = 0
 
     def __len__(self) -> int:
@@ -134,6 +139,7 @@ class AdmissionScheduler:
     # ------------------------------------------------------------------ #
     def submit(self, req: Request, now: int):
         req.submit_tick = now
+        req.submit_t = time.perf_counter()
         req._seq = self._arrivals
         self._arrivals += 1
         self.queue.append(req)
@@ -264,6 +270,7 @@ class AdmissionScheduler:
                 break
             self.queue.pop(0)
             req.admit_tick = now
+            req.admit_t = time.perf_counter()
             req.bucket = self.pick_bucket(len(req.prompt))
             tokens += cost
             frames -= pages
@@ -277,8 +284,13 @@ class AdmissionScheduler:
                 # queue latency is anchored at FIRST admission; readmission
                 # waits are visible via Request.preemptions instead
                 self._latencies.append(req.queue_latency)
+                self._waits_s.append(req.admit_t - req.submit_t)
         return out
 
     # ------------------------------------------------------------------ #
     def queue_latencies(self) -> List[int]:
         return [l for l in self._latencies if l >= 0]
+
+    def queue_waits_s(self) -> List[float]:
+        """Seconds from submit to first admission, per admitted request."""
+        return list(self._waits_s)

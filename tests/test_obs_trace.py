@@ -1,9 +1,10 @@
 """Unified trace/metrics layer: round-trips, exporters, and the FIFO diff.
 
-Five families:
+Six families:
 
   * tracer unit tests: Chrome export schema, the NullTracer's zero-cost
-    contract, JSON-safety of args (tuples, non-finite floats);
+    contract, JSON-safety of args (tuples, non-finite floats), spans paired
+    back onto ``perf_counter`` from a bounded buffer;
   * schema-validator fixtures: one hand-built broken trace per rule the
     validator enforces (unbalanced B/E, unknown phase, async e-before-b,
     negative X duration, counter without numerics, non-finite ts);
@@ -18,11 +19,16 @@ Five families:
     own ``metrics_registry()``;
   * DMA FIFO diff: the executed occupancy of a traced ``run_stream``
     matches the plan verifier's symbolic schedule (clean and
-    back-pressure cases), and a corrupted occupancy log is caught.
+    back-pressure cases), and a corrupted occupancy log is caught;
+  * spans in the profile: a span shows in a ``jax.profiler`` session; the
+    default tracer records nothing without one, and under one every engine
+    and pool span is in the profile on one offset from its
+    ``perf_counter`` record, nested and closed; queue wait in seconds.
 """
 import dataclasses
 import math
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -41,9 +47,11 @@ from repro.core import (
 )
 from repro.models import build_model
 from repro.obs import (
+    DEFAULT_TRACER,
     MetricsRegistry,
     NULL_TRACER,
     Tracer,
+    profiler_recording,
     cache_economics,
     economics_into_registry,
     page_events_from_chrome,
@@ -51,6 +59,7 @@ from repro.obs import (
 )
 from repro.serving import PagedEngineConfig, PagedServingEngine, Request
 from repro.serving.kv_pages import PoolMetrics
+from repro.serving.scheduler import AdmissionScheduler, SchedulerConfig
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
 import trace_diff  # noqa: E402  (tools/ is not a package)
@@ -116,6 +125,29 @@ def test_args_are_json_safe_and_restored():
     assert ev["args"]["pinned"] == [1, 2]
     (pe,) = page_events_from_chrome(doc)
     assert pe.deadline == math.inf and pe.pinned == (1, 2)
+
+
+def test_spans_pair_begin_and_end_on_perf_counter():
+    t = Tracer()
+    before = time.perf_counter()
+    t.set_tick(4)
+    with t.span("engine", "outer", rows=2):
+        with t.span("engine", "inner"):
+            pass
+        t.instant("engine", "mark")
+    after = time.perf_counter()
+    outer, inner = t.spans()
+    assert (outer.name, outer.parent, outer.tick, outer.args) == \
+        ("outer", None, 4, {"rows": 2})
+    assert (inner.name, inner.parent, inner.args) == ("inner", "outer", None)
+    assert before <= outer.start <= inner.start <= inner.end <= outer.end \
+        <= after
+    # the bounded buffer drops the oldest events: a span whose begin went
+    # is left out, the ones after it stay whole
+    t.events = t.events[1:]
+    assert [sp.name for sp in t.spans()] == ["inner"]
+    assert Tracer(only_while_profiling=True).events.maxlen == \
+        Tracer.MAX_PROFILED_EVENTS
 
 
 # ======================================================================== #
@@ -218,7 +250,7 @@ def test_traced_run_exports_valid_trace():
     assert validate_chrome_trace(doc) == []
     assert eng.metrics.preemptions >= 1, "workload must force a preemption"
     names = {ev.get("name") for ev in doc["traceEvents"]}
-    assert {"tick", "admit", "decode"} <= names          # engine spans
+    assert {"serve.tick", "serve.admit", "serve.decode"} <= names
     assert "preempt" in names                            # reasoned decision
     # counters never carry tick in args (each key renders as a series)
     for ev in doc["traceEvents"]:
@@ -253,9 +285,9 @@ def test_decision_stream_carries_reasons():
 def test_tracing_off_records_nothing_and_stays_deterministic():
     eng_t, out_t, _ = _traced()
     eng_u, out_u = _untraced()
-    assert eng_u.tracer is NULL_TRACER and eng_u.tracer.events == ()
+    assert eng_u.tracer is DEFAULT_TRACER and len(eng_u.tracer.events) == 0
     assert out_u == out_t                       # token streams identical
-    volatile = ("tokens_per_sec", "wall_time")
+    volatile = ("tokens_per_sec", "wall_time", "mean_queue_wait_s")
     snap_t = {k: v for k, v in eng_t.snapshot().items() if k not in volatile}
     snap_u = {k: v for k, v in eng_u.snapshot().items() if k not in volatile}
     assert snap_u == snap_t
@@ -273,6 +305,173 @@ def test_crashing_metrics_hook_warns_once_and_is_disabled():
     assert len(calls) == 1, "hook must be disabled after the first raise"
     assert eng.metrics_hook is None
     assert out == _untraced()[1], "a hook crash must not perturb the run"
+
+
+# ======================================================================== #
+# the default tracer: spans on the profiler's clock, only under a session
+# ======================================================================== #
+
+# every span the engine and pool open, with the span each opens inside
+SPAN_PARENTS = {
+    "serve.tick": None,
+    "serve.admit": "serve.tick",
+    "serve.prefill": "serve.admit",
+    "pool.write_page": "serve.prefill",
+    "pool.evict": "serve.admit",
+    "serve.decode": "serve.tick",
+    "serve.decode.prepare": "serve.decode",
+    "pool.restore": "serve.decode.prepare",
+    "serve.decode.fetch": "serve.decode",
+    "serve.sample": "serve.decode",
+}
+
+
+def _profile_host_events(root, names):
+    """[(name, start, end)] in seconds, by start: the host events of the
+    one `.xplane.pb` profile under `root` whose names are in `names`."""
+    from jax.profiler import ProfileData
+    (pb,) = Path(root).rglob("*.xplane.pb")
+    return sorted(((ev.name, ev.start_ns * 1e-9,
+                    (ev.start_ns + ev.duration_ns) * 1e-9)
+                   for plane in ProfileData.from_file(str(pb)).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events
+                   if ev.name in names), key=lambda e: e[1])
+
+
+def _profiled(tmp_path_factory):
+    """The mixed run inside a profiler session: (engine, outputs, the
+    default tracer's spans, the profile's host events of those names, the
+    default tracer's events)."""
+    if "profiled" not in _RUNS:
+        DEFAULT_TRACER.events.clear()
+        tmp = tmp_path_factory.mktemp("profile")
+        jax.profiler.start_trace(str(tmp))
+        try:
+            eng, out = _mixed_run()
+        finally:
+            jax.profiler.stop_trace()
+        spans = DEFAULT_TRACER.spans()
+        events = list(DEFAULT_TRACER.events)
+        DEFAULT_TRACER.events.clear()
+        _RUNS["profiled"] = (eng, out, spans,
+                             _profile_host_events(tmp, SPAN_PARENTS), events)
+    return _RUNS["profiled"]
+
+
+def _one_offset(pairs):
+    """Fit trace time = perf_counter + c over [((s, e), (a_s, a_e))], each
+    recorded span (s, e) inside its profile event (a_s, a_e): c lies in
+    [max(a_s - s), min(a_e - e)], and a pause of the host between the
+    annotation and the clock reading only widens that room. Returns (c,
+    by how much the bounds cross (<= 0 where they do not), the median
+    distance of a mapped start from its event's)."""
+    lo = max(a_s - s for (s, _), (a_s, _) in pairs)
+    hi = min(a_e - e for (_, e), (_, a_e) in pairs)
+    c = (lo + hi) / 2
+    gap = float(np.median([abs(a_s - (s + c)) for (s, _), (a_s, _) in pairs]))
+    return c, lo - hi, gap
+
+
+def test_spans_show_in_a_profile_only_while_it_records(tmp_path):
+    explicit = Tracer()
+    gated = Tracer(only_while_profiling=True)
+    assert not gated.enabled and explicit.enabled
+    with explicit.span("engine", "before"), gated.span("engine", "before"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert profiler_recording()
+        with explicit.span("engine", "during.explicit", slot=1):
+            with gated.span("engine", "during.default"):
+                time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    with gated.span("engine", "after"):
+        pass
+    assert [sp.name for sp in explicit.spans()] == ["before",
+                                                    "during.explicit"]
+    assert [sp.name for sp in gated.spans()] == ["during.default"]
+    host = _profile_host_events(tmp_path, {"before", "during.explicit",
+                                           "during.default", "after"})
+    assert [n for n, _, _ in host] == ["during.explicit", "during.default"]
+    (mine,) = [sp for sp in explicit.spans() if sp.name != "before"]
+    _, crossed, _ = _one_offset([((mine.start, mine.end), host[0][1:])])
+    assert crossed <= 1e-6                  # the span lies inside the event
+
+
+def test_default_tracer_records_nothing_without_a_profiler_session():
+    DEFAULT_TRACER.events.clear()
+    eng_d, out_d = _untraced()
+    assert eng_d.tracer is DEFAULT_TRACER
+    assert eng_d.pool.tracer is DEFAULT_TRACER
+    assert eng_d.scheduler.tracer is DEFAULT_TRACER
+    assert not profiler_recording()
+    eng_n, out_n = _mixed_run(tracer=NULL_TRACER)
+    assert len(DEFAULT_TRACER.events) == 0 and DEFAULT_TRACER.spans() == []
+    assert DEFAULT_TRACER.events.maxlen is not None      # bounded
+    assert out_d == out_n                       # token for token
+    # the check is the only cost: spans off hand back the shared null ctx
+    assert DEFAULT_TRACER.span("engine", "serve.tick") is \
+        DEFAULT_TRACER.span("engine", "serve.sample", rows=3)
+
+
+def test_profiled_spans_are_in_the_profile_on_one_offset(tmp_path_factory):
+    eng, out, spans, events, _ = _profiled(tmp_path_factory)
+    assert out == _untraced()[1]
+    assert eng.metrics.preemptions >= 1
+    names = {s.name for s in spans}
+    assert names == set(SPAN_PARENTS)           # the run reaches every one
+    assert {n for n, _, _ in events} == names
+    # the k-th span of a name is the k-th profile event of that name
+    by_name = {}
+    for n, s, e in events:
+        by_name.setdefault(n, []).append((s, e))
+    pairs = []
+    for name in names:
+        mine = sorted((s.start, s.end) for s in spans if s.name == name)
+        theirs = by_name[name]
+        assert len(mine) == len(theirs), name
+        pairs += zip(mine, theirs)
+    _, crossed, gap = _one_offset(pairs)
+    assert crossed <= 50e-6 and gap <= 50e-6
+    assert all(set(s.args) == {"bucket", "rows"}
+               for s in spans if s.name == "serve.prefill")
+    assert all(set(s.args) == {"n_valid"}
+               for s in spans if s.name == "pool.write_page")
+
+
+def test_profiled_spans_nest_and_close(tmp_path_factory):
+    eng, _, spans, _, events = _profiled(tmp_path_factory)
+    phases = [ev.ph for ev in events]
+    # only spans (no page-lifecycle instants), and every one closed
+    assert set(phases) == {"B", "E"}
+    assert phases.count("B") == phases.count("E") == len(spans)
+    assert not DEFAULT_TRACER.enabled and eng.pool.trace is None
+    for s in spans:
+        assert s.end >= s.start
+        assert s.parent == SPAN_PARENTS[s.name], s
+        if s.parent is not None:                # inside a span of that name
+            assert any(p.name == s.parent and p.start <= s.start
+                       and s.end <= p.end for p in spans), s
+    ticks = [s for s in spans if s.name == "serve.tick"]
+    assert len(ticks) == eng.metrics.ticks
+    assert [s.tick for s in ticks] == list(range(len(ticks)))
+
+
+def test_queue_wait_in_seconds_beside_ticks():
+    sched = AdmissionScheduler(SchedulerConfig(prefill_buckets=(8,)))
+    reqs = [Request(rid=i, prompt=[1, 2, 3]) for i in range(2)]
+    for r in reqs:
+        sched.submit(r, now=0)
+    time.sleep(0.01)
+    (a,) = sched.admit([0], active_tokens=0, free_hot_frames=8, now=3)
+    assert a.request is reqs[0] and reqs[0].queue_latency == 3
+    assert reqs[0].admit_t - reqs[0].submit_t >= 0.01
+    assert reqs[1].admit_t == -1.0                    # still queued
+    assert sched.queue_waits_s() == [reqs[0].admit_t - reqs[0].submit_t]
+    eng, _ = _untraced()
+    assert eng.snapshot()["mean_queue_wait_s"] >= 0.0
 
 
 # ======================================================================== #

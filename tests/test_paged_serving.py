@@ -255,7 +255,7 @@ def test_metrics_hook_sees_page_faults_and_throughput():
     eng.run()
     assert snaps
     for key in ("tokens_per_sec", "page_faults", "page_faults_step",
-                "shared_page_hits", "mean_queue_latency",
+                "shared_page_hits", "mean_queue_latency", "mean_queue_wait_s",
                 "preload_distance", "modeled_restore_latency_hidden"):
         assert key in snaps[-1]
     assert snaps[-1]["tokens_emitted"] == 4
