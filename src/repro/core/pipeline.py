@@ -108,8 +108,10 @@ class PreloadStream:
         """Non-blocking FIFO enqueue of block i (PRELOAD)."""
         self._copy(i).start()
 
-    def issue_if_in_range(self, i):
-        @pl.when(i < self.n_blocks)
+    def issue_if_in_range(self, i, limit=None):
+        """Issue block i if it is below `limit` (default: the stream's
+        static n_blocks; a traced trip count passes its own)."""
+        @pl.when(i < (self.n_blocks if limit is None else limit))
         def _():
             self.issue(i)
 
@@ -147,10 +149,12 @@ class UnloadStream:
         dst_blk = _block_slice(self.dst, self.index_map(i), self.block_shape)
         return pltpu.make_async_copy(self.buf.at[slot], dst_blk, self.sems.at[slot])
 
-    def slot(self, i):
-        """VMEM view for producing block i; enforces single-owner slot reuse."""
+    def slot(self, i, start=0):
+        """VMEM view for producing block i; enforces single-owner slot reuse.
+        `start` is the first block the loop produces (traced with a traced
+        `pul_loop` start)."""
         j = i - self.slots
-        @pl.when(j >= 0)
+        @pl.when(j >= start)
         def _():
             self._copy(j).wait()
         return self.buf.at[jax.lax.rem(i, self.slots)]
@@ -160,27 +164,25 @@ class UnloadStream:
         if self.cfg.unload_distance == 0:       # synchronous-flush baseline
             self._copy(i).wait()
 
-    def drain(self, produced: Optional[int] = None):
-        """Wait for every in-flight flush. `produced` = number of blocks
-        issued so far (defaults to the stream's static n_blocks)."""
+    def drain(self, produced: Optional[int] = None, start=0):
+        """Wait for every in-flight flush. `produced` = one past the last
+        block issued (defaults to the stream's static n_blocks); a traced
+        `produced` may come with the traced first block `start`."""
         n = self.n_blocks if produced is None else produced
         if self.cfg.unload_distance == 0:
             return
-        first = max(0, n - self.slots) if isinstance(n, int) else jnp.maximum(0, n - self.slots)
         if isinstance(n, int):
-            for j in range(first, n):
+            for j in range(max(0, n - self.slots), n):
                 self._copy(jnp.int32(j)).wait()
         else:
             def body(j, _):
-                @pl.when(j >= first)
-                def _w():
-                    self._copy(j).wait()
+                self._copy(j).wait()
                 return 0
-            jax.lax.fori_loop(0, n, body, 0)
+            jax.lax.fori_loop(jnp.maximum(start, n - self.slots), n, body, 0)
 
 
 def pul_loop(
-    n_blocks: int,
+    n_blocks: Union[int, jax.Array],
     preloads: Sequence[PreloadStream],
     body: Callable,                      # body(i, views: list[Ref], carry) -> carry
     carry,
@@ -188,6 +190,8 @@ def pul_loop(
     *,
     unloads: Sequence[UnloadStream] = (),
     drain: bool = True,
+    max_blocks: Optional[int] = None,
+    start: Union[int, jax.Array] = 0,
 ):
     """The steady-state PUL driver (paper Listing 1 around the compute).
 
@@ -196,8 +200,16 @@ def pul_loop(
     i+d *before* computing block i (`PL[i+d] -> compute[i]`), whereas BATCH
     issues after the compute — with 2d slots the batches double-buffer.
 
-    `n_blocks` must be static (Python int). Returns the final carry.
+    With `max_blocks` None, `n_blocks` is a static Python int and the loop
+    runs blocks 0 .. n_blocks - 1. Given the static bound `max_blocks`, the
+    trip count is read at run time instead: `n_blocks` (clamped to
+    `max_blocks`) and `start` may be traced int32 scalars, the loop runs
+    blocks start .. n_blocks - 1, and no block outside that range is
+    issued, waited on or computed. Returns the final carry.
     """
+    if max_blocks is not None:
+        return _pul_loop_bounded(n_blocks, start, max_blocks, preloads, body,
+                                 carry, cfg, unloads=unloads, drain=drain)
     if n_blocks <= 0:
         return carry
     d = min(cfg.distance, n_blocks)
@@ -223,6 +235,42 @@ def pul_loop(
     if drain:
         for u in unloads:
             u.drain()
+    return carry
+
+
+def _pul_loop_bounded(n_blocks, start, max_blocks: int, preloads, body,
+                      carry, cfg: PULConfig, *, unloads, drain: bool):
+    """`pul_loop` over blocks start .. n_blocks - 1 read at run time: the
+    warm-up window is sized by the static `max_blocks` and each of its
+    issues is guarded by the traced end, so a range shorter than the
+    distance issues only what it waits for."""
+    d = min(cfg.distance, max_blocks)
+    if d <= 0:
+        return carry
+    n = jnp.minimum(jnp.asarray(n_blocks, jnp.int32), max_blocks)
+    start = jnp.asarray(start, jnp.int32)
+
+    for s in preloads:
+        for k in range(d):
+            s.issue_if_in_range(start + k, n)
+
+    seq = cfg.strategy is IssueStrategy.SEQUENTIAL
+
+    def step(i, carry):
+        if seq:
+            for s in preloads:
+                s.issue_if_in_range(i + d, n)
+        views = [s.wait(i) for s in preloads]
+        carry = body(i, views, carry)
+        if not seq:
+            for s in preloads:
+                s.issue_if_in_range(i + d, n)
+        return carry
+
+    carry = jax.lax.fori_loop(start, n, step, carry)
+    if drain:
+        for u in unloads:
+            u.drain(n, start)
     return carry
 
 

@@ -341,6 +341,19 @@ def pul_paged_mla_decode_attention(q_abs: jax.Array, q_rope: jax.Array,
     return out[..., :kvr]
 
 
+def _kept_pages(length, P: int, n_pages: int, window: Optional[int]):
+    """The page range [first, last) whose rows the decode mask can keep for
+    a query at position `length`: up to the page of row length - 1, and
+    from the page of the window's first row. The sweeps stream only these
+    pages; a page outside them would add exactly nothing (past the length
+    its weights underflow to 0, and before the window the first kept
+    page's correction factor of 0 wipes it)."""
+    last = jnp.minimum(jax.lax.div(length + (P - 1), P), n_pages)
+    if window is None:
+        return 0, last
+    return jax.lax.div(jnp.maximum(length - window + 1, 0), P), last
+
+
 def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
                                layer_smem, q_vmem, knew_vmem, vnew_vmem,
                                k_hbm, v_hbm, o_vmem, kp_out, vp_out,
@@ -366,9 +379,9 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     v_fetch.start()
 
     # same page-table-driven stream as the per-layer kernel, with the layer
-    # scalar prepended: block t is plane row (g, pt[b, t], kv_h) — the sweep
-    # reads the SAME bytes the per-layer launch would, just without the
-    # host-side layer slice
+    # scalar prepended: block t is plane row (g, pt[b, t], kv_h), without
+    # the host-side layer slice. Only the pages the mask can keep are
+    # streamed (`_kept_pages`): an empty slot runs no iteration at all
     k_st = PreloadStream(k_hbm, kbuf, ksems,
                          index_map=lambda t: (g, pt_smem[b, t], kv_h, 0, 0),
                          cfg=cfg, n_blocks=n_pages)
@@ -406,7 +419,9 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     init = (jnp.full((G, 1), NEG_INF, jnp.float32),
             jnp.zeros((G, 1), jnp.float32),
             jnp.zeros((G, W), jnp.float32))
-    m, l, acc = pul_loop(n_pages, [k_st, v_st], body, init, cfg)
+    first, last = _kept_pages(length, P, n_pages, window)
+    m, l, acc = pul_loop(last, [k_st, v_st], body, init, cfg,
+                         max_blocks=n_pages, start=first)
     # the current token (position `length`, not yet paged) is always
     # causally visible and always inside the window
     kn = knew_vmem[0, 0].astype(jnp.float32)             # (1, W)
@@ -570,7 +585,9 @@ def _paged_sweep_mla_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     init = (jnp.full((H, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
             jnp.zeros((H, C), jnp.float32))
-    m, l, acc = pul_loop(n_pages, [c_st, r_st], body, init, cfg)
+    first, last = _kept_pages(length, P, n_pages, None)
+    m, l, acc = pul_loop(last, [c_st, r_st], body, init, cfg,
+                         max_blocks=n_pages, start=first)
     cn = cnew_vmem[0].astype(jnp.float32)                # (1, C)
     rn = rnew_vmem[0].astype(jnp.float32)                # (1, R)
     ls = (jnp.dot(qa, cn.T, preferred_element_type=jnp.float32)

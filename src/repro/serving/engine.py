@@ -323,6 +323,8 @@ class EngineMetrics:
     prefill_skips: int = 0      # admissions served entirely from shared pages
     chunk_passes: int = 0       # chunked-prefill passes (subset of prefills)
     decode_steps: int = 0
+    sweep_pages: int = 0        # pages the fused sweep streamed, per layer
+                                # and KV head, summed over decode steps
     preemptions: int = 0        # slots swapped out for a more urgent arrival
     readmissions: int = 0       # preempted requests resumed mid-stream
     slo_violations: int = 0     # first tokens emitted after their deadline
@@ -991,10 +993,20 @@ class PagedServingEngine:
             out.append(leaf if keys in pageable else flat_new[path])
         self.resident = jax.tree_util.tree_unflatten(treedef, out)
 
-    def _decode_step(self):
+    def _sweep_pages(self, live: List[int]) -> int:
+        """Pages the fused sweep streams this step for each layer and KV
+        head, summed over the live slots: a slot of length n reads pages
+        0 .. ceil(n / P) - 1 (a sliding-window layer reads fewer). 0 where
+        the engine runs no sweep."""
+        if not (self.cfg.use_paged_kernel and self.layout.features
+                and self.cfg.sweep_decode):
+            return 0
+        P = self.cfg.page_tokens
+        return sum(-(-int(self.slot_len[i]) // P) for i in live)
+
+    def _decode_step(self, live: List[int]):
         """One decode step of the live slots: prepare and launch it, fetch
         its logits (the host waits for the device here), then emit."""
-        live = self._live_slots()
         if not live:
             return
         tr = self.tracer
@@ -1161,8 +1173,11 @@ class PagedServingEngine:
             with tr.span("engine", "serve.admit"):
                 self._admit()
             self._advance_chunks()
-            with tr.span("engine", "serve.decode"):
-                faults = self._decode_step() or 0
+            live = self._live_slots()
+            pages = self._sweep_pages(live)
+            with tr.span("engine", "serve.decode", pages=pages):
+                faults = self._decode_step(live) or 0
+            self.metrics.sweep_pages += pages
             self._tick += 1
             self.metrics.ticks = self._tick
             self.metrics.wall_time += time.perf_counter() - t0
@@ -1226,9 +1241,18 @@ class PagedServingEngine:
             "modeled_restore_latency_hidden": pm.modeled_latency_hidden,
             "mean_queue_latency": mean(lat),
             "mean_queue_wait_s": mean(self.scheduler.queue_waits_s()),
+            "sweep_page_share": self._sweep_page_share(),
         }
         snap.update(extra)
         return snap
+
+    def _sweep_page_share(self) -> float:
+        """Share of the slots' whole capacity (every slot's page table,
+        every decode step) that the fused sweep streamed; 0.0 before the
+        first decode step and where the engine runs no sweep."""
+        whole = (self.metrics.decode_steps * self.cfg.batch_slots
+                 * self.n_pages_per_slot)
+        return self.metrics.sweep_pages / whole if whole else 0.0
 
     def economics(self) -> Dict[str, Any]:
         """Cache economics of the run so far: bytes moved per token emitted

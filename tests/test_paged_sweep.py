@@ -10,6 +10,9 @@ The KVStoreLayout redesign's satellite contract:
     primitive, and a jitted plane commit with donated planes aliases the
     input buffers in place (CPU buffer donation — the same mechanism the
     engine's ``_sweep_decode`` uses via ``donate_argnums``);
+  * both sweeps stream only the pages the decode mask can keep (NaN
+    frames everywhere else change no output), and the engine counts the
+    pages they stream;
   * the deprecated v1 surface (``page_views`` / ``pack_new_rows``) warns
     ``PendingDeprecationWarning`` and no in-repo caller reaches it.
 """
@@ -142,6 +145,123 @@ def test_sweep_parity_mid_chunk_preempt_resume():
     for eng in engines:
         assert eng.metrics.preemptions == 1 and eng.metrics.readmissions == 1
         assert eng.pool.metrics.page_faults >= 1    # resumed through cold
+
+
+# ======================================================================== #
+# the sweep streams only the pages the mask can keep
+# ======================================================================== #
+
+_P, _N_PAGES, _WINDOW = 8, 8, 19
+_MAX_SEQ = _P * _N_PAGES
+_LENGTHS = (0, 1, _P - 1, _P, _P + 1, _MAX_SEQ - 1)
+_B = len(_LENGTHS)
+# frames: 0 zeros, 1 NaN, then each slot's commit frame, then data pages
+_ZERO, _NAN = 0, 1
+
+
+def _page_tables(window):
+    """(clean, poisoned) page tables over the frames above: slot b's page
+    t < ceil(len/P) sits in data frame 2 + B + b * n_pages + t. Past the
+    length the clean table points at the zero frame, the poisoned one at
+    the NaN frame, which it also gives every page before the window's
+    first."""
+    clean = np.full((_B, _N_PAGES), _ZERO, np.int32)
+    poisoned = np.full((_B, _N_PAGES), _NAN, np.int32)
+    for b, n in enumerate(_LENGTHS):
+        first = 0 if window is None else max(0, n - window + 1) // _P
+        for t in range(-(-n // _P)):
+            clean[b, t] = 2 + _B + b * _N_PAGES + t
+            if t >= first:
+                poisoned[b, t] = clean[b, t]
+    return jnp.asarray(clean), jnp.asarray(poisoned)
+
+
+def _frames(key, shape):
+    """(L, NF, ...) bf16 planes: random, the zero frame zero, the NaN frame
+    NaN."""
+    x = jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+    return x.at[:, _ZERO].set(0).at[:, _NAN].set(jnp.nan)
+
+
+@pytest.mark.parametrize("kind,window", [("gqa", None), ("gqa", _WINDOW),
+                                         ("mla", None)])
+def test_sweep_never_reads_pages_past_the_mask(kind, window):
+    """Every page-table entry the decode mask cannot keep (past ceil(len/P),
+    before a window's first page) points at a NaN frame. A sweep that
+    streamed it would poison its accumulator (0 * NaN); the sweep that
+    skips it gives outputs bitwise equal to the same call on a clean table
+    and to the per-layer kernel there."""
+    from repro.core import PULConfig
+    from repro.kernels import (
+        pul_paged_decode_attention, pul_paged_mla_decode_attention,
+        pul_paged_sweep_decode_attention,
+        pul_paged_sweep_mla_decode_attention)
+    L, layer, NF = 2, 1, 2 + _B + _B * _N_PAGES
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    lengths = jnp.asarray(_LENGTHS, jnp.int32)
+    frames = jnp.arange(2, 2 + _B, dtype=jnp.int32)
+    offsets = lengths % _P
+    clean, poisoned = _page_tables(window)
+    cfg = PULConfig(distance=2)
+    if kind == "gqa":
+        H, K, W = 4, 2, 32
+        q = jax.random.normal(ks[0], (_B, H, W), jnp.float32)
+        kp = _frames(ks[1], (L, NF, K, _P, W))
+        vp = _frames(ks[2], (L, NF, K, _P, W))
+        kn = jax.random.normal(ks[3], (_B, K, W)).astype(jnp.bfloat16)
+        vn = jax.random.normal(ks[4], (_B, K, W)).astype(jnp.bfloat16)
+
+        def sweep(pt):
+            return pul_paged_sweep_decode_attention(
+                q, kp, vp, layer, pt, lengths, kn, vn, frames, offsets,
+                cfg=cfg, window=window)[0]
+        per_layer = pul_paged_decode_attention(
+            q, kp[layer], vp[layer], clean, lengths, cfg=cfg, window=window,
+            k_new=kn, v_new=vn)
+    else:
+        H, C, R, scale = 4, 32, 16, 0.2
+        qa = jax.random.normal(ks[0], (_B, H, C), jnp.float32)
+        qr = jax.random.normal(ks[1], (_B, H, R), jnp.float32)
+        cp = _frames(ks[2], (L, NF, _P, C))
+        rp = _frames(ks[3], (L, NF, _P, R))
+        cn = jax.random.normal(ks[4], (_B, C)).astype(jnp.bfloat16)
+        rn = jax.random.normal(ks[5], (_B, R)).astype(jnp.bfloat16)
+
+        def sweep(pt):
+            return pul_paged_sweep_mla_decode_attention(
+                qa, qr, cp, rp, layer, pt, lengths, cn, rn, frames, offsets,
+                scale=scale, cfg=cfg)[0]
+        per_layer = pul_paged_mla_decode_attention(
+            qa, qr, cp[layer], rp[layer], clean, lengths, cn, rn,
+            scale=scale, cfg=cfg)
+    got = np.asarray(sweep(poisoned))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, np.asarray(sweep(clean)))
+    np.testing.assert_array_equal(got, np.asarray(per_layer))
+
+
+def test_sweep_page_counter_matches_hand_count():
+    """Two live slots of known lengths and one empty: prompts of 7 and 17
+    tokens decode 3 steps each at lengths 7, 8, 9 and 17, 18, 19, so with
+    pages of 8 the sweep streams 1 + 3, 1 + 3 and 2 + 3 pages a step (the
+    empty slot none), out of 3 slots x 8 pages a step."""
+    from repro.obs import Tracer
+    cfg, _, params = _model("qwen3-1.7b")
+    tracer = Tracer()
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=3, max_seq=64, page_tokens=8, prefill_buckets=(8, 16, 32),
+        use_paged_kernel=True), tracer=tracer)
+    rng = np.random.default_rng(3)
+    for rid, n in enumerate((7, 17)):
+        eng.submit(Request(
+            rid=rid, prompt=rng.integers(1, cfg.vocab_size, size=n).tolist(),
+            max_new_tokens=4))
+    eng.run()
+    assert [sp.args["pages"] for sp in tracer.spans()
+            if sp.name == "serve.decode"] == [4, 4, 5]
+    assert eng.metrics.decode_steps == 3
+    assert eng.metrics.sweep_pages == 13
+    assert eng.snapshot()["sweep_page_share"] == 13 / (3 * 3 * 8)
 
 
 # ======================================================================== #

@@ -78,13 +78,16 @@ def _compile(fn, *args, **jit_kw):
     return compiled
 
 
-def test_gqa_sweep_kernel_compiles(spec):
+@pytest.mark.parametrize("window", [None, 1024])
+def test_gqa_sweep_kernel_compiles(spec, window):
+    """The sweep's trip count, and on a sliding-window layer its first
+    page, are read from the SMEM length at run time."""
     cfg, (kp, vp) = _planes("qwen3-1.7b", spec)
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     assert kp.shape == (cfg.num_layers, NF, K, P, hd)
     vec = spec((B,), I32)
     _compile(functools.partial(pul_paged_sweep_decode_attention, cfg=PUL,
-                               interpret=False),
+                               window=window, interpret=False),
              spec((B, H, hd), BF16), kp, vp, spec((), I32),
              spec((B, N_PAGES), I32), vec, spec((B, K, hd), BF16),
              spec((B, K, hd), BF16), vec, vec)
