@@ -1,10 +1,13 @@
-"""The one module that speaks to the program under test (`repro`).
+"""The harness's side of the program under test (`repro`), the same for
+every architecture family.
 
 Everything the harness needs from the program goes through here: its model
-configuration built from the configuration file, the benchmark's weights
-placed in its parameter tree, its paged serving engine, requests, counters,
-and the compile cache directory its entry points use. Reading the program's
-state is limited to public attributes, read once after the measured window.
+configuration and parameter tree, which the configuration's family maps
+(`adapters/<family>.py`, found by `discover.family`), its paged serving
+engine, requests, counters, and the compile cache directory its entry
+points use. Besides this module, only the family adapters and
+`program_spans.py` import `repro`. Reading the program's state is limited
+to public attributes, read once after the measured window.
 """
 from __future__ import annotations
 
@@ -17,9 +20,8 @@ if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
-from repro.configs import get_config  # noqa: E402
+from chipbench import discover  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402,F401
 from repro.models import build_model  # noqa: E402
 from repro.serving import PagedServingEngine, Request, ServingConfig  # noqa: E402
@@ -27,27 +29,8 @@ from repro.serving import PagedServingEngine, Request, ServingConfig  # noqa: E4
 
 def model_config(name: str, arch: dict):
     """The program's config of registry entry `name`, with every size and
-    option taken from the configuration file `arch`."""
-    base = get_config(name)
-    cfg = dataclasses.replace(
-        base,
-        num_layers=arch["num_hidden_layers"],
-        d_model=arch["hidden_size"],
-        num_heads=arch["num_attention_heads"],
-        num_kv_heads=arch["num_key_value_heads"],
-        head_dim=arch["head_dim"],
-        d_ff=arch["intermediate_size"],
-        vocab_size=arch["vocab_size"],
-        norm_eps=arch["rms_norm_eps"],
-        rope_theta=float(arch["rope_theta"]),
-        tie_embeddings=arch["tie_word_embeddings"],
-        qkv_bias=arch["qkv_bias"],
-        qk_norm=arch["qk_norm"],
-        vocab_chunk=arch.get("vocab_chunk", base.vocab_chunk))
-    if (cfg.family, cfg.attn_type, cfg.pattern, cfg.mlp_type) != (
-            "dense", "gqa", ("global",), "swiglu"):
-        raise ValueError(f"{name}: not a dense GQA SwiGLU decoder")
-    return cfg
+    option taken from the configuration file `arch`, by its family."""
+    return discover.family(arch).adapter.model_config(name, arch)
 
 
 def vocab_rows(cfg) -> int:
@@ -55,27 +38,11 @@ def vocab_rows(cfg) -> int:
     return cfg.padded_vocab
 
 
-def params(cfg, w: dict):
-    """The benchmark's weights `w` (chipbench/weights.py layout) in the
-    program's parameter tree. The program's RMSNorm scales by (1 + s), so
-    a norm weight g is handed over as g - 1; matmul weights are the same
-    buffers."""
-    one = lambda g: g - 1.0
-    attn = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
-    if cfg.qkv_bias:
-        attn.update(bq=w["bq"], bk=w["bk"], bv=w["bv"])
-    if cfg.qk_norm:
-        attn.update(q_norm=one(w["q_norm"]), k_norm=one(w["k_norm"]))
-    tree = {
-        "embedding": {"table": w["embed"]},
-        "final_norm": one(w["final_norm"]),
-        "groups": {"0:global": {
-            "norm1": one(w["attn_norm"]), "norm2": one(w["mlp_norm"]),
-            "attn": attn,
-            "mlp": {k: w[k] for k in ("w_gate", "w_up", "w_down")}}},
-    }
-    if not cfg.tie_embeddings:
-        tree["embedding"]["lm_head"] = w["lm_head"]
+def params(arch: dict, cfg, w: dict):
+    """The benchmark's weights `w` (the family reference's layout) in the
+    program's parameter tree, mapped by the family; checked leaf by leaf
+    against the tree the program's model expects."""
+    tree = discover.family(arch).adapter.params(cfg, w)
     want = build_model(cfg).abstract_params()
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
     if jax.tree.structure(want) != jax.tree.structure(got) or any(

@@ -5,16 +5,24 @@
   traffic mix     chipbench/traffic/<mix>.json
   generator kind  chipbench/generators/<kind>.py   (named by the mix)
   metric          chipbench/metrics/<metric>.py    (a `read(ctx)` function)
+  family          the configuration's "family" key names two files:
+    reference     chipbench/reference/<family>.py  (float32 reference, weight
+                                                    layout, FLOPs and bytes)
+    adapter       chipbench/adapters/<family>.py   (the program's config and
+                                                    parameter tree)
 
 BENCHMARK.json, at the root of the repository, says which end-to-end and
 per-layer metrics each cell reports. A new cell, configuration, mix,
-generator or metric is a new file and a new entry; no file here changes.
+generator, metric or architecture family is a new file and a new entry; no
+file here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from typing import NamedTuple
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -27,8 +35,9 @@ def _json(path: Path) -> dict:
 def _module(path: Path):
     if not path.is_file():
         raise FileNotFoundError(path)
+    name = f"chipbench_{path.parent.name}_{path.stem}"
     spec = importlib.util.spec_from_file_location(
-        "chipbench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -62,3 +71,28 @@ def metric_names(bench: dict, workload: str, group: str) -> list:
 
 def metric(name: str, base: Path = BENCH):
     return _module(base / "metrics" / f"{name}.py")
+
+
+class Family(NamedTuple):
+    name: str
+    reference: object     # reference/<family>.py: hidden, head_stats, shapes,
+                          # prompt_flops, decode_flops, sweep_work
+    adapter: object       # adapters/<family>.py: model_config, params
+
+
+def family(arch: dict, base: Path = BENCH) -> Family:
+    """The architecture family the configuration `arch` names. Both of its
+    files are looked for before either is loaded, so a missing one fails
+    at once, naming its path."""
+    return _family(arch["family"], base)
+
+
+@functools.lru_cache(maxsize=None)
+def _family(name: str, base: Path) -> Family:
+    paths = (base / "reference" / f"{name}.py",
+             base / "adapters" / f"{name}.py")
+    for path in paths:
+        if not path.is_file():
+            raise FileNotFoundError(
+                f"architecture family {name!r}: no file {path}")
+    return Family(name, *map(_module, paths))

@@ -13,23 +13,69 @@ cache or batching, and every matrix product at `Precision.HIGHEST`:
               x += (silu(h Wg) * h Wu) Wd
   logits = rmsnorm(x) * w_final_norm @ head^T        (head = embed if tied)
 
-It reads the configuration file's keys and the weight dict of
-`chipbench/weights.py`, and imports nothing of the program under test. One
-sequence runs at a time, layer after layer inside one scan, so the largest
-temporaries are one layer's float32 weights and one head's (T, T) scores.
+It reads the configuration file's keys and the weights that
+`chipbench/weights.py` makes to `shapes` below, the family's layout, and
+imports nothing of the program under test. One sequence runs at a time,
+layer after layer inside one scan, so the largest temporaries are one
+layer's float32 weights and one head's (T, T) scores.
 
 `quant` switches the control on: every matmul weight goes through float8
 (e4m3, one scale per output channel) before the float32 product.
+
+The same equations give the work the metrics divide by: `prompt_flops`,
+`decode_flops` and `sweep_work` count each multiply-add as 2 and only the
+work the answer needs: the projections, the MLP and causal attention over
+the positions already written, plus the output head once for each token
+whose logits are used (a prompt's last position and every generated
+token). Recomputation, padding, masked blocks and empty slots do not count.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0            # largest finite float8_e4m3fn
+BIAS_STD = 0.1             # q/k/v biases (Qwen2): large enough to move the
+                           # logits
+EMBED_STD = 0.02           # embedding and output head rows
+BF16 = 2
+
+
+def shapes(arch: dict, vocab_rows: int) -> dict:
+    """The weight layout: {name: (shape, dtype, std, or None for a norm
+    weight)}. Per-layer tensors are stacked on a leading layer axis; norm
+    weights are plain RMSNorm scales."""
+    L, D = arch["num_hidden_layers"], arch["hidden_size"]
+    H, K = arch["num_attention_heads"], arch["num_key_value_heads"]
+    hd, F = arch["head_dim"], arch["intermediate_size"]
+    bf, f32 = jnp.bfloat16, jnp.float32
+    out = {
+        "embed": ((vocab_rows, D), bf, EMBED_STD),
+        "final_norm": ((D,), f32, None),
+        "attn_norm": ((L, D), f32, None),
+        "mlp_norm": ((L, D), f32, None),
+        "wq": ((L, D, H, hd), bf, 1 / math.sqrt(D)),
+        "wk": ((L, D, K, hd), bf, 1 / math.sqrt(D)),
+        "wv": ((L, D, K, hd), bf, 1 / math.sqrt(D)),
+        "wo": ((L, H, hd, D), bf, 1 / math.sqrt(H * hd)),
+        "w_gate": ((L, D, F), bf, 1 / math.sqrt(D)),
+        "w_up": ((L, D, F), bf, 1 / math.sqrt(D)),
+        "w_down": ((L, F, D), bf, 1 / math.sqrt(F)),
+    }
+    if not arch["tie_word_embeddings"]:
+        out["lm_head"] = ((vocab_rows, D), bf, EMBED_STD)
+    if arch["qkv_bias"]:
+        out["bq"] = ((L, H, hd), bf, BIAS_STD)
+        out["bk"] = ((L, K, hd), bf, BIAS_STD)
+        out["bv"] = ((L, K, hd), bf, BIAS_STD)
+    if arch["qk_norm"]:
+        out["q_norm"] = ((L, hd), f32, None)
+        out["k_norm"] = ((L, hd), f32, None)
+    return out
 
 
 def fp8(w, axes):
@@ -173,3 +219,48 @@ def head_stats(arch: dict, w: dict, h, targets, *, quant: bool = False):
     rows = min(256, h.shape[0])
     return _head_stats(w, h, jnp.asarray(targets, jnp.int32),
                        arch_items=_items(arch), quant=quant, rows=rows)
+
+
+# --------------------------------------------------------------------------
+# work: model FLOPs and HBM bytes
+# --------------------------------------------------------------------------
+def body_params(arch: dict) -> int:
+    """Matmul weights per token of all layers (embedding gather excluded)."""
+    D, H = arch["hidden_size"], arch["num_attention_heads"]
+    K, hd = arch["num_key_value_heads"], arch["head_dim"]
+    F = arch["intermediate_size"]
+    per_layer = D * (H + 2 * K) * hd + H * hd * D + 3 * D * F
+    return arch["num_hidden_layers"] * per_layer
+
+
+def head_params(arch: dict) -> int:
+    return arch["vocab_size"] * arch["hidden_size"]
+
+
+def attn_flops(arch: dict, keys: int) -> float:
+    """QK^T and PV of one query over `keys` positions, all layers."""
+    return (4.0 * arch["num_attention_heads"] * arch["head_dim"]
+            * arch["num_hidden_layers"] * keys)
+
+
+def prompt_flops(arch: dict, n: int) -> float:
+    """A prefill of an n-token prompt: every position through the body,
+    causal attention, and the head at the last position."""
+    causal_keys = n * (n + 1) / 2
+    return (2.0 * body_params(arch) * n + 2.0 * head_params(arch)
+            + attn_flops(arch, causal_keys))
+
+
+def decode_flops(arch: dict, cached: int) -> float:
+    """One generated token attending to `cached` earlier positions and
+    itself."""
+    return (2.0 * (body_params(arch) + head_params(arch))
+            + attn_flops(arch, cached + 1))
+
+
+def sweep_work(arch: dict, cached: int) -> tuple:
+    """(FLOPs, HBM bytes) the decode attention needs for one slot with
+    `cached` positions in its pages: read each cached K and V row once."""
+    L, K = arch["num_hidden_layers"], arch["num_key_value_heads"]
+    hd = arch["head_dim"]
+    return (attn_flops(arch, cached), float(cached * L * 2 * K * hd * BF16))

@@ -9,13 +9,13 @@ program's paged serving engine, warms up every shape the cell's traffic
 uses, then drives the engine's `submit` and `step` from a seeded open-loop
 schedule in this process: the traffic's warm period, then `--seconds` of
 measured window. Each request is timed from its due time. After the window
-it checks what the engine served against the float32 reference
-(chipbench/reference/) and prints, as the last line of standard output, one
-JSON object: `correct`, `attempted`, `failed`, `metrics`, `device`, with
-`--trace 1` also `breakdown`, and last `check`, the numbers compared with
-their limits. `--trace 0` reports the cell's end-to-end metrics; `--trace 1`
-traces the start of the window with the profiler and reports its per-layer
-metrics.
+it checks what the engine served against the float32 reference of the
+configuration's family (chipbench/reference/<family>.py) and prints, as the
+last line of standard output, one JSON object: `correct`, `attempted`,
+`failed`, `metrics`, `device`, with `--trace 1` also `breakdown`, and last
+`check`, the numbers compared with their limits. `--trace 0` reports the
+cell's end-to-end metrics; `--trace 1` traces the start of the window with
+the profiler and reports its per-layer metrics.
 """
 from __future__ import annotations
 
@@ -260,7 +260,7 @@ def served_gaps(arch, w, picks, max_seq: int, *, control: bool = False):
     logit lies below the reference's best. With `control`, the served
     tokens are replaced by the argmax of the float8 control at the same
     positions of the same sequences."""
-    from chipbench.reference import dense_gqa as R
+    R = discover.family(arch).reference
     out = []
     for r in picks:
         seq = r["prompt"] + r["out"]
@@ -294,6 +294,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     bench = discover.benchmark()
     c = discover.cell(workload)
     cell, mix, arch = cell or c["cell"], mix or c["mix"], arch or c["arch"]
+    discover.family(arch)       # a family with a file missing fails here
     devs = (require_chips(jax, cell["chips"]) if require_tpu
             else jax.devices())
     from chipbench import adapter as A
@@ -308,7 +309,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         mix, rate=cell["rate_per_s"], seconds=seconds, seed=seed,
         vocab=arch["vocab_size"], max_total=cell["max_seq"] - 1)
     w = weights.make(arch, seed, A.vocab_rows(cfg))
-    eng = A.engine(cfg, A.params(cfg, w), cell)
+    eng = A.engine(cfg, A.params(arch, cfg, w), cell)
     preempts = len({k["priority"] for k in mix["classes"]}) > 1
     warm_up(A, eng, cell, arch["vocab_size"], preempts)
     A.wait(eng)

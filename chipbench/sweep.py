@@ -41,7 +41,7 @@ def sweep(workload, seed, rates, seconds, *, arch=None, cell=None, mix=None,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     clock = R.CompileClock(jax)
     cfg = A.model_config(cell["config"], arch)
-    tree = A.params(cfg, weights.make(arch, seed, A.vocab_rows(cfg)))
+    tree = A.params(arch, cfg, weights.make(arch, seed, A.vocab_rows(cfg)))
     preempts = len({k["priority"] for k in mix["classes"]}) > 1
     out = []
     for rate in rates:
