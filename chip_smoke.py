@@ -110,15 +110,30 @@ def smoke_prompts(vocab: int, seed: int):
 
 
 def record_paged_logits(eng):
-    """{rid: [logits per emitted token]} of a PagedServingEngine."""
-    logs, emit = {}, eng._emit_token
+    """{rid: [logits per emitted token]} of a PagedServingEngine: a first
+    token's logits as the host picks it, a decode step's rows as the sweep
+    returns them (the slots with a commit frame; copying them to the host
+    blocks the loop, which a smoke run can afford)."""
+    from repro.serving.kv_pages import TRASH_FRAME
+    logs, emit, sweep = {}, eng._emit_token, eng._sweep_decode
 
-    def emit_token(slot, logits):
+    def note(slot, logits):
         logs.setdefault(eng.slot_req[slot].rid, []).append(
             np.asarray(logits, np.float32))
+
+    def emit_token(slot, logits):
+        note(slot, logits)
         emit(slot, logits)
 
+    def sweep_decode(params, batch, tree, planes):
+        out = sweep(params, batch, tree, planes)
+        rows = np.asarray(out[0], np.float32)
+        for slot in np.flatnonzero(np.asarray(batch["frames"]) != TRASH_FRAME):
+            note(int(slot), rows[slot])
+        return out
+
     eng._emit_token = emit_token
+    eng._sweep_decode = sweep_decode
     return logs
 
 

@@ -29,6 +29,16 @@ Decode runs one of two equivalent paths:
     and is scattered into its tail page afterwards. No dense per-slot view
     is ever materialized — the serving realization of the paper's claim.
 
+Greedy decode keeps one step in flight: the host dispatches step t+1 before
+it reads step t's tokens. Step t's argmax stays on the device and feeds step
+t+1 directly (`_next_tokens`), so only B int32 tokens ever reach the host,
+read while the next step runs. Whether a slot finishes is known before its
+token is (the engine stops on `max_new_tokens` or `max_seq`, never on a
+token's value), so no step ever runs for a finished slot. Whatever reads or
+moves a slot's tokens, length or pages outside the loop (preemption, pause,
+resume, the end of `run`) first settles the step in flight. Sampling
+(``greedy=False``) keeps the serial loop: the host draws from the logits.
+
 Fully-shared prompts are cheaper still: when a request's whole page-aligned
 prompt already lives in shared pages, admission refs the pages and replays
 the cached first-token logits — zero prefill compute (`prefill_skips`).
@@ -120,7 +130,10 @@ def mean(xs) -> float:
 def _set_idx(tree, idx: np.ndarray):
     """Overwrite every cache `idx` leaf with per-slot fill levels."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    vec = jnp.asarray(idx, jnp.int32)
+    # from a copy: the device may read the host buffer it is given after
+    # this returns (zero-copy on the CPU), while the engine updates `idx`
+    # in place with a step still in flight
+    vec = jnp.asarray(np.array(idx, np.int32))
     out = []
     for path, leaf in flat:
         keys = _path_keys(path)
@@ -328,6 +341,11 @@ class EngineMetrics:
     preemptions: int = 0        # slots swapped out for a more urgent arrival
     readmissions: int = 0       # preempted requests resumed mid-stream
     slo_violations: int = 0     # first tokens emitted after their deadline
+    inflight_steps: int = 0     # decode steps dispatched while the previous
+                                # step's tokens were still on the device
+    settles: int = 0            # in-flight steps read with no next step
+                                # dispatched behind them (preemption, pause,
+                                # resume, nothing left to decode, run's end)
     wall_time: float = 0.0
 
     @property
@@ -337,6 +355,16 @@ class EngineMetrics:
         if self.wall_time <= 0.0:
             return 0.0
         return self.tokens_emitted / self.wall_time
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A dispatched greedy decode step whose tokens are still on the
+    device."""
+    logits: Any             # (B, V), on the device
+    live: List[int]         # the slots it decodes
+    lens: np.ndarray        # every slot's fill level once it ran
+    done: frozenset = frozenset()   # slots its token finishes
 
 
 class PagedServingEngine:
@@ -430,6 +458,19 @@ class PagedServingEngine:
         # store per step); returns (logits, new_tree, planes)
         self._sweep_decode = jax.jit(sweep_paged_decode, donate_argnums=(3,))
 
+        def next_tokens(logits, host_toks, from_host):
+            # ties go to the first index, as with np.argmax on the host
+            best = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            return jnp.where(from_host, host_toks, best)
+
+        # greedy inputs of the next decode step, (B, 1) int32: the previous
+        # step's argmax, or the host's token where a prefill, a shared-
+        # prefix admission or a resume produced it
+        self._next_tokens = jax.jit(next_tokens)
+        self._inflight: Optional[_InFlight] = None
+        self._all_from_step = (jnp.zeros((B, 1), jnp.int32),
+                               jnp.zeros((B, 1), bool))
+
         # slot state
         self.slot_req: List[Optional[Request]] = [None] * B
         self.slot_len = np.zeros((B,), np.int32)    # tokens cached per slot
@@ -498,11 +539,13 @@ class PagedServingEngine:
 
     # ------------------------------------------------------------------ #
     def _live_slots(self) -> List[int]:
-        """Slots that decode this tick: occupied, not paused, and not still
-        mid-chunked-prefill (a chunking slot has no first token yet)."""
+        """Slots that decode this tick: occupied, not paused, not still
+        mid-chunked-prefill (a chunking slot has no first token yet), and
+        not finished by the token of the step in flight."""
+        done = self._inflight.done if self._inflight is not None else ()
         return [i for i, r in enumerate(self.slot_req)
                 if r is not None and not self.paused[i]
-                and i not in self._chunk]
+                and i not in self._chunk and i not in done]
 
     def _active_tokens(self) -> int:
         """Budget charge of the live batch — the SAME cost function the
@@ -534,14 +577,22 @@ class PagedServingEngine:
         # preemptive policies: while the policy-ordered head is still queued
         # and a running victim should yield, swap the victim out (pages to
         # the cold tier, request requeued) and retry admission. Bounded by
-        # the slot count — at most one preemption per occupied slot per tick.
-        for _ in range(len(self.slot_req)):
+        # the slot count — at most one preemption per occupied slot per tick,
+        # and one settle of the step in flight before the first.
+        for _ in range(len(self.slot_req) + 1):
             cand = self.scheduler.head()
             if cand is None:
                 return
             victim = self._preemption_victim(cand)
             if victim is None:
                 return
+            if self._inflight is not None:
+                # the step in flight may finish slots, and a victim's stream
+                # must be whole before it is swapped out: settle, refill
+                # what it freed, then look for a victim again
+                self._settle()
+                self._place(self._run_admission())
+                continue
             if self.tracer.enabled:
                 policy = self.scheduler.cfg.policy
                 self.tracer.decision(
@@ -965,7 +1016,7 @@ class PagedServingEngine:
             # (events must precede the write they describe)
             self.pool.note_fused_commit(frames, offs)
             logits, new_tree, planes = self._sweep_decode(
-                self.params, {"tokens": jnp.asarray(toks),
+                self.params, {"tokens": toks,
                               "pos0": jnp.asarray(pos0),
                               "page_table": jnp.asarray(page_table),
                               "frames": jnp.asarray(frames),
@@ -976,7 +1027,7 @@ class PagedServingEngine:
         tree = self.layout.page_view_tree(self.resident, self.pool.planes)
         tree = _set_idx(tree, self.slot_len)
         return self._paged_decode(
-            self.params, {"tokens": jnp.asarray(toks),
+            self.params, {"tokens": toks,
                           "pos0": jnp.asarray(pos0),
                           "page_table": jnp.asarray(page_table)}, tree)
 
@@ -1005,13 +1056,47 @@ class PagedServingEngine:
         return sum(-(-int(self.slot_len[i]) // P) for i in live)
 
     def _decode_step(self, live: List[int]):
-        """One decode step of the live slots: prepare and launch it, fetch
-        its logits (the host waits for the device here), then emit."""
+        """One decode step of the live slots.
+
+        Greedy: prepare and dispatch it, then read the int32 tokens of the
+        step the previous call left in flight (the host waits here only for
+        what the device finished while this step was being prepared), emit
+        them, and leave this step in flight. With no slot left to decode,
+        the step in flight is settled. Sampling: prepare and launch the
+        step, fetch its logits, draw and emit."""
+        if not self.cfg.greedy:
+            return self._sampled_step(live)
+        prev, self._inflight = self._inflight, None
+        tr = self.tracer
+        faults, toks = 0, None
+        if live:
+            with tr.span("engine", "serve.decode.prepare"):
+                faults, logits, toks = self._launch_decode(live, prev)
+                self.slot_len[live] += 1
+                self._inflight = _InFlight(logits, live, self.slot_len.copy())
+                if prev is not None:
+                    self.metrics.inflight_steps += 1
+        if prev is not None:
+            with tr.span("engine", "serve.decode.fetch"):
+                got = self._read_tokens(prev, toks)
+            with tr.span("engine", "serve.sample"):
+                self._emit_step(prev, got)
+        step = self._inflight
+        if step is not None:
+            step.done = frozenset(
+                i for i in live if self._finishes(
+                    self.slot_req[i], len(self.slot_req[i].out_tokens) + 1,
+                    int(step.lens[i])))
+        return faults
+
+    def _sampled_step(self, live: List[int]):
+        """The serial loop of ``greedy=False``: the host draws each token
+        from the step's logits before the next step is prepared."""
         if not live:
             return
         tr = self.tracer
         with tr.span("engine", "serve.decode.prepare"):
-            faults, logits = self._launch_decode(live)
+            faults, logits, _ = self._launch_decode(live)
         with tr.span("engine", "serve.decode.fetch"):
             logits = np.asarray(logits)
         with tr.span("engine", "serve.sample"):
@@ -1020,19 +1105,60 @@ class PagedServingEngine:
                 self._emit_token(i, logits[i])
         return faults
 
-    def _launch_decode(self, live: List[int]):
-        """Tail pages, restores, page table and commit coordinates, then
-        the dispatch of the decode step; returns (page faults, the step's
-        logits on the device)."""
+    def _settle(self):
+        """Read and emit the tokens of the step in flight, with no step
+        dispatched behind it: before anything outside the decode loop
+        reads or moves a slot's tokens, length or pages."""
+        prev, self._inflight = self._inflight, None
+        if prev is not None:
+            self._emit_step(prev, self._read_tokens(prev))
+
+    def _read_tokens(self, step: _InFlight, toks=None) -> np.ndarray:
+        """A greedy step's tokens on the host, (B, 1) with the step's
+        argmax on its rows: from `toks`, the next step's inputs, or where
+        no step was dispatched behind it, read on their own (a settle)."""
+        if toks is None:
+            self.metrics.settles += 1
+            toks = self._next_tokens(step.logits, *self._all_from_step)
+        return np.asarray(toks)
+
+    def _emit_step(self, step: _InFlight, toks: np.ndarray):
+        """Emit a greedy step's tokens (`toks` (B, 1), the step's argmax on
+        its rows) at the fill levels the step left."""
+        for i in step.live:
+            self._append_token(i, int(toks[i, 0]), int(step.lens[i]))
+
+    def _step_inputs(self, live: List[int], prev: Optional[_InFlight]):
+        """The decode step's (B, 1) int32 input tokens on the device: the
+        previous step's argmax for the slots it decoded, the host's last
+        token for the rest (0 where a slot does not decode)."""
+        B = self.cfg.batch_slots
+        host = np.zeros((B, 1), np.int32)
+        from_host = np.ones((B, 1), bool)
+        if prev is not None:
+            from_host[prev.live] = False
+        for i in live:
+            if from_host[i, 0]:
+                host[i, 0] = self.slot_req[i].out_tokens[-1]
+        if prev is None:
+            return jnp.asarray(host)
+        toks = self._next_tokens(prev.logits, host, from_host)
+        toks.copy_to_host_async()      # the host reads it while step t runs
+        return toks
+
+    def _launch_decode(self, live: List[int],
+                       prev: Optional[_InFlight] = None):
+        """Tail pages, restores, input tokens, page table and commit
+        coordinates, then the dispatch of the decode step; returns (page
+        faults, the step's logits on the device, its input tokens)."""
         B = self.cfg.batch_slots
         self._ensure_tail_pages()
         needed = sorted({pid for i in live for pid in self.slot_pages[i]})
         faults = self.pool.ensure_hot(needed)
 
-        toks = np.zeros((B, 1), np.int32)
+        toks = self._step_inputs(live, prev)
         pos0 = np.zeros((B,), np.int32)
         for i in live:
-            toks[i, 0] = self.slot_req[i].out_tokens[-1]
             pos0[i] = self.slot_len[i]
         # tail-page commit coordinates for every slot this step (TRASH sink
         # for slots not decoding); the fused sweep needs them BEFORE launch
@@ -1053,7 +1179,7 @@ class PagedServingEngine:
         else:
             tree = self._assemble()
             logits, new_tree = self._decode(
-                self.params, {"tokens": jnp.asarray(toks),
+                self.params, {"tokens": toks,
                               "pos0": jnp.asarray(pos0)}, tree)
         self.metrics.decode_steps += 1
 
@@ -1061,31 +1187,43 @@ class PagedServingEngine:
         # (the sweep already committed them in its fused epilogue)
         if self.layout.features and not sweep:
             rows = (self.layout._pack_new_rows_impl(new_tree) if kernel_true
-                    else self.layout.pack_rows(new_tree,
-                                               jnp.asarray(self.slot_len)))
+                    else self.layout.pack_rows(
+                        new_tree, jnp.asarray(self.slot_len.copy())))
             self.pool.write_rows(frames, offs, rows)
         if kernel_true:
             self._merge_nonpageable(new_tree)
         else:
             self.resident = new_tree
-        return faults, logits
+        return faults, logits, toks
+
+    def _finishes(self, r: Request, n_out: int, length: int) -> bool:
+        """Whether a request with `n_out` tokens out, the last at fill
+        level `length`, is done: it has all it asked for, or its next
+        token would have no room."""
+        return n_out >= r.max_new_tokens or length + 1 >= self.cfg.max_seq
 
     def _emit_token(self, slot: int, logits: np.ndarray):
-        r = self.slot_req[slot]
+        """Pick a slot's next token on the host from its logits (a
+        prefill's first token, or a sampled decode step) and emit it."""
         if self.cfg.greedy:
             nxt = int(np.argmax(logits))
         else:
             z = logits.astype(np.float64) - logits.max()
             p = np.exp(z)
             nxt = int(self._rng.choice(p.shape[-1], p=p / p.sum()))
-        r.out_tokens.append(nxt)
+        self._append_token(slot, nxt, int(self.slot_len[slot]))
+
+    def _append_token(self, slot: int, tok: int, length: int):
+        """Emit `tok`, produced at fill level `length`; finish the slot
+        when that was its last."""
+        r = self.slot_req[slot]
+        r.out_tokens.append(tok)
         self.metrics.tokens_emitted += 1
         if r.first_token_tick < 0:
             r.first_token_tick = self._tick
             if r.ttft_deadline >= 0 and r.ttft > r.ttft_deadline:
                 self.metrics.slo_violations += 1
-        out_of_room = int(self.slot_len[slot]) + 1 >= self.cfg.max_seq
-        if len(r.out_tokens) >= r.max_new_tokens or out_of_room:
+        if self._finishes(r, len(r.out_tokens), length):
             self._finish(slot)
 
     def _finish(self, slot: int):
@@ -1141,6 +1279,7 @@ class PagedServingEngine:
         Recurrent (non-pageable) state is snapshotted: paused slots still
         ride through the batched decode step with dummy inputs, which would
         otherwise advance their SSM/conv state."""
+        self._settle()
         assert self.slot_req[slot] is not None
         if self.tracer.enabled:
             self.tracer.instant("engine", "pause", slot=slot,
@@ -1155,6 +1294,7 @@ class PagedServingEngine:
         """Un-pause; the next decode step's ensure_hot restores the pages
         through the planned preload path (counted as page faults), and the
         snapshotted recurrent state is written back."""
+        self._settle()
         assert self.slot_req[slot] is not None
         if self.tracer.enabled:
             self.tracer.instant("engine", "unpause", slot=slot,
@@ -1228,6 +1368,9 @@ class PagedServingEngine:
             "preemptions": self.metrics.preemptions,
             "readmissions": self.metrics.readmissions,
             "slo_violations": self.metrics.slo_violations,
+            "decode_steps": self.metrics.decode_steps,
+            "inflight_steps": self.metrics.inflight_steps,
+            "settles": self.metrics.settles,
             "rejected": self.scheduler.rejected,
             "swapped_out": len(self._swapped),
             "live_slots": len(self._live_slots()),
@@ -1282,4 +1425,5 @@ class PagedServingEngine:
         while pending() and ticks < max_ticks:
             self.step()
             ticks += 1
+        self._settle()
         return _drain_results(self.requests)

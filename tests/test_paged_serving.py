@@ -550,3 +550,213 @@ def test_pallas_page_gather_assembly_matches_default():
         eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=4))
         outs.append(eng.run()[0])
     assert outs[0] == outs[1]
+
+
+# --------------------------------------------------------------------------
+# one decode step in flight: greedy tokens stay on the device
+# --------------------------------------------------------------------------
+_REFS = {}
+
+
+def _ref(model, params, prompt, max_new, bucket, *, B, max_seq):
+    """dense_reference, memoized across the in-flight cases."""
+    key = (model.cfg.name, tuple(prompt), max_new, bucket, B, max_seq)
+    if key not in _REFS:
+        _REFS[key] = dense_reference(model, params, prompt, max_new, bucket,
+                                     B=B, max_seq=max_seq)
+    return _REFS[key]
+
+
+def _inflight_case(case, vocab):
+    """(engine knobs, [(rid, prompt, max_new, priority, submit tick)], a
+    check of the engine after the run) for one in-flight scenario."""
+    draw = lambda seed, n: np.random.default_rng(seed).integers(
+        1, vocab, size=n).tolist()
+    if case == "admissions":
+        # rid 1 shares rid 0's three prompt pages; rid 2 is rid 0's prompt
+        # again, admitted mid-run with no prefill at all; rid 3 arrives late
+        shared = draw(60, 24)
+        reqs = [(0, shared, 4, 0, 0), (1, shared + draw(61, 5), 10, 0, 0),
+                (2, list(shared), 5, 0, 2), (3, draw(62, 3), 6, 0, 4)]
+
+        def check(eng):
+            assert eng.metrics.prefill_skips >= 1
+            assert eng.pool.metrics.shared_hits > 0
+        return dict(max_seq=64), reqs, check
+    if case == "max_seq":
+        # rid 0 fills its slot to max_seq; rid 1 stops at max_new_tokens
+        reqs = [(0, draw(63, 10), 22, 0, 0), (1, draw(64, 5), 4, 0, 0)]
+
+        def check(eng):
+            assert eng.metrics.decode_steps == 21     # no step past the end
+            assert eng.metrics.tokens_emitted == 26
+        return dict(max_seq=32), reqs, check
+    if case == "preempt":
+        reqs = [(0, draw(65, 9), 12, 0, 0), (1, draw(66, 7), 12, 0, 0),
+                (2, draw(67, 5), 4, 5, 3)]
+
+        def check(eng):
+            assert eng.metrics.preemptions >= 1
+            assert eng.metrics.readmissions >= 1
+            assert eng.pool.metrics.page_faults >= 1
+            assert eng.metrics.settles >= 2     # the preemption, the drain
+        return dict(max_seq=64, policy="priority"), reqs, check
+    assert case == "chunked"
+    reqs = [(0, draw(68, 40), 4, 0, 0)] + [
+        (rid, draw(68 + rid, 5), 6, 0, 0) for rid in (1, 2, 3)]
+
+    def check(eng):
+        assert eng.metrics.chunk_passes == 5          # ceil(40 / 8)
+    return dict(max_seq=64, prefill_chunk_tokens=8), reqs, check
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["default", "kernel-sweep"])
+@pytest.mark.parametrize("case", ["admissions", "max_seq", "preempt",
+                                  "chunked"])
+def test_inflight_greedy_matches_dense_reference(case, use_kernel):
+    """With one decode step in flight (the host dispatches step t+1 before
+    it reads step t's tokens), greedy streams equal the dense reference
+    token for token: admissions mid-run (shared-prefix and fully shared
+    ones included), finishes at max_new_tokens and at max_seq, a policy
+    preemption through the cold tier and back, and chunked prefill."""
+    cfg, model, params = _model("qwen3-1.7b")
+    buckets = (8, 16, 32)
+    knobs, reqs, check = _inflight_case(case, cfg.vocab_size)
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=2, page_tokens=8, prefill_buckets=buckets,
+        use_paged_kernel=use_kernel, **knobs))
+    tick = 0
+    for when in sorted({r[4] for r in reqs}):
+        while tick < when:
+            eng.step()
+            tick += 1
+        for rid, prompt, new, prio, at in reqs:
+            if at == when:
+                eng.submit(Request(rid=rid, prompt=list(prompt),
+                                   max_new_tokens=new, priority=prio))
+    got = eng.run()
+    check(eng)
+    assert eng.metrics.inflight_steps > 0
+    assert eng.metrics.decode_steps == (eng.metrics.inflight_steps
+                                        + eng.metrics.settles)
+    assert all(r is None for r in eng.slot_req) and not eng.pool.pages
+    for rid, prompt, new, _, _ in reqs:
+        want = _ref(model, params, prompt, new,
+                    _pick_bucket(buckets, len(prompt), knobs["max_seq"]),
+                    B=2, max_seq=knobs["max_seq"])
+        assert got[rid] == want, f"{case} rid {rid}: {got[rid]} != {want}"
+
+
+def test_inflight_counters_and_settles():
+    """`inflight_steps` counts the decode steps dispatched over the
+    previous step's tokens, `settles` the steps read with none dispatched
+    behind them; every step is one or the other once the engine is idle.
+    A preemption, a pause or a resume that meets a step in flight settles
+    it, one settle each."""
+    cfg, _, params = _model("qwen3-1.7b")
+    draw = lambda seed, n: np.random.default_rng(seed).integers(
+        1, cfg.vocab_size, size=n).tolist()
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=2, max_seq=64, page_tokens=8, prefill_buckets=(16,)))
+    for rid in range(3):
+        eng.submit(Request(rid=rid, prompt=draw(70 + rid, 9),
+                           max_new_tokens=5))
+    eng.run()
+    m = eng.metrics
+    # rids 0 and 1 finish on one step and rid 2 waits for their slots, so
+    # the loop runs dry twice: there, and at the end
+    assert m.settles == 2 and m.decode_steps == 8
+    assert m.inflight_steps == m.decode_steps - m.settles
+    snap = eng.snapshot()
+    assert (snap["inflight_steps"], snap["settles"],
+            snap["decode_steps"]) == (m.inflight_steps, m.settles,
+                                      m.decode_steps)
+
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=2, max_seq=64, page_tokens=8, prefill_buckets=(16,),
+        policy="priority"))
+    for rid in range(2):
+        eng.submit(Request(rid=rid, prompt=draw(80 + rid, 9),
+                           max_new_tokens=12))
+    for _ in range(3):
+        eng.step()
+    assert eng.metrics.settles == 0 and eng._inflight is not None
+    eng.submit(Request(rid=2, prompt=draw(82, 5), max_new_tokens=3,
+                       priority=5))
+    eng.step()                                # preempts: one settle
+    assert eng.metrics.preemptions == 1 and eng.metrics.settles == 1
+    eng.step()
+    slot = next(i for i, r in enumerate(eng.slot_req) if r and r.rid < 2)
+    eng.preempt(slot)                         # pause: one settle
+    assert eng.metrics.settles == 2 and eng._inflight is None
+    eng.step()
+    assert eng._inflight is not None
+    eng.resume(slot)                          # resume: one settle
+    assert eng.metrics.settles == 3
+    eng.preempt(slot)                         # nothing in flight: none
+    eng.resume(slot)
+    assert eng.metrics.settles == 3
+    eng.run()
+    m = eng.metrics
+    assert m.inflight_steps == m.decode_steps - m.settles
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["default", "kernel-sweep"])
+def test_greedy_decode_brings_no_logits_to_the_host(monkeypatch, use_kernel):
+    """On the greedy path every array the engine brings to the host during
+    decode is a (B, 1) int32 token array; no (B, V) logits ever leave the
+    device. The sampling path, which draws on the host, copies them."""
+    from repro.serving import engine as engine_mod
+    cfg, _, params = _model("qwen3-1.7b")
+    fetched = []
+
+    class Recorder:
+        """The engine module's numpy, noting each device array it reads."""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kwargs):
+            if isinstance(a, jax.Array):
+                fetched.append((a.shape, a.dtype))
+            return np.asarray(a, *args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, "np", Recorder())
+    for greedy in (True, False):
+        fetched.clear()
+        eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+            batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,),
+            use_paged_kernel=use_kernel, greedy=greedy))
+        eng.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=6))
+        eng.submit(Request(rid=1, prompt=[2, 7, 1, 8], max_new_tokens=4))
+        eng.run()
+        assert eng.metrics.decode_steps == 5
+        full = [f for f in fetched if f[0] == (2, cfg.vocab_size)]
+        if greedy:
+            assert not full
+            assert fetched.count(((2, 1), jnp.int32)) == 5
+        else:
+            assert len(full) == 5
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["default", "kernel-sweep"])
+def test_sampled_decode_draws_as_before(use_kernel):
+    """greedy=False keeps the serial loop: a fixed seed draws the streams
+    the engine drew before greedy decode kept a step in flight."""
+    cfg, _, params = _model("qwen3-1.7b")
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,),
+        greedy=False, sample_seed=3, use_paged_kernel=use_kernel))
+    eng.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=8))
+    eng.submit(Request(rid=1, prompt=[2, 7, 1, 8], max_new_tokens=8))
+    eng.step()
+    eng.submit(Request(rid=2, prompt=[9, 9, 8], max_new_tokens=5))
+    got = eng.run()
+    assert got == {0: [21, 205, 23, 121, 187, 100, 108, 188],
+                   1: [56, 147, 110, 38, 27, 130,
+                       149 if use_kernel else 150, 245],
+                   2: [73, 168, 179, 78, 0]}
+    assert eng.metrics.inflight_steps == eng.metrics.settles == 0

@@ -244,7 +244,9 @@ def test_sweep_page_counter_matches_hand_count():
     """Two live slots of known lengths and one empty: prompts of 7 and 17
     tokens decode 3 steps each at lengths 7, 8, 9 and 17, 18, 19, so with
     pages of 8 the sweep streams 1 + 3, 1 + 3 and 2 + 3 pages a step (the
-    empty slot none), out of 3 slots x 8 pages a step."""
+    empty slot none), out of 3 slots x 8 pages a step. A fourth tick
+    decodes nothing and streams no page: it reads the tokens of the third
+    step, which was still in flight."""
     from repro.obs import Tracer
     cfg, _, params = _model("qwen3-1.7b")
     tracer = Tracer()
@@ -258,7 +260,7 @@ def test_sweep_page_counter_matches_hand_count():
             max_new_tokens=4))
     eng.run()
     assert [sp.args["pages"] for sp in tracer.spans()
-            if sp.name == "serve.decode"] == [4, 4, 5]
+            if sp.name == "serve.decode"] == [4, 4, 5, 0]
     assert eng.metrics.decode_steps == 3
     assert eng.metrics.sweep_pages == 13
     assert eng.snapshot()["sweep_page_share"] == 13 / (3 * 3 * 8)
