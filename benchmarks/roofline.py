@@ -136,7 +136,7 @@ _SERVING_METRICS = {
 }
 
 
-def run_serving(arch: str, steps: int = 160, use_kernel: bool = True):
+def run_serving(arch: str, steps: int = 160):
     """Run the deterministic serving workload; return the roofline report.
 
     Mirrors examples/serve_lm.py's shape: 6 requests over 3 slots with a
@@ -157,7 +157,7 @@ def run_serving(arch: str, steps: int = 160, use_kernel: bool = True):
     params = model.init(jax.random.PRNGKey(0))
     eng = PagedServingEngine(cfg, params, ServingConfig(
         batch_slots=3, max_seq=96, page_tokens=8,
-        prefill_buckets=(8, 16, 32), use_paged_kernel=use_kernel))
+        prefill_buckets=(8, 16, 32)))
 
     rng = np.random.default_rng(0)
     shared = rng.integers(1, cfg.vocab_size, size=8).tolist()
@@ -191,8 +191,6 @@ def run_serving(arch: str, steps: int = 160, use_kernel: bool = True):
                             cold_bw=LINK_BW)
     roof["arch"] = arch
     roof["steps"] = steps
-    roof["paged_kernel"] = use_kernel
-    roof["sweep_decode"] = bool(use_kernel and eng.cfg.sweep_decode)
     return roof
 
 
@@ -242,11 +240,9 @@ def _merge_serving_report(out_path, roof, gate):
 
 
 def serving_main(args):
-    roof = run_serving(args.serving_arch, steps=args.serving_steps,
-                       use_kernel=not args.serving_no_kernel)
+    roof = run_serving(args.serving_arch, steps=args.serving_steps)
     m = roof["modeled"]
-    print(f"serving roofline [{roof['arch']}, "
-          f"{'fused sweep' if roof['sweep_decode'] else 'reference path'}]: "
+    print(f"serving roofline [{roof['arch']}]: "
           f"{roof['tokens_emitted']} tokens, critical path "
           f"{m['critical_path_s'] * 1e6:.1f}us ({m['dominant']}-bound)")
     for tier, t in roof["tiers"].items():
@@ -306,8 +302,6 @@ def main(argv=None):
                          "--serving-baseline and merges into --out")
     ap.add_argument("--serving-arch", default="qwen3-1.7b")
     ap.add_argument("--serving-steps", type=int, default=160)
-    ap.add_argument("--serving-no-kernel", action="store_true",
-                    help="measure the reference (non-fused) paged path")
     ap.add_argument("--serving-baseline",
                     default="benchmarks/baselines/roofline_serving.json")
     ap.add_argument("--out", default="BENCH_serving.json",

@@ -221,8 +221,7 @@ def serve_phase(cfg, *, slots=8, max_seq=1024, page_tokens=16,
 
     scfg = ServingConfig(batch_slots=slots, max_seq=max_seq,
                          page_tokens=page_tokens, prefill_buckets=buckets,
-                         prefill_bucket=buckets[-1], use_paged_kernel=True,
-                         sweep_decode=True)
+                         prefill_bucket=buckets[-1])
     prompts = smoke_prompts(cfg.vocab_size, SEED)
     requests = lambda: [Request(rid=i, prompt=list(p), max_new_tokens=max_new)
                         for i, p in enumerate(prompts)]

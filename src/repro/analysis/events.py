@@ -30,7 +30,7 @@ class EventKind(str, enum.Enum):
     TOUCH = "touch"          # page named in a step's working set (LRU update)
     READ = "read"            # page's frame handed to a decode gather/kernel
     WRITE_PAGE = "write_page"    # whole-page fill (prefill rows)
-    WRITE_ROWS = "write_rows"    # one-row-per-slot decode scatter (by frame)
+    WRITE_ROWS = "write_rows"    # one row per slot, the sweep's commit (by frame)
     DEADLINE = "deadline"    # page tagged with its owner's deadline tick
     TICK = "tick"            # pool clock advanced (step boundary)
 

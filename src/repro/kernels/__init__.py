@@ -13,12 +13,10 @@ from repro.kernels.ops import (
     sum_op,
 )
 from repro.kernels.pul_sum import pul_sum
-from repro.kernels.pul_gather import pul_gather, pul_page_gather
+from repro.kernels.pul_gather import pul_gather
 from repro.kernels.pul_matmul import pul_matmul
 from repro.kernels.pul_attention import (
     pul_attention,
-    pul_paged_decode_attention,
-    pul_paged_mla_decode_attention,
     pul_paged_sweep_decode_attention,
     pul_paged_sweep_mla_decode_attention,
 )
@@ -26,9 +24,7 @@ from repro.kernels.pul_filter import pul_filter
 from repro.kernels.pul_decode import pul_decode_attention
 
 __all__ = ["ref", "sum_op", "gather_op", "matmul_op", "attention_op",
-           "filter_op", "pul_sum", "pul_gather", "pul_page_gather",
-           "pul_matmul", "pul_attention", "pul_filter",
-           "pul_decode_attention", "pul_paged_decode_attention",
-           "pul_paged_mla_decode_attention",
+           "filter_op", "pul_sum", "pul_gather", "pul_matmul",
+           "pul_attention", "pul_filter", "pul_decode_attention",
            "pul_paged_sweep_decode_attention",
            "pul_paged_sweep_mla_decode_attention"]

@@ -99,248 +99,6 @@ def _merge_row(page_ref, row, offset):
         page_ref.dtype)
 
 
-def _paged_decode_kernel(pt_smem, len_smem, q_vmem, *rest, cfg: PULConfig,
-                         P: int, n_pages: int, scale: float,
-                         softcap: Optional[float], window: Optional[int],
-                         has_new: bool):
-    if has_new:
-        knew_vmem, vnew_vmem, k_hbm, v_hbm, o_vmem, \
-            kbuf, ksems, vbuf, vsems = rest
-    else:
-        k_hbm, v_hbm, o_vmem, kbuf, ksems, vbuf, vsems = rest
-    b = pl.program_id(0)
-    kv_h = pl.program_id(1)
-    length = len_smem[b]
-
-    # the page table IS the preload trace: block t of the stream is whatever
-    # physical page the slot's logical page t maps to (random access in slow
-    # memory, sequential consumption in the ring — the paper's Exp. 2 trace)
-    k_st = PreloadStream(k_hbm, kbuf, ksems,
-                         index_map=lambda t: (pt_smem[b, t], kv_h, 0, 0),
-                         cfg=cfg, n_blocks=n_pages)
-    v_st = PreloadStream(v_hbm, vbuf, vsems,
-                         index_map=lambda t: (pt_smem[b, t], kv_h, 0, 0),
-                         cfg=cfg, n_blocks=n_pages)
-
-    q = q_vmem[0, 0].astype(jnp.float32)                 # (G, hd)
-
-    def _cap(logits):
-        if softcap is not None:
-            return softcap * jnp.tanh(logits / softcap)
-        return logits
-
-    def body(t, views, carry):
-        m, l, acc = carry
-        kt = views[0][0, 0].astype(jnp.float32)          # (P, hd)
-        vt = views[1][0, 0].astype(jnp.float32)
-        logits = _cap(
-            jnp.dot(q, kt.T, preferred_element_type=jnp.float32) * scale)
-        jk = t * P + jax.lax.iota(jnp.int32, P)
-        msk = jk < length
-        if window is not None:
-            # the incoming query sits at absolute position `length`; cached
-            # token jk is visible iff jk > length - window
-            msk &= jk > length - window
-        logits = jnp.where(msk[None, :], logits, NEG_INF)
-        bmax = jnp.max(logits, axis=-1, keepdims=True)
-        new_m = jnp.maximum(m, bmax)
-        corr = jnp.exp(m - new_m)
-        p = jnp.exp(logits - new_m)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p, vt, preferred_element_type=jnp.float32)
-        return new_m, l, acc
-
-    G, hd = q.shape
-    init = (jnp.full((G, 1), NEG_INF, jnp.float32),
-            jnp.zeros((G, 1), jnp.float32),
-            jnp.zeros((G, hd), jnp.float32))
-    m, l, acc = pul_loop(n_pages, [k_st, v_st], body, init, cfg)
-    if has_new:
-        # fold in the current token's K/V (not yet written to any page);
-        # it is always causally visible and always inside the window
-        kn = knew_vmem[0, 0].astype(jnp.float32)         # (1, hd)
-        vn = vnew_vmem[0, 0].astype(jnp.float32)
-        ls = _cap(jnp.dot(q, kn.T, preferred_element_type=jnp.float32)
-                  * scale)                               # (G, 1)
-        new_m = jnp.maximum(m, ls)
-        corr = jnp.exp(m - new_m)
-        p = jnp.exp(ls - new_m)
-        l = l * corr + p
-        acc = acc * corr + jnp.dot(p, vn, preferred_element_type=jnp.float32)
-    o_vmem[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_vmem.dtype)
-
-
-def pul_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
-                               v_pages: jax.Array, page_tables: jax.Array,
-                               lengths, *, cfg: PULConfig = PULConfig(),
-                               scale: Optional[float] = None,
-                               softcap: Optional[float] = None,
-                               window: Optional[int] = None,
-                               k_new: Optional[jax.Array] = None,
-                               v_new: Optional[jax.Array] = None,
-                               interpret: Optional[bool] = None) -> jax.Array:
-    """Decode attention straight over a paged KV store (serving hot path).
-
-    q: (B, H, hd) one query token per slot; k_pages/v_pages: (NP, K, P, W)
-    physical page frames (P tokens per page, W >= hd lanes, zero beyond
-    hd); page_tables: (B, n_pages) int32 physical page id of each slot's
-    logical page; lengths: (B,) valid tokens per slot. Returns (B, H, hd).
-
-    `window` bounds the visible range to the last `window` tokens relative to
-    the incoming query at position `lengths[b]` (sliding-window layers).
-    `k_new`/`v_new` ((B, K, hd)) carry the CURRENT token's K/V — not yet
-    written to any page — and are folded into the online softmax after the
-    page stream, so the engine can run attention before the page write-back.
-
-    The kernel never materializes a contiguous KV view: pages stream from
-    slow memory through a distance-d preload ring, addressed by the SMEM
-    page table — software paging *is* the trace-driven preload of the paper.
-    """
-    B, H, hd = q.shape
-    NP, K, P, W = k_pages.shape
-    _, n_pages = page_tables.shape
-    assert H % K == 0
-    G = H // K
-    has_new = k_new is not None
-    assert (v_new is not None) == has_new, "k_new/v_new come as a pair"
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-    qg = _pad_lanes(q, W).reshape(B, K, G, W)
-    kern = functools.partial(_paged_decode_kernel, cfg=cfg, P=P,
-                             n_pages=n_pages, scale=scale, softcap=softcap,
-                             window=window, has_new=has_new)
-    new_specs, new_args = [], []
-    if has_new:
-        new_specs = [pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0)),
-                     pl.BlockSpec((1, 1, 1, W), lambda b, h: (b, h, 0, 0))]
-        new_args = [_pad_lanes(k_new, W).reshape(B, K, 1, W),
-                    _pad_lanes(v_new, W).reshape(B, K, 1, W)]
-    out = pl.pallas_call(
-        kern,
-        grid=(B, K),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, W), q.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
-            *new_specs,
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, W), lambda b, h: (b, h, 0, 0)),
-        scratch_shapes=[
-            *ring_scratch(cfg, (1, 1, P, W), k_pages.dtype),
-            *ring_scratch(cfg, (1, 1, P, W), v_pages.dtype),
-        ],
-        interpret=interpret_mode(interpret),
-    )(page_tables.astype(jnp.int32), lengths, qg, *new_args,
-      k_pages, v_pages)
-    return out.reshape(B, H, W)[..., :hd]
-
-
-def _paged_mla_decode_kernel(pt_smem, len_smem, qa_vmem, qr_vmem, cnew_vmem,
-                             rnew_vmem, ckv_hbm, kr_hbm, o_vmem,
-                             cbuf, csems, rbuf, rsems, *, cfg: PULConfig,
-                             P: int, n_pages: int, scale: float):
-    b = pl.program_id(0)
-    length = len_smem[b]
-
-    c_st = PreloadStream(ckv_hbm, cbuf, csems,
-                         index_map=lambda t: (pt_smem[b, t], 0, 0),
-                         cfg=cfg, n_blocks=n_pages)
-    r_st = PreloadStream(kr_hbm, rbuf, rsems,
-                         index_map=lambda t: (pt_smem[b, t], 0, 0),
-                         cfg=cfg, n_blocks=n_pages)
-
-    qa = qa_vmem[0].astype(jnp.float32)                  # (H, C)
-    qr = qr_vmem[0].astype(jnp.float32)                  # (H, R)
-
-    def body(t, views, carry):
-        m, l, acc = carry
-        ct = views[0][0].astype(jnp.float32)             # (P, C)
-        rt = views[1][0].astype(jnp.float32)             # (P, R)
-        logits = (jnp.dot(qa, ct.T, preferred_element_type=jnp.float32)
-                  + jnp.dot(qr, rt.T, preferred_element_type=jnp.float32)
-                  ) * scale                              # (H, P)
-        jk = t * P + jax.lax.iota(jnp.int32, P)
-        logits = jnp.where((jk < length)[None, :], logits, NEG_INF)
-        bmax = jnp.max(logits, axis=-1, keepdims=True)
-        new_m = jnp.maximum(m, bmax)
-        corr = jnp.exp(m - new_m)
-        p = jnp.exp(logits - new_m)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # MLA: the compressed cache IS the value stream (absorbed decode)
-        acc = acc * corr + jnp.dot(p, ct, preferred_element_type=jnp.float32)
-        return new_m, l, acc
-
-    H, C = qa.shape
-    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, C), jnp.float32))
-    m, l, acc = pul_loop(n_pages, [c_st, r_st], body, init, cfg)
-    # current token's compressed KV, not yet paged
-    cn = cnew_vmem[0].astype(jnp.float32)                # (1, C)
-    rn = rnew_vmem[0].astype(jnp.float32)                # (1, R)
-    ls = (jnp.dot(qa, cn.T, preferred_element_type=jnp.float32)
-          + jnp.dot(qr, rn.T, preferred_element_type=jnp.float32)) * scale
-    new_m = jnp.maximum(m, ls)
-    corr = jnp.exp(m - new_m)
-    p = jnp.exp(ls - new_m)
-    l = l * corr + p
-    acc = acc * corr + jnp.dot(p, cn, preferred_element_type=jnp.float32)
-    o_vmem[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_vmem.dtype)
-
-
-def pul_paged_mla_decode_attention(q_abs: jax.Array, q_rope: jax.Array,
-                                   ckv_pages: jax.Array, kr_pages: jax.Array,
-                                   page_tables: jax.Array, lengths,
-                                   c_new: jax.Array, r_new: jax.Array, *,
-                                   scale: float,
-                                   cfg: PULConfig = PULConfig(),
-                                   interpret: Optional[bool] = None) -> jax.Array:
-    """Absorbed MLA decode attention straight over compressed-KV pages.
-
-    q_abs: (B, H, kvr) queries absorbed into the compressed space; q_rope:
-    (B, H, dr) rope-carrying queries; ckv_pages: (NP, P, C) and kr_pages:
-    (NP, P, R) physical page frames (one row per token — MLA's cache is
-    head-shared; C >= kvr and R >= dr lanes, zero beyond); page_tables:
-    (B, n_pages); lengths: (B,) cached tokens per slot; c_new/r_new:
-    (B, kvr)/(B, dr) the current token's compressed KV. Returns o_c
-    (B, H, kvr) — the caller applies the absorbed v up-projection.
-    """
-    B, H, kvr = q_abs.shape
-    NP, P, C = ckv_pages.shape
-    R = kr_pages.shape[-1]
-    _, n_pages = page_tables.shape
-    lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-    kern = functools.partial(_paged_mla_decode_kernel, cfg=cfg, P=P,
-                             n_pages=n_pages, scale=scale)
-    out = pl.pallas_call(
-        kern,
-        grid=(B,),
-        out_shape=jax.ShapeDtypeStruct((B, H, C), q_abs.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, C), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, H, C), lambda b: (b, 0, 0)),
-        scratch_shapes=[
-            *ring_scratch(cfg, (1, P, C), ckv_pages.dtype),
-            *ring_scratch(cfg, (1, P, R), kr_pages.dtype),
-        ],
-        interpret=interpret_mode(interpret),
-    )(page_tables.astype(jnp.int32), lengths, _pad_lanes(q_abs, C),
-      _pad_lanes(q_rope, R), _pad_lanes(c_new, C).reshape(B, 1, C),
-      _pad_lanes(r_new, R).reshape(B, 1, R), ckv_pages, kr_pages)
-    return out[..., :kvr]
-
-
 def _kept_pages(length, P: int, n_pages: int, window: Optional[int]):
     """The page range [first, last) whose rows the decode mask can keep for
     a query at position `length`: up to the page of row length - 1, and
@@ -378,10 +136,10 @@ def _paged_sweep_decode_kernel(pt_smem, len_smem, frames_smem, offs_smem,
     k_fetch.start()
     v_fetch.start()
 
-    # same page-table-driven stream as the per-layer kernel, with the layer
-    # scalar prepended: block t is plane row (g, pt[b, t], kv_h), without
-    # the host-side layer slice. Only the pages the mask can keep are
-    # streamed (`_kept_pages`): an empty slot runs no iteration at all
+    # the page table drives the stream, with the layer scalar prepended:
+    # block t is plane row (g, pt[b, t], kv_h), without a host-side layer
+    # slice. Only the pages the mask can keep are streamed (`_kept_pages`):
+    # an empty slot runs no iteration at all
     k_st = PreloadStream(k_hbm, kbuf, ksems,
                          index_map=lambda t: (g, pt_smem[b, t], kv_h, 0, 0),
                          cfg=cfg, n_blocks=n_pages)

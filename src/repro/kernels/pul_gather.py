@@ -11,7 +11,6 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,26 +37,6 @@ def _kernel(trace_smem, table_hbm, out_hbm, pbuf, psems, ubuf, usems, *,
         return carry
 
     pul_loop(n_req, [pre], body, 0, cfg, unloads=[unl])
-
-
-def pul_page_gather(store: jax.Array, page_table: jax.Array, *,
-                    cfg: PULConfig = PULConfig(),
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Assemble sequences from a paged KV store (the serving gather path).
-
-    store: (n_pages, page_tokens, feat) physical page frames.
-    page_table: (n_seqs, pages_per_seq) int32 page ids (a serving slot's
-      logical->physical page map; the SMEM-resident trace of the PUL gather).
-    Returns (n_seqs, pages_per_seq * page_tokens, feat): each sequence's
-    token-contiguous KV, pulled page-by-page through the preload ring and
-    written back out through the unload ring.
-    """
-    n_pages, P, F = store.shape
-    n_seqs, ppseq = page_table.shape
-    flat = pul_gather(store.reshape(n_pages * P, F),
-                      page_table.reshape(-1).astype(jnp.int32),
-                      cfg=cfg, rows_per_req=P, interpret=interpret)
-    return flat.reshape(n_seqs, ppseq * P, F)
 
 
 def pul_gather(table: jax.Array, trace: jax.Array, *,

@@ -48,6 +48,64 @@ def attention_ref(q, k, v, *, causal: bool = True, scale: Optional[float] = None
     return jnp.einsum("bhts,bhsd->bhtd", p, vv.astype(jnp.float32)).astype(q.dtype)
 
 
+def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
+                     *, scale: Optional[float] = None,
+                     softcap: Optional[float] = None,
+                     window: Optional[int] = None) -> jax.Array:
+    """One layer of the paged sweep decode. q: (B,H,hd); k_pages/v_pages:
+    (NF,K,P,W >= hd) the layer's page frames; page_table: (B,n) the frame
+    of each slot's logical page; lengths: (B,) cached rows; k_new/v_new:
+    (B,K,hd) the current token, at position lengths[b] and always visible
+    (the window is anchored there)."""
+    B, H, hd = q.shape
+    K, P = k_pages.shape[1:3]
+    S = page_table.shape[1] * P
+
+    def stream(pages, new):             # (B, H, S + 1, hd): cache, then new
+        d = pages[page_table][..., :hd].transpose(0, 2, 1, 3, 4)
+        d = jnp.concatenate([d.reshape(B, K, S, hd), new[:, :, None]], 2)
+        return jnp.repeat(d.astype(jnp.float32), H // K, axis=1)
+
+    kk, vv = stream(k_pages, k_new), stream(v_pages, v_new)
+    scale = scale if scale is not None else 1.0 / (hd ** 0.5)
+    logits = jnp.einsum("bhd,bhsd->bhs", q.astype(jnp.float32), kk) * scale
+    if softcap is not None:
+        logits = softcap * jnp.tanh(logits / softcap)
+    jk = jnp.arange(S + 1)[None, None, :]
+    n = jnp.asarray(lengths)[:, None, None]
+    keep = jk < n
+    if window is not None:
+        keep &= jk > n - window
+    keep |= jk == S
+    p = jax.nn.softmax(jnp.where(keep, logits, -2.0e38), axis=-1)
+    return jnp.einsum("bhs,bhsd->bhd", p, vv)
+
+
+def paged_mla_decode_ref(q_abs, q_rope, ckv_pages, kr_pages, page_table,
+                         lengths, c_new, r_new, *, scale: float) -> jax.Array:
+    """One layer of the absorbed-MLA paged sweep decode. q_abs: (B,H,kvr),
+    q_rope: (B,H,dr); ckv_pages/kr_pages: (NF,P,C >= kvr)/(NF,P,R >= dr)
+    the layer's compressed page frames; c_new/r_new: (B,kvr)/(B,dr) the
+    current token, always visible. The compressed cache doubles as the
+    value stream: returns (B,H,kvr)."""
+    B, H, kvr = q_abs.shape
+    dr = q_rope.shape[-1]
+    S = page_table.shape[1] * ckv_pages.shape[1]
+
+    def stream(pages, new, w):          # (B, S + 1, w): cache, then new
+        d = pages[page_table][..., :w].reshape(B, S, w)
+        return jnp.concatenate([d, new[:, None]], 1).astype(jnp.float32)
+
+    cd, rd = stream(ckv_pages, c_new, kvr), stream(kr_pages, r_new, dr)
+    logits = (jnp.einsum("bhr,bsr->bhs", q_abs.astype(jnp.float32), cd)
+              + jnp.einsum("bhd,bsd->bhs", q_rope.astype(jnp.float32), rd)
+              ) * scale
+    jk = jnp.arange(S + 1)[None, None, :]
+    keep = (jk < jnp.asarray(lengths)[:, None, None]) | (jk == S)
+    p = jax.nn.softmax(jnp.where(keep, logits, -2.0e38), axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", p, cd)
+
+
 def filter_ref(data: jax.Array, threshold) -> jax.Array:
     """Selection bit-vector (paper Exp. 5): bit i set iff data[i,0] > thr.
     Packed little-endian into int32 words."""

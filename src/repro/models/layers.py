@@ -25,17 +25,17 @@ NEG_INF = -2.0e38  # large finite; avoids nan from (-inf) - (-inf)
 class PagedSweep:
     """Routing context for the single-sweep paged decode.
 
-    When the engine serves from per-layer page planes (`KVStoreLayout` v2),
-    `paged` carries one of these instead of the legacy (page_table, PULConfig)
-    tuple. The backbone threads the FULL stacked planes through the layer
-    scan's carry and mutates this context as tracing walks the blocks: it
-    sets `prefix` to the current block's cache path and `layer` to the
-    scan-carried group index; each attention block then reads its planes via
-    :meth:`plane`, calls the sweep kernel (which selects the layer in-kernel
-    from an SMEM scalar and commits the current token's rows in its fused
-    epilogue), and writes the aliased plane outputs back via
-    :meth:`set_plane`. `frames`/`offsets` ((B,) int32) name each slot's
-    tail-page commit position (TRASH frame for inactive slots).
+    The engine serves decode from per-layer page planes (`KVStoreLayout`
+    v2); `paged` carries one of these. The backbone threads the FULL
+    stacked planes through the layer scan's carry and mutates this context
+    as tracing walks the blocks: it sets `prefix` to the current block's
+    cache path and `layer` to the scan-carried group index; each attention
+    block then reads its planes via :meth:`plane`, calls the sweep kernel
+    (which selects the layer in-kernel from an SMEM scalar and commits the
+    current token's rows in its fused epilogue), and writes the aliased
+    plane outputs back via :meth:`set_plane`. `frames`/`offsets` ((B,)
+    int32) name each slot's tail-page commit position (TRASH frame for
+    inactive slots).
     """
 
     def __init__(self, page_table, pul_cfg, frames, offsets, planes):
@@ -211,15 +211,15 @@ def attention_apply(
     local: bool = False,
     cache: Optional[Dict[str, Any]] = None,
     max_seq: Optional[int] = None,  # prefill: emit caches sized for decode
-    paged: Optional[Tuple] = None,  # paged_decode: (page_table, PULConfig)
+    paged: Optional[PagedSweep] = None,  # paged_decode: planes + routing
 ):
     """Returns (y, new_cache). Cache: {"k","v": (B,Smax,K,hd), "idx": ()}.
 
-    kind="paged_decode" consumes a PAGED cache instead: {"k","v":
-    (NP, K, P, hd) physical page frames, "idx": (B,) per-slot fill}, with the
-    logical->physical map in `paged` — attention streams straight over the
-    pages (kernels.pul_paged_decode_attention) and the returned cache holds
-    only the current token's rows for the engine to scatter into its page."""
+    kind="paged_decode" reads the KV from the page planes in `paged`
+    instead (the cache's "k"/"v" are placeholders; "idx" is the (B,)
+    per-slot fill): attention streams straight over the pages
+    (kernels.pul_paged_sweep_decode_attention), whose epilogue commits the
+    current token's rows; the returned cache holds those rows."""
     B, T, D = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
@@ -243,38 +243,26 @@ def attention_apply(
     window = cfg.sliding_window if local else None
 
     if kind == "paged_decode":
-        # Kernel-true paged decode: no dense (B, S) view is ever assembled —
-        # the PUL preload ring pulls physical pages in page-table order and
-        # the current token's K/V (not yet in any page) merges in after the
-        # stream. Sliding windows are an in-kernel mask term (paged layouts
-        # are token-indexed, never rings).
+        # Paged decode: no dense (B, S) view is ever assembled — the PUL
+        # preload ring pulls physical pages in page-table order and the
+        # current token's K/V (not yet in any page) merges in after the
+        # stream. The kernel reads THIS layer out of the full per-layer
+        # planes (SMEM layer scalar) and commits k_new/v_new in its fused
+        # epilogue. Sliding windows are an in-kernel mask term (paged
+        # layouts are token-indexed, never rings).
         assert T == 1, "paged decode processes one token per step"
-        assert paged is not None, "paged_decode needs (page_table, PULConfig)"
+        from repro.kernels.pul_attention import (
+            pul_paged_sweep_decode_attention)
         idx = jnp.asarray(cache["idx"], jnp.int32).reshape(B)
-        if isinstance(paged, PagedSweep):
-            # single-sweep path: the kernel reads THIS layer out of the full
-            # per-layer planes (SMEM layer scalar) and commits k_new/v_new
-            # in its fused epilogue — no host-side view slicing or scatter
-            from repro.kernels.pul_attention import (
-                pul_paged_sweep_decode_attention)
-            kp, vp = paged.plane("k"), paged.plane("v")
-            k_new = k[:, 0].astype(kp.dtype)
-            v_new = v[:, 0].astype(vp.dtype)
-            out, kp, vp = pul_paged_sweep_decode_attention(
-                q[:, 0], kp, vp, paged.layer, paged.page_table, idx,
-                k_new, v_new, paged.frames, paged.offsets, scale=scale,
-                softcap=cfg.attn_softcap, window=window, cfg=paged.pul_cfg)
-            paged.set_plane("k", kp)
-            paged.set_plane("v", vp)
-        else:
-            from repro.kernels.pul_attention import pul_paged_decode_attention
-            page_table, pul_cfg = paged
-            k_new = k[:, 0].astype(cache["k"].dtype)
-            v_new = v[:, 0].astype(cache["v"].dtype)
-            out = pul_paged_decode_attention(
-                q[:, 0], cache["k"], cache["v"], page_table, idx,
-                scale=scale, softcap=cfg.attn_softcap, window=window,
-                k_new=k_new, v_new=v_new, cfg=pul_cfg)
+        kp, vp = paged.plane("k"), paged.plane("v")
+        k_new = k[:, 0].astype(kp.dtype)
+        v_new = v[:, 0].astype(vp.dtype)
+        out, kp, vp = pul_paged_sweep_decode_attention(
+            q[:, 0], kp, vp, paged.layer, paged.page_table, idx,
+            k_new, v_new, paged.frames, paged.offsets, scale=scale,
+            softcap=cfg.attn_softcap, window=window, cfg=paged.pul_cfg)
+        paged.set_plane("k", kp)
+        paged.set_plane("v", vp)
         out = out[:, None]
         new_cache = {"k": k_new, "v": v_new, "idx": idx + 1}
     elif kind == "decode":

@@ -10,7 +10,7 @@ n_heads*(192+128).
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,16 +64,17 @@ def mla_apply(
     kind: str,
     cache: Optional[Dict[str, Any]] = None,
     max_seq: Optional[int] = None,
-    paged: Optional[Tuple] = None,
+    paged=None,             # paged_decode: a layers.PagedSweep
 ):
     """Returns (y, new_cache). Cache: {"c_kv": (B,Smax,kvr), "k_rope":
     (B,Smax,dr), "idx": ()} — compressed, per the MLA design.
 
-    kind="paged_decode" consumes a PAGED compressed cache: {"c_kv":
-    (NP, P, kvr), "k_rope": (NP, P, dr) physical page frames, "idx": (B,)},
-    with the logical->physical map in `paged`; the absorbed attention runs
-    straight over the pages (kernels.pul_paged_mla_decode_attention) and the
-    returned cache holds only the current token's compressed rows."""
+    kind="paged_decode" reads the compressed KV from the page planes in
+    `paged` (a `layers.PagedSweep`; the cache's "c_kv"/"k_rope" are
+    placeholders, "idx" the (B,) per-slot fill): the absorbed attention runs
+    straight over the pages (kernels.pul_paged_sweep_mla_decode_attention),
+    whose epilogue commits the current token's compressed rows; the returned
+    cache holds those rows."""
     B, T, D = x.shape
     H = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -82,36 +83,24 @@ def mla_apply(
 
     if kind == "paged_decode":
         assert T == 1, "paged decode processes one token per step"
-        assert paged is not None, "paged_decode needs (page_table, PULConfig)"
-        from repro.models.layers import PagedSweep
+        from repro.kernels.pul_attention import (
+            pul_paged_sweep_mla_decode_attention)
         idx = jnp.asarray(cache["idx"], jnp.int32).reshape(B)
         c_new, r_new = _compress_kv(p, x, cfg, positions)
         wkv_b_k = p["wkv_b"][..., :dn]                      # (kvr, H, dn)
         wkv_b_v = p["wkv_b"][..., dn:]                      # (kvr, H, dv)
         q_abs = jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b_k)[:, 0]
-        if isinstance(paged, PagedSweep):
-            # single-sweep path over the full per-layer compressed planes;
-            # the fused epilogue commits c_new/r_new to the tail page
-            from repro.kernels.pul_attention import (
-                pul_paged_sweep_mla_decode_attention)
-            cp, rp = paged.plane("c_kv"), paged.plane("k_rope")
-            c_new = c_new[:, 0].astype(cp.dtype)
-            r_new = r_new[:, 0].astype(rp.dtype)
-            o_c, cp, rp = pul_paged_sweep_mla_decode_attention(
-                q_abs, q_rope[:, 0], cp, rp, paged.layer, paged.page_table,
-                idx, c_new, r_new, paged.frames, paged.offsets, scale=scale,
-                cfg=paged.pul_cfg)
-            paged.set_plane("c_kv", cp)
-            paged.set_plane("k_rope", rp)
-        else:
-            from repro.kernels.pul_attention import (
-                pul_paged_mla_decode_attention)
-            page_table, pul_cfg = paged
-            c_new = c_new[:, 0].astype(cache["c_kv"].dtype)
-            r_new = r_new[:, 0].astype(cache["k_rope"].dtype)
-            o_c = pul_paged_mla_decode_attention(
-                q_abs, q_rope[:, 0], cache["c_kv"], cache["k_rope"],
-                page_table, idx, c_new, r_new, scale=scale, cfg=pul_cfg)
+        # one sweep over the full per-layer compressed planes; the fused
+        # epilogue commits c_new/r_new to the tail page
+        cp, rp = paged.plane("c_kv"), paged.plane("k_rope")
+        c_new = c_new[:, 0].astype(cp.dtype)
+        r_new = r_new[:, 0].astype(rp.dtype)
+        o_c, cp, rp = pul_paged_sweep_mla_decode_attention(
+            q_abs, q_rope[:, 0], cp, rp, paged.layer, paged.page_table,
+            idx, c_new, r_new, paged.frames, paged.offsets, scale=scale,
+            cfg=paged.pul_cfg)
+        paged.set_plane("c_kv", cp)
+        paged.set_plane("k_rope", rp)
         out = jnp.einsum("bhr,rhv->bhv", o_c, wkv_b_v)[:, None]
         new_cache = {"c_kv": c_new, "k_rope": r_new, "idx": idx + 1}
     elif kind == "decode":

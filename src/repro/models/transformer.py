@@ -226,17 +226,16 @@ def backbone(params, x, positions, *, cfg: ModelConfig, step_kind: str,
              caches=None, max_seq=None, paged=None):
     """Runs dense prefix + scanned groups. Returns (x, new_caches, aux)."""
     aux_total = jnp.float32(0.0)
-    # single-sweep paged decode: `paged` is a PagedSweep carrying the full
-    # per-layer page planes; the backbone sets its (prefix, layer) routing
-    # per block and threads the grouped planes through the scan carry
-    sweep = paged if isinstance(paged, L.PagedSweep) else None
+    # paged decode: `paged` is a PagedSweep carrying the full per-layer
+    # page planes; the backbone sets its (prefix, layer) routing per block
+    # and threads the grouped planes through the scan carry
     new_dense = {}
     if cfg.first_dense_layers:
         for i in range(cfg.first_dense_layers):
             c = None if caches is None else caches["dense"][str(i)]
-            if sweep is not None:
-                sweep.prefix = ("dense", str(i))
-                sweep.layer = 0         # dense planes have layer extent 1
+            if paged is not None:
+                paged.prefix = ("dense", str(i))
+                paged.layer = 0         # dense planes have layer extent 1
             x, nc, aux = block_apply(params["dense"][str(i)], None, x, cfg=cfg,
                                      kind="dense", positions=positions,
                                      step_kind=step_kind, cache=c,
@@ -264,8 +263,8 @@ def backbone(params, x, positions, *, cfg: ModelConfig, step_kind: str,
         for i, kind in enumerate(cfg.pattern):
             key = f"{i}:{kind}"
             c = None if gc is None else gc[key]
-            if sweep is not None:
-                sweep.prefix = ("groups", key)
+            if paged is not None:
+                paged.prefix = ("groups", key)
             x, nc, aux = block_apply(gp[key], shared, x, cfg=cfg, kind=kind,
                                      positions=positions,
                                      step_kind=step_kind, cache=c,
@@ -297,8 +296,8 @@ def backbone(params, x, positions, *, cfg: ModelConfig, step_kind: str,
         if cfg.first_dense_layers:
             new_caches["dense"] = new_dense
     else:  # decode
-        gkeys = (sorted(k for k in sweep.planes if k.startswith("groups/"))
-                 if sweep is not None else [])
+        gkeys = (sorted(k for k in paged.planes if k.startswith("groups/"))
+                 if paged is not None else [])
         if gkeys:
             # thread the grouped planes through the scan carry: iteration g
             # receives the planes as written by layers < g, the sweep kernel
@@ -307,18 +306,18 @@ def backbone(params, x, positions, *, cfg: ModelConfig, step_kind: str,
             def sweep_body(carry, inp):
                 inner, gplanes = carry
                 gp, gc, g = inp
-                sweep.layer = g
+                paged.layer = g
                 for pk in gkeys:
-                    sweep.planes[pk] = gplanes[pk]
+                    paged.planes[pk] = gplanes[pk]
                 inner, new_gc = body(inner, (gp, gc))
-                return (inner, {pk: sweep.planes[pk] for pk in gkeys}), new_gc
+                return (inner, {pk: paged.planes[pk] for pk in gkeys}), new_gc
             ((x, aux_total), gout), new_gcaches = jax.lax.scan(
                 sweep_body,
-                ((x, aux_total), {pk: sweep.planes[pk] for pk in gkeys}),
+                ((x, aux_total), {pk: paged.planes[pk] for pk in gkeys}),
                 (params["groups"], caches["groups"],
                  jnp.arange(cfg.num_groups, dtype=jnp.int32)))
             for pk in gkeys:
-                sweep.planes[pk] = gout[pk]
+                paged.planes[pk] = gout[pk]
         else:
             (x, aux_total), new_gcaches = jax.lax.scan(
                 body, (x, aux_total), (params["groups"], caches["groups"]))
@@ -378,47 +377,35 @@ def decode_fn(params, batch, caches, *, cfg: ModelConfig):
     return logits[:, 0, :], new_caches
 
 
-def paged_decode_fn(params, batch, caches, planes=None, *, cfg: ModelConfig,
+def paged_decode_fn(params, batch, caches, planes, *, cfg: ModelConfig,
                     pul_distance: int = 4):
-    """Kernel-true paged decode step: attention reads KV pages directly.
+    """Paged decode step as one sweep: attention reads KV pages directly.
 
     batch: tokens (B,1), pos0 (B,) absolute position of the new token,
     page_table (B, n_pages) int32 physical frame of each slot's logical
-    page.
+    page, frames/offsets (B,) int32 each slot's tail-page commit position.
 
-    **Single-sweep mode** (`planes` is the `KVStoreLayout` plane dict):
-    one launch sequence walks all layers inside the decode scan over the
-    FULL per-layer planes — each layer's pages are read by the sweep kernel
-    at an SMEM layer scalar (zero-copy: no per-layer gather/slice is built
-    under jit) and the current token's K/V rows are committed by the
-    kernel's fused epilogue at batch["frames"]/batch["offsets"]. `caches`
-    carries only non-pageable state (SSM leaves, idx; pageable leaves may
-    be placeholders — only their tree position is used). Returns
-    (logits (B,V), new_caches, new_planes).
-
-    **Legacy per-layer mode** (`planes` is None): `caches` is the decode
-    tree with every pageable leaf replaced by a physical page view and idx
-    leaves set to per-slot fill levels; returns (logits, new_caches) where
-    pageable leaves hold ONLY the current token's rows for the engine to
-    scatter into each slot's tail page (`KVPagePool.write_rows`).
+    `planes` is the `KVStoreLayout` plane dict. One launch sequence walks
+    all layers inside the decode scan over the FULL per-layer planes: each
+    layer's pages are read by the sweep kernel at an SMEM layer scalar
+    (zero-copy: no per-layer gather/slice is built under jit) and the
+    current token's K/V rows are committed by the kernel's fused epilogue
+    at batch["frames"]/batch["offsets"]. `caches` carries only non-pageable
+    state (SSM leaves, idx); its pageable leaves are placeholders, of which
+    only the tree position is used. Returns (logits (B,V), new_caches,
+    new_planes).
 
     `pul_distance` is the preload distance of the in-kernel page ring
     (static; the engine passes the planner's d*)."""
     from repro.core import PULConfig
     x, positions = _embed_inputs(params, batch, cfg)
-    pul_cfg = PULConfig(distance=pul_distance)
-    page_table = batch["page_table"].astype(jnp.int32)
-    if planes is not None:
-        paged = L.PagedSweep(
-            page_table, pul_cfg,
-            jnp.asarray(batch["frames"], jnp.int32),
-            jnp.asarray(batch["offsets"], jnp.int32), dict(planes))
-    else:
-        paged = (page_table, pul_cfg)
+    paged = L.PagedSweep(
+        batch["page_table"].astype(jnp.int32),
+        PULConfig(distance=pul_distance),
+        jnp.asarray(batch["frames"], jnp.int32),
+        jnp.asarray(batch["offsets"], jnp.int32), dict(planes))
     x, new_caches, _ = backbone(params, x, positions, cfg=cfg,
                                 step_kind="paged_decode", caches=caches,
                                 paged=paged)
     logits = L.logits_apply(params["embedding"], x, cfg=cfg)
-    if planes is not None:
-        return logits[:, 0, :], new_caches, paged.planes
-    return logits[:, 0, :], new_caches
+    return logits[:, 0, :], new_caches, paged.planes

@@ -26,8 +26,8 @@ class Model:
     loss: Callable                # (params, batch) -> scalar
     prefill: Callable             # (params, batch) -> (logits, caches)
     decode_step: Callable         # (params, batch, caches) -> (logits, caches)
-    paged_decode_step: Callable   # (params, batch, page-view caches) ->
-                                  # (logits, new-token rows + state)
+    paged_decode_step: Callable   # (params, batch, caches, page planes)
+                                  # -> (logits, caches, planes): the sweep
 
     def init(self, key):
         return M.init_tree(key, self.params)

@@ -481,6 +481,7 @@ def page_events_from_chrome(doc: Dict[str, Any]):
             kind=kind,
             pid=args.get("page"),
             frame=args.get("frame"),
+            layer=args.get("layer"),
             refcount=args.get("refcount"),
             deadline=(None if args.get("deadline") is None
                       else float(_json_restore(args["deadline"]))),

@@ -56,13 +56,6 @@ class ServingConfig:
       share_prefix_pages: share page-aligned prompt prefixes across
         requests (and reuse their cached first-token logits).
 
-    Decode path (paged engine):
-      use_pallas_gather: route dense assembly through the PUL page gather.
-      use_paged_kernel: kernel-true decode straight over page frames.
-      sweep_decode: with the kernel, run ALL layers as one sweep over the
-        per-layer planes with the token commit fused into the kernel
-        epilogue (False = per-layer launches + eager scatter).
-
     Admission (paged engine):
       prefill_buckets: ascending compiled prefill widths.
       max_active_tokens: token budget across live slots (0 = slots cap).
@@ -84,9 +77,6 @@ class ServingConfig:
     hot_pages: int = 0
     preload_distance: Optional[int] = None
     share_prefix_pages: bool = True
-    use_pallas_gather: bool = False
-    use_paged_kernel: bool = False
-    sweep_decode: bool = True
     prefill_buckets: Tuple[int, ...] = (16, 32, 64)
     max_active_tokens: int = 0
     policy: str = "fcfs"
@@ -115,9 +105,6 @@ class ServingConfig:
             max_active_tokens=self.max_active_tokens,
             preload_distance=self.preload_distance,
             share_prefix_pages=self.share_prefix_pages,
-            use_pallas_gather=self.use_pallas_gather,
-            use_paged_kernel=self.use_paged_kernel,
-            sweep_decode=self.sweep_decode,
             policy=self.policy,
             prefill_chunk_tokens=self.prefill_chunk_tokens,
             greedy=self.greedy, sample_seed=self.sample_seed,
@@ -161,13 +148,6 @@ class ServingConfig:
                         help="page-restore preload distance (0 = planner d*)")
         ap.add_argument("--max-active-tokens", type=int, default=0)
         ap.add_argument("--no-prefix-sharing", action="store_true")
-        ap.add_argument("--paged-kernel", action="store_true",
-                        help="kernel-true decode: attention streams straight "
-                             "over page frames (no dense assembly)")
-        ap.add_argument("--no-sweep", action="store_true",
-                        help="with --paged-kernel: per-layer kernel launches "
-                             "+ eager row scatter instead of the fused "
-                             "single-sweep decode")
         ap.add_argument("--policy", default="fcfs",
                         choices=("fcfs", "priority", "slo-edf"),
                         help="admission policy; priority and slo-edf preempt "
@@ -193,8 +173,6 @@ class ServingConfig:
             preload_distance=get("distance", 0) or None,
             max_active_tokens=get("max_active_tokens", 0),
             share_prefix_pages=not get("no_prefix_sharing", False),
-            use_paged_kernel=get("paged_kernel", False),
-            sweep_decode=not get("no_sweep", False),
             policy=get("policy", "fcfs"),
             prefill_chunk_tokens=get("prefill_chunk", 0),
             prefill_buckets=tuple(b for b in _BUCKET_LADDER

@@ -15,19 +15,16 @@ Two engines share the zoo's prefill/decode entry points:
     sharing a page-aligned prompt prefix share prompt pages, and cold pages
     ride UNLOAD/PRELOAD descriptors planned at the paper's d* distance.
 
-Decode runs one of two equivalent paths:
-
-  * **assembly** (default): each step's dense cache view is rebuilt from
-    pages (token r of slot b == packed row r) — optionally through the
-    page-indexed PUL gather (``use_pallas_gather=True``) — then decoded as
-    usual; greedy token streams match the dense reference bit-for-bit, the
-    invariant `tests/test_paged_serving.py` enforces. Kept as the oracle.
-  * **kernel-true** (``use_paged_kernel=True``): attention streams straight
-    over the page frames (`kernels.pul_paged_decode_attention`, or the MLA
-    variant over compressed pages), the page table acting as the preload
-    trace; the current token's K/V merges into the online softmax in-kernel
-    and is scattered into its tail page afterwards. No dense per-slot view
-    is ever materialized — the serving realization of the paper's claim.
+Decode streams attention straight over the page planes as ONE fused sweep
+(`kernels.pul_paged_sweep_decode_attention`, or its MLA variant over
+compressed pages): the layer scan carries every per-layer plane, the kernel
+picks its layer from an SMEM scalar and walks each slot's page table as the
+preload trace, and its epilogue commits the current token's K/V into the
+slot's tail page inside the same launch. The planes are donated to the
+jitted step, so the hot tier updates in place and no dense per-slot view of
+the KV ever exists. A model with no pageable KV (a pure state-space model)
+decodes its recurrent state in `resident` with the plain decode step; that
+choice follows from the model's cache layout, not from a flag.
 
 Greedy decode keeps one step in flight: the host dispatches step t+1 before
 it reads step t's tokens. Step t's argmax stays on the device and feeds step
@@ -299,19 +296,6 @@ class PagedEngineConfig:
     max_active_tokens: int = 0      # 0 -> slots * max_seq
     preload_distance: Optional[int] = None   # None -> planner d*
     share_prefix_pages: bool = True
-    use_pallas_gather: bool = False  # route page assembly through pul_gather
-    use_paged_kernel: bool = False   # kernel-true decode: attention streams
-                                     # straight over pages (no dense assembly);
-                                     # False keeps assemble-then-attend as the
-                                     # oracle path
-    sweep_decode: bool = True        # kernel-true decode as ONE sweep: the
-                                     # layer scan walks the full per-layer
-                                     # planes (zero-copy views), the sweep
-                                     # kernel selects its layer via an SMEM
-                                     # scalar and commits the new token's
-                                     # rows in its fused epilogue. False
-                                     # keeps the per-layer launch + eager
-                                     # write_rows scatter (parity baseline)
     policy: str = "fcfs"            # "fcfs" | "priority" | "slo-edf"
     prefill_chunk_tokens: int = 0   # >0: prompts longer than this prefill in
                                     # page-aligned chunks, one pass per tick,
@@ -433,10 +417,10 @@ class PagedServingEngine:
             tracer=self.tracer)
 
         # compiled entry points: one prefill per bucket, one decode; the
-        # kernel-true path binds the planner's d* as the in-kernel preload
-        # distance (static arg, so it is part of the compiled artifact).
-        # Each is a named function: the name is its program's name in a
-        # device profile ("jit_prefill", "jit_paged_decode", ...)
+        # sweep binds the planner's d* as the in-kernel preload distance
+        # (static arg, so it is part of the compiled artifact). Each is a
+        # named function: the name is its program's name in a device
+        # profile ("jit_prefill", "jit_sweep_paged_decode", ...)
         self._prefill_fns: Dict[int, Callable] = {}
         model = self.model
         d = max(1, min(self.pool.distance, self.pool.cfg.fifo_depth))
@@ -444,18 +428,15 @@ class PagedServingEngine:
         def decode(p, batch, tree):
             return model.decode_step(p, batch, tree)
 
-        def paged_decode(p, batch, tree):
-            return model.paged_decode_step(p, batch, tree, pul_distance=d)
-
         def sweep_paged_decode(p, batch, tree, planes):
             return model.paged_decode_step(p, batch, tree, planes,
                                            pul_distance=d)
 
+        # models with no pageable KV decode their recurrent state alone
         self._decode = jax.jit(decode)
-        self._paged_decode = jax.jit(paged_decode)
-        # single-sweep decode: planes ride as a donated argument so the
-        # fused in-kernel commit updates them in place (no copy of the
-        # store per step); returns (logits, new_tree, planes)
+        # the fused sweep: planes ride as a donated argument so the fused
+        # in-kernel commit updates them in place (no copy of the store per
+        # step); returns (logits, new_tree, planes)
         self._sweep_decode = jax.jit(sweep_paged_decode, donate_argnums=(3,))
 
         def next_tokens(logits, host_toks, from_host):
@@ -477,8 +458,7 @@ class PagedServingEngine:
         self.slot_pages: List[List[int]] = [[] for _ in range(B)]
         self.paused: List[bool] = [False] * B
         spec, _ = self.model.cache_specs(B, S)
-        self.resident = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), spec)
+        self.resident = self._resident_tree(spec)
         self.metrics = EngineMetrics()
         self.requests: Dict[int, Request] = {}
         self._rng = np.random.default_rng(engine_cfg.sample_seed)
@@ -499,6 +479,22 @@ class PagedServingEngine:
             _path_keys(path) not in pageable and _path_keys(path)[-1] != "idx"
             for path, _ in jax.tree_util.tree_flatten_with_path(spec)[0])
         self._prompt_logits: "OrderedDict[tuple, np.ndarray]" = OrderedDict()
+
+    def _resident_tree(self, spec):
+        """The decode cache tree the engine carries: zeros for every
+        non-pageable leaf (recurrent state, idx), and for every pageable
+        leaf a placeholder the sweep reads only the tree position of (the
+        KV lives in the pool's planes). Grouped placeholders keep a
+        leading layer axis so the backbone scan can slice them."""
+        pageable = {e.keys: e for e in self.layout.entries}
+
+        def leaf(path, s):
+            e = pageable.get(_path_keys(path))
+            if e is None:
+                return jnp.zeros(s.shape, s.dtype)
+            return jnp.zeros((e.shape[0], 1) if e.grouped else (1,), s.dtype)
+
+        return jax.tree_util.tree_map_with_path(leaf, spec)
 
     # ------------------------------------------------------------------ #
     def _prefill_for(self, bucket: int) -> Callable:
@@ -930,29 +926,6 @@ class PagedServingEngine:
     # ------------------------------------------------------------------ #
     # decode
     # ------------------------------------------------------------------ #
-    def _assemble(self) -> Any:
-        """Build the decode cache tree: pages -> dense token-indexed view."""
-        if not self.layout.features:
-            return _set_idx(self.resident, self.slot_len)
-        B, P = self.cfg.batch_slots, self.cfg.page_tokens
-        frames = np.full((B, self.n_pages_per_slot), ZERO_FRAME, np.int32)
-        for i in self._live_slots():
-            pids = self.slot_pages[i]
-            frames[i, :len(pids)] = self.pool.frames_of(pids)
-        store = self.pool.packed_store()
-        if self.cfg.use_pallas_gather:
-            from repro.kernels import pul_page_gather
-            from repro.core import PULConfig
-            d = min(self.pool.distance, self.pool.cfg.fifo_depth)
-            packed = pul_page_gather(
-                store, jnp.asarray(frames),
-                cfg=PULConfig(distance=max(1, d)))
-        else:
-            packed = store[jnp.asarray(frames)].reshape(
-                B, self.cfg.max_seq, -1)
-        tree = self.layout.unpack_into(self.resident, packed)
-        return _set_idx(tree, self.slot_len)
-
     def _ensure_tail_pages(self):
         """Every live slot needs a writable page for the incoming token.
         The step's whole working set is threaded into alloc so a tail-page
@@ -970,71 +943,41 @@ class PagedServingEngine:
                 self.slot_pages[i].append(pid)
                 working.add(pid)
 
-    def _sweep_cache_tree(self):
-        """Decode cache tree for the single-sweep path: pageable leaves are
-        tiny placeholders — the sweep branch reads only the tree POSITION
-        (the KV data rides in the donated planes), so no page view is ever
-        materialized into the tree. Grouped placeholders keep a leading
-        layer axis so the backbone scan can slice them; non-pageable leaves
-        (SSM state, idx) come from `resident` as usual."""
-        pageable = {e.keys: e for e in self.layout.entries}
-
-        def repl(path, leaf):
-            e = pageable.get(_path_keys(path))
-            if e is None:
-                return leaf
-            if e.grouped:
-                return jnp.zeros((e.shape[0], 1), leaf.dtype)
-            return jnp.zeros((1,), leaf.dtype)
-
-        return jax.tree_util.tree_map_with_path(repl, self.resident)
-
-    def _paged_kernel_decode(self, live, toks, pos0, frames, offs):
-        """Kernel-true decode: attention streams straight over page frames;
-        no dense per-slot KV view is assembled.
-
-        ``sweep_decode=True`` (default) runs ONE sweep: the layer scan
-        carries the full per-layer planes (``layer_view`` is zero-copy —
-        the plane IS the stored array), the kernel picks its layer via an
-        SMEM scalar, and its fused epilogue commits the current token's
-        rows into each slot's tail page inside the same launch. The planes
-        are donated to the jit call, so the hot tier updates in place.
-        ``sweep_decode=False`` keeps per-layer launches over per-layer
-        views, with the caller doing the eager write_rows scatter (the
-        parity baseline).
-
-        Returns (logits, new_tree); new_tree's pageable leaves hold only
-        the current token's rows."""
-        B = self.cfg.batch_slots
+    def _sweep(self, live, toks, pos0):
+        """Dispatch the fused sweep over the page planes: attention streams
+        straight over each slot's pages, and the kernel's epilogue commits
+        the current token's rows into each slot's tail page in the same
+        launch. The planes are donated to the jit call, so the hot tier
+        updates in place. Returns (logits, new_tree); new_tree's pageable
+        leaves are the placeholders it was given."""
+        B, P = self.cfg.batch_slots, self.cfg.page_tokens
         page_table = np.full((B, self.n_pages_per_slot), ZERO_FRAME, np.int32)
+        # tail-page commit coordinates for every slot (TRASH sink for slots
+        # not decoding); the fused commit needs them BEFORE launch
+        frames = np.full((B,), TRASH_FRAME, np.int32)
+        offs = np.zeros((B,), np.int32)
         for i in live:
             pids = self.slot_pages[i]
             page_table[i, :len(pids)] = self.pool.frames_of(pids)
-        if self.cfg.sweep_decode:
-            tree = _set_idx(self._sweep_cache_tree(), self.slot_len)
-            # account + lifecycle-trace the fused commit BEFORE the launch
-            # (events must precede the write they describe)
-            self.pool.note_fused_commit(frames, offs)
-            logits, new_tree, planes = self._sweep_decode(
-                self.params, {"tokens": toks,
-                              "pos0": jnp.asarray(pos0),
-                              "page_table": jnp.asarray(page_table),
-                              "frames": jnp.asarray(frames),
-                              "offsets": jnp.asarray(offs)},
-                tree, self.pool.planes)
-            self.pool.planes = planes
-            return logits, new_tree
-        tree = self.layout.page_view_tree(self.resident, self.pool.planes)
-        tree = _set_idx(tree, self.slot_len)
-        return self._paged_decode(
+            pos = int(self.slot_len[i])
+            frames[i] = self.pool.pages[pids[pos // P]].frame
+            offs[i] = pos % P
+        tree = _set_idx(self.resident, self.slot_len)
+        # account + lifecycle-trace the fused commit BEFORE the launch
+        # (events must precede the write they describe)
+        self.pool.note_fused_commit(frames, offs)
+        logits, new_tree, self.pool.planes = self._sweep_decode(
             self.params, {"tokens": toks,
                           "pos0": jnp.asarray(pos0),
-                          "page_table": jnp.asarray(page_table)}, tree)
+                          "page_table": jnp.asarray(page_table),
+                          "frames": jnp.asarray(frames),
+                          "offsets": jnp.asarray(offs)},
+            tree, self.pool.planes)
+        return logits, new_tree
 
     def _merge_nonpageable(self, new_tree):
-        """Fold a paged-decode step's NON-pageable outputs (SSM state, idx)
-        into the resident tree; pageable leaves (page views in, new-token
-        rows out) never live in `resident`."""
+        """Fold a decode step's NON-pageable outputs (SSM state, idx) into
+        the resident tree; its pageable leaves stay the placeholders."""
         pageable = {e.keys for e in self.layout.entries}
         flat, treedef = jax.tree_util.tree_flatten_with_path(self.resident)
         flat_new = dict(jax.tree_util.tree_flatten_with_path(new_tree)[0])
@@ -1048,9 +991,8 @@ class PagedServingEngine:
         """Pages the fused sweep streams this step for each layer and KV
         head, summed over the live slots: a slot of length n reads pages
         0 .. ceil(n / P) - 1 (a sliding-window layer reads fewer). 0 where
-        the engine runs no sweep."""
-        if not (self.cfg.use_paged_kernel and self.layout.features
-                and self.cfg.sweep_decode):
+        the model has no pageable KV, and so no sweep."""
+        if not self.layout.features:
             return 0
         P = self.cfg.page_tokens
         return sum(-(-int(self.slot_len[i]) // P) for i in live)
@@ -1148,9 +1090,11 @@ class PagedServingEngine:
 
     def _launch_decode(self, live: List[int],
                        prev: Optional[_InFlight] = None):
-        """Tail pages, restores, input tokens, page table and commit
-        coordinates, then the dispatch of the decode step; returns (page
-        faults, the step's logits on the device, its input tokens)."""
+        """Tail pages, restores, input tokens and positions, then the
+        dispatch of the decode step: the fused sweep where the model has
+        pageable KV, the plain decode of `resident` where it has none;
+        returns (page faults, the step's logits on the device, its input
+        tokens)."""
         B = self.cfg.batch_slots
         self._ensure_tail_pages()
         needed = sorted({pid for i in live for pid in self.slot_pages[i]})
@@ -1160,40 +1104,14 @@ class PagedServingEngine:
         pos0 = np.zeros((B,), np.int32)
         for i in live:
             pos0[i] = self.slot_len[i]
-        # tail-page commit coordinates for every slot this step (TRASH sink
-        # for slots not decoding); the fused sweep needs them BEFORE launch
-        P = self.cfg.page_tokens
-        frames = np.full((B,), TRASH_FRAME, np.int32)
-        offs = np.zeros((B,), np.int32)
         if self.layout.features:
-            for i in live:
-                pos = int(self.slot_len[i])
-                pid = self.slot_pages[i][pos // P]
-                frames[i] = self.pool.pages[pid].frame
-                offs[i] = pos % P
-        kernel_true = self.cfg.use_paged_kernel and self.layout.features
-        sweep = kernel_true and self.cfg.sweep_decode
-        if kernel_true:
-            logits, new_tree = self._paged_kernel_decode(
-                live, toks, pos0, frames, offs)
+            logits, new_tree = self._sweep(live, toks, pos0)
         else:
-            tree = self._assemble()
             logits, new_tree = self._decode(
-                self.params, {"tokens": toks,
-                              "pos0": jnp.asarray(pos0)}, tree)
+                self.params, {"tokens": toks, "pos0": jnp.asarray(pos0)},
+                _set_idx(self.resident, self.slot_len))
         self.metrics.decode_steps += 1
-
-        # write the step's new KV rows back into each live slot's tail page
-        # (the sweep already committed them in its fused epilogue)
-        if self.layout.features and not sweep:
-            rows = (self.layout._pack_new_rows_impl(new_tree) if kernel_true
-                    else self.layout.pack_rows(
-                        new_tree, jnp.asarray(self.slot_len.copy())))
-            self.pool.write_rows(frames, offs, rows)
-        if kernel_true:
-            self._merge_nonpageable(new_tree)
-        else:
-            self.resident = new_tree
+        self._merge_nonpageable(new_tree)
         return faults, logits, toks
 
     def _finishes(self, r: Request, n_out: int, length: int) -> bool:

@@ -16,25 +16,22 @@ vs per-page decode compute, and the restore batch is replayed through the
 discrete-event twin (`core.dma`) so the engine reports how much restore
 latency the schedule hides — the paper's claim, measured per serving step.
 
-Hot storage comes in two layouts, both behind the versioned
-:class:`KVStoreLayout` protocol (``KV_LAYOUT_VERSION``):
+Hot storage comes in two layouts:
 
-  * **per-layer planes** (v2, the kernel-true serving layout): each pageable
+  * **per-layer planes** (the serving layout, behind the versioned
+    :class:`KVStoreLayout` protocol, ``KV_LAYOUT_VERSION``): each pageable
     cache leaf owns a *plane* whose leading axis is the layer (scan-group)
     index — attention leaves are ``(L, NF, K, P, hd)``, MLA's compressed
     leaves ``(L, NF, P, kvr)``. A plane IS the page-frame layout the decode
-    kernels consume, so ``layer_view`` / ``page_view_tree`` are pure
-    indexing — zero-copy under jit, no gather, no transpose — and the
-    single-sweep decode kernel walks all layers of one plane with a
-    prefetched layer scalar. The current token's rows are committed either
-    *fused* (in the sweep kernel's epilogue, see
-    ``kernels.pul_paged_sweep_decode_attention``) or *eagerly* via
-    :meth:`KVStoreLayout.commit_token`.
-  * **packed rows** (v1, the portable/oracle layout): token t of a page is
-    one ``(F,)`` row concatenating every layer's features
-    (:class:`PackedKVLayout` ``pack``/``unpack``); kept for the dense
-    assembly oracle and for direct pool users (``KVPagePool(pcfg,
-    features=F)``).
+    sweep consumes, so ``layer_view`` is pure indexing — zero-copy under
+    jit, no gather, no transpose — and the sweep kernel walks all layers of
+    one plane with a prefetched layer scalar. The current token's rows are
+    committed in the sweep kernel's epilogue (see
+    ``kernels.pul_paged_sweep_decode_attention``).
+  * **packed rows**: token t of a page is one ``(F,)`` row concatenating
+    every layer's features (:class:`PackedKVLayout` ``pack``); the format
+    prefill pages are written in, and the store of direct pool users
+    (``KVPagePool(pcfg, features=F)``).
 
 The cold tier always holds packed ``(P, F)`` rows regardless of the hot
 layout, so UNLOAD/PRELOAD byte accounting, the DMA twin's KV-page workload,
@@ -48,7 +45,6 @@ full, immutable prompt pages are shared).
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -77,9 +73,9 @@ from repro.core.pul import (
 # matches max_seq): standard GQA attention and MLA's compressed cache
 _KV_LEAF_KEYS = ("k", "v", "c_kv", "k_rope")
 
-#: Version of the KV store-layout protocol. v1 was the ad-hoc
-#: ``page_views``/``pack_new_rows`` pair over a single packed store plane;
-#: v2 is the per-layer-plane :class:`KVStoreLayout` protocol below.
+#: Version of the KV store-layout protocol. v1 served decode from a single
+#: packed store plane; v2 is the per-layer-plane :class:`KVStoreLayout`
+#: protocol below.
 KV_LAYOUT_VERSION = 2
 
 
@@ -146,19 +142,13 @@ class KVStoreLayout:
         layer. **Zero-copy**: pure leading-axis indexing, no gather or
         transpose under jit (property-tested in
         ``tests/test_paged_sweep.py``).
-      * :meth:`page_view_tree` — a cache tree whose pageable leaves are
-        whole planes (grouped leaves keep their leading scan axis); the
-        per-layer decode kernels address it directly.
-      * :meth:`commit_token` — the *eager* commit: scatter one packed row
-        per slot into ``(frame, offset)``. The *fused* commit is the same
-        contract implemented in the sweep kernel's epilogue
-        (``kernels.pul_paged_sweep_decode_attention``); the pool accounts
-        it via :meth:`KVPagePool.note_fused_commit`.
       * :meth:`read_frame_packed` / :meth:`write_frame_packed` — bridge one
         frame to the packed ``(P, F)`` row layout the cold tier and DMA
         descriptors use (tier movement is layout-independent).
-      * :meth:`pack_planes` — materialize the packed ``(NF, P, F)`` store
-        (a copy; oracle/assembly path only).
+
+    The current token's rows are committed in the sweep kernel's epilogue
+    (``kernels.pul_paged_sweep_decode_attention``); the pool accounts that
+    commit via :meth:`KVPagePool.note_fused_commit`.
 
     ``features`` (the packed row width F) and ``entries`` describe the
     geometry; ``layout_version`` pins the protocol revision a layout
@@ -177,15 +167,6 @@ class KVStoreLayout:
                    layer: int) -> Dict[str, jnp.ndarray]:
         raise NotImplementedError
 
-    def page_view_tree(self, tree: Any,
-                       planes: Dict[str, jnp.ndarray]) -> Any:
-        raise NotImplementedError
-
-    def commit_token(self, planes: Dict[str, jnp.ndarray],
-                     rows: jnp.ndarray, frames, offsets,
-                     dtype) -> Dict[str, jnp.ndarray]:
-        raise NotImplementedError
-
     def read_frame_packed(self, planes: Dict[str, jnp.ndarray],
                           frame: int) -> np.ndarray:
         raise NotImplementedError
@@ -194,19 +175,15 @@ class KVStoreLayout:
                            rows, dtype) -> Dict[str, jnp.ndarray]:
         raise NotImplementedError
 
-    def pack_planes(self, planes: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        raise NotImplementedError
-
 
 class PackedKVLayout(KVStoreLayout):
     """Mapping between a model's cache tree and its paged KV store.
 
-    Implements :class:`KVStoreLayout` v2 (per-layer planes) and keeps the
-    v1 packed-row codec: token t of slot b occupies row (b, t) — the
-    concatenation over every pageable cache leaf of that token's features
-    (all layers, all kv heads). `pack`/`unpack` are pure jnp functions
-    (jit-able, shape-polymorphic in S so prefill buckets and the decode
-    max_seq share one layout).
+    Implements :class:`KVStoreLayout` v2 (per-layer planes) and the packed
+    row codec: token t of slot b occupies row (b, t) — the concatenation
+    over every pageable cache leaf of that token's features (all layers,
+    all kv heads). `pack` is a pure jnp function (jit-able,
+    shape-polymorphic in S so every prefill bucket shares one layout).
     """
 
     layout_version = KV_LAYOUT_VERSION
@@ -256,104 +233,6 @@ class PackedKVLayout(KVStoreLayout):
             [self._leaf_rows(self._get(tree, e.keys), e)
              for e in self.entries], axis=-1)
 
-    def pack_rows(self, tree: Any, idx: jnp.ndarray) -> jnp.ndarray:
-        """One row per slot: (B, F) at per-slot positions `idx` (B,)."""
-        B = idx.shape[0]
-        rows = jnp.arange(B)
-        outs = []
-        for e in self.entries:
-            leaf = self._get(tree, e.keys)
-            S = leaf.shape[2 if e.grouped else 1]
-            i = jnp.clip(idx, 0, S - 1)
-            if e.grouped:
-                x = jnp.moveaxis(leaf, 0, 2)        # (B, S, G, feat...)
-                outs.append(x[rows, i].reshape(B, -1))
-            else:
-                outs.append(leaf[rows, i].reshape(B, -1))
-        return jnp.concatenate(outs, axis=-1)
-
-    def _pack_new_rows_impl(self, tree: Any) -> jnp.ndarray:
-        outs = []
-        for e in self.entries:
-            leaf = self._get(tree, e.keys)
-            if e.grouped:
-                B = leaf.shape[1]
-                outs.append(jnp.moveaxis(leaf, 0, 1).reshape(B, -1))
-            else:
-                outs.append(leaf.reshape(leaf.shape[0], -1))
-        return jnp.concatenate(outs, axis=-1)
-
-    def pack_new_rows(self, tree: Any) -> jnp.ndarray:
-        """Deprecated v1 API: pack a paged-decode output tree's NEW-TOKEN
-        rows into (B, F) for an out-of-kernel scatter.
-
-        `tree` holds only the current token's features per pageable leaf —
-        grouped (G, B, feat...) or ungrouped (B, feat...) — in `pack` entry
-        order. Superseded by the :class:`KVStoreLayout` commit contract:
-        the sweep kernel commits rows in its fused epilogue
-        (`KVPagePool.note_fused_commit`) and the eager fallback is
-        :meth:`commit_token` / `KVPagePool.write_rows`."""
-        warnings.warn(
-            "PackedKVLayout.pack_new_rows is deprecated; the KVStoreLayout "
-            "protocol commits new-token rows fused (sweep-kernel epilogue) "
-            "or eagerly via commit_token/KVPagePool.write_rows",
-            PendingDeprecationWarning, stacklevel=2)
-        return self._pack_new_rows_impl(tree)
-
-    def _page_views_packed(self, tree: Any, store: jnp.ndarray) -> Any:
-        NP, P, _ = store.shape
-        new = jax.tree_util.tree_map(lambda x: x, tree)
-        for e in self.entries:
-            cols = store[:, :, e.offset:e.offset + e.nfeat]   # (NP, P, nfeat)
-            feat = e.feat
-            if e.grouped:
-                G = e.shape[0]
-                view = jnp.moveaxis(cols.reshape(NP, P, G, *feat), 2, 0)
-            else:
-                view = cols.reshape(NP, P, *feat)
-            if len(feat) == 2:              # (K, hd) -> pages (.., NP, K, P, hd)
-                view = jnp.swapaxes(view, -3, -2)
-            node = new
-            for k in e.keys[:-1]:
-                node = node[k]
-            node[e.keys[-1]] = view
-        return new
-
-    def page_views(self, tree: Any, store: jnp.ndarray) -> Any:
-        """Deprecated v1 API: slice a PACKED store ((NP, P, F)) into
-        per-layer kernel views — a gather/transpose under jit every step.
-
-        Superseded by :meth:`page_view_tree` over per-layer planes, where
-        the "view" is the stored array itself (zero-copy). Kept for one
-        release for direct packed-store users."""
-        warnings.warn(
-            "PackedKVLayout.page_views is deprecated; use the KVStoreLayout "
-            "protocol (page_view_tree/layer_view over per-layer planes, "
-            "which are zero-copy) instead",
-            PendingDeprecationWarning, stacklevel=2)
-        return self._page_views_packed(tree, store)
-
-    def unpack_into(self, tree: Any, packed: jnp.ndarray) -> Any:
-        """Return `tree` with every pageable leaf replaced from `packed`
-        ((B, S, F)); non-pageable leaves (SSM states, idx) pass through."""
-        B, S, _ = packed.shape
-        # tree_map rebuilds every container, so in-place edits below only
-        # touch the fresh copy, never the caller's tree
-        new = jax.tree_util.tree_map(lambda x: x, tree)
-        for e in self.entries:
-            cols = packed[..., e.offset:e.offset + e.nfeat]
-            if e.grouped:
-                G = e.shape[0]
-                feat = e.shape[3:]
-                leaf = jnp.moveaxis(cols.reshape(B, S, G, *feat), 2, 0)
-            else:
-                leaf = cols.reshape(B, S, *e.shape[2:])
-            node = new
-            for k in e.keys[:-1]:
-                node = node[k]
-            node[e.keys[-1]] = leaf.astype(self._get(tree, e.keys).dtype)
-        return new
-
     # ------------------------------------------------------------------ #
     # KVStoreLayout v2: per-layer planes
     # ------------------------------------------------------------------ #
@@ -382,50 +261,6 @@ class PackedKVLayout(KVStoreLayout):
         return {e.plane_key:
                 planes[e.plane_key][layer if e.layers > 1 else 0]
                 for e in self.entries}
-
-    def page_view_tree(self, tree: Any,
-                       planes: Dict[str, jnp.ndarray]) -> Any:
-        """Return `tree` with every pageable leaf replaced by its plane —
-        THE stored array, not a slice of one (grouped leaves keep their
-        leading scan axis; unscanned leaves drop their singleton layer
-        axis). This is what makes the kernel-true decode zero-copy: the
-        leaf the kernel addresses is the buffer the pool owns."""
-        new = jax.tree_util.tree_map(lambda x: x, tree)
-        for e in self.entries:
-            plane = planes[e.plane_key]
-            view = plane if e.grouped else plane[0]
-            node = new
-            for k in e.keys[:-1]:
-                node = node[k]
-            node[e.keys[-1]] = view
-        return new
-
-    def commit_token(self, planes: Dict[str, jnp.ndarray],
-                     rows: jnp.ndarray, frames, offsets,
-                     dtype) -> Dict[str, jnp.ndarray]:
-        """Eager commit: scatter one packed (F,) row per slot into its
-        (frame, offset) page position across every plane. The fused
-        equivalent runs in the sweep kernel's epilogue."""
-        frames = jnp.asarray(frames, jnp.int32)
-        offsets = jnp.asarray(offsets, jnp.int32)
-        B = rows.shape[0]
-        out = dict(planes)
-        for e in self.entries:
-            cols = rows[:, e.offset:e.offset + e.nfeat].astype(dtype)
-            plane = planes[e.plane_key]
-            feat = e.feat
-            if len(feat) == 2:
-                vals = cols.reshape(B, e.layers, *feat)       # (B, L, K, hd)
-                # advanced indices (frames @ axis 1, offsets @ axis 3) are
-                # separated by a slice, so the broadcast B axis leads
-                out[e.plane_key] = plane.at[
-                    :, frames, :, offsets, :feat[-1]].set(vals)
-            else:
-                vals = cols.reshape(B, e.layers, *feat)       # (B, L, f)
-                # adjacent advanced indices keep their position: (L, B, f)
-                out[e.plane_key] = plane.at[:, frames, offsets, :feat[-1]].set(
-                    jnp.moveaxis(vals, 0, 1))
-        return out
 
     def read_frame_packed(self, planes: Dict[str, jnp.ndarray],
                           frame: int) -> np.ndarray:
@@ -457,19 +292,6 @@ class PackedKVLayout(KVStoreLayout):
             out[e.plane_key] = planes[e.plane_key].at[
                 :, frame, ..., :feat[-1]].set(vals)
         return out
-
-    def pack_planes(self, planes: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        """Materialize the packed (NF, P, F) store from the planes — a
-        COPY; only the dense-assembly oracle path pays it."""
-        cols = []
-        for e in self.entries:
-            plane = planes[e.plane_key][..., :e.feat[-1]]
-            if len(e.feat) == 2:            # (L,NF,K,P,hd) -> (NF,P,L,K,hd)
-                sl = jnp.transpose(plane, (1, 3, 0, 2, 4))
-            else:                           # (L,NF,P,f) -> (NF,P,L,f)
-                sl = jnp.transpose(plane, (1, 2, 0, 3))
-            cols.append(sl.reshape(sl.shape[0], sl.shape[1], -1))
-        return jnp.concatenate(cols, axis=-1)
 
 
 # -------------------------------------------------------------------------- #
@@ -507,8 +329,7 @@ class PoolMetrics:
     modeled_restore_stall: float = 0.0  # PE stall within those batches
     # cache-economics counters (repro.obs.metrics.cache_economics):
     bytes_hot_written: int = 0  # bytes scattered into the hot store (prefill
-                                # page fills + decode row commits, fused or
-                                # eager)
+                                # page fills + the sweep's fused row commits)
     # prefetch-quality counters for planned d* restores (accuracy /
     # timeliness / coverage, per the prefetching survey in PAPERS.md):
     planned_preloads: int = 0   # restores issued through ensure_hot's
@@ -603,8 +424,7 @@ class KVPagePool:
       * ``KVPagePool(pcfg, layout=<KVStoreLayout>)`` — per-layer mode (v2):
         storage is ``pool.planes`` (one plane per pageable cache leaf; see
         :class:`KVStoreLayout`) and all data movement delegates to the
-        layout. The packed view, when the oracle path needs it, is
-        :meth:`packed_store`.
+        layout.
 
     Frame ids, page ids, refcounts, eviction order, DMA descriptors, and
     the lifecycle trace are identical across modes — a frame spans every
@@ -676,13 +496,6 @@ class KVPagePool:
     def hot_in_use(self) -> int:
         return sum(1 for m in self.pages.values() if m.frame is not None)
 
-    def packed_store(self) -> jnp.ndarray:
-        """The packed (NF, P, F) store: the array itself in packed mode, a
-        materialized copy of the planes in per-layer mode (oracle path)."""
-        if self.layout is not None:
-            return self.layout.pack_planes(self.planes)
-        return self.store
-
     # ------------------------------------------------------------------ #
     # layout-dispatched frame data movement (cold tier stays packed)
     # ------------------------------------------------------------------ #
@@ -698,15 +511,6 @@ class KVPagePool:
         else:
             self.store = self.store.at[frame].set(
                 jnp.asarray(rows).astype(self.dtype))
-
-    def _scatter_rows(self, frames, offsets, rows) -> None:
-        if self.layout is not None:
-            self.planes = self.layout.commit_token(
-                self.planes, rows, frames, offsets, self.dtype)
-        else:
-            self.store = self.store.at[
-                jnp.asarray(frames), jnp.asarray(offsets)].set(
-                    rows.astype(self.dtype))
 
     # ------------------------------------------------------------------ #
     def _emit(self, kind: EventKind, **fields) -> None:
@@ -909,32 +713,15 @@ class KVPagePool:
             self._write_frame(meta.frame, rows)
         self.metrics.bytes_hot_written += self.page_bytes
 
-    def write_rows(self, frames: np.ndarray, offsets: np.ndarray,
-                   rows: jnp.ndarray) -> None:
-        """Eagerly commit one packed row per slot into (frame, offset)
-        positions — the out-of-kernel half of the KVStoreLayout commit
-        contract (`commit_token`). Inactive slots should point at
-        TRASH_FRAME."""
-        # the event precedes validation so a zero-frame write reaches the
-        # sanitizer trace even though the assert stops the scatter
-        self._emit(EventKind.WRITE_ROWS,
-                   frames=tuple(int(f) for f in frames))
-        # validate BEFORE the scatter: the reserved zero frame backs every
-        # unallocated page-table slot and must stay all-zeros
-        assert ZERO_FRAME not in frames.tolist(), "write to the zero frame"
-        live = sum(1 for f in frames.tolist() if f != TRASH_FRAME)
-        self.metrics.bytes_hot_written += live * self.row_bytes
-        self._scatter_rows(frames, offsets, rows)
-
     def note_fused_commit(self, frames: np.ndarray,
                           offsets: np.ndarray) -> None:
         """Account a FUSED commit: the sweep kernel's epilogue scatters the
         current token's rows into the planes in-kernel (one write per
         layer), so no host-side scatter runs — only validation, byte
         accounting, and the lifecycle trace happen here. Call BEFORE the
-        kernel so the events precede the writes they describe (the same
-        order `write_rows` guarantees), and so a zero-frame table stops
-        the step before the kernel touches the reserved frame."""
+        kernel so the events precede the writes they describe, and so a
+        zero-frame table stops the step before the kernel touches the
+        reserved frame."""
         del offsets  # positions are per-layer-identical; frames identify pages
         for layer in range(self.n_layers):
             self._emit(EventKind.WRITE_ROWS, layer=layer,

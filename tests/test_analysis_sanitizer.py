@@ -157,7 +157,7 @@ def test_write_to_zero_frame_detected():
     pool = _pool()
     pool.alloc()
     with pytest.raises(AssertionError, match="zero frame"):
-        pool.write_rows(np.array([0]), np.array([0]), _rows())
+        pool.note_fused_commit(np.array([0]), np.array([0]))
     v = _only(check_page_trace(pool.trace), "write-to-non-hot-frame")
     assert v.event.kind is EventKind.WRITE_ROWS
     assert v.event.frames == (0,)
@@ -170,7 +170,7 @@ def test_write_to_unowned_frame_detected():
     pool = _pool()
     pool.alloc()                                    # occupies one frame
     free = pool.free_frames[0]                      # backs no hot page
-    pool.write_rows(np.array([free]), np.array([0]), _rows())  # pool accepts!
+    pool.note_fused_commit(np.array([free]), np.array([0]))   # pool accepts!
     v = _only(check_page_trace(pool.trace), "write-to-non-hot-frame")
     assert v.event.kind is EventKind.WRITE_ROWS
     assert f"frame {free}" in v.message
@@ -180,7 +180,7 @@ def test_trash_frame_writes_are_legal():
     pool = _pool()
     pid = pool.alloc()
     frame = int(pool.frames_of([pid])[0])
-    pool.write_rows(np.array([1, frame]), np.array([0, 0]), _rows(2))
+    pool.note_fused_commit(np.array([1, frame]), np.array([0, 0]))
     assert check_page_trace(pool.trace) == []
 
 

@@ -13,12 +13,12 @@ from repro.kernels import (
     pul_filter,
     pul_gather,
     pul_matmul,
-    pul_page_gather,
-    pul_paged_decode_attention,
-    pul_paged_mla_decode_attention,
+    pul_paged_sweep_decode_attention,
+    pul_paged_sweep_mla_decode_attention,
     pul_sum,
     ref,
 )
+from repro.serving.kv_pages import RESERVED_FRAMES, TRASH_FRAME
 
 pytestmark = pytest.mark.kernels
 
@@ -143,43 +143,60 @@ def test_gather_roundtrip_property(n, rows, d, seq):
 
 
 # --------------------------------------------------------------- paged paths
-@pytest.mark.parametrize("distance", [1, 4])
-@pytest.mark.parametrize("P", [8, 16])
-def test_pul_page_gather(distance, P):
-    """Page-table gather == store[page_table] (the serving assembly path)."""
-    NP, F = 12, 128
-    store = _rand(KEY, (NP, P, F), jnp.float32)
-    pt = jax.random.randint(jax.random.PRNGKey(11), (3, 4), 0, NP, jnp.int32)
-    got = pul_page_gather(store, pt, cfg=PULConfig(distance=distance))
-    want = store[pt].reshape(3, 4 * P, F)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+def _one_layer(*pages):
+    """Each (NF, ...) frame array as a one-layer plane (1, 2 + NF, ...)
+    behind the pool's reserved zero and trash frames."""
+    return [jnp.concatenate([jnp.zeros((RESERVED_FRAMES, *p.shape[1:]),
+                                       p.dtype), p])[None] for p in pages]
+
+
+def _trash_commits(B):
+    """Commit coordinates that send every slot's row to the trash frame."""
+    return (jnp.full((B,), TRASH_FRAME, jnp.int32),
+            jnp.zeros((B,), jnp.int32))
+
+
+def _gqa_sweep(q, kp, vp, pt, lengths, kn, vn, **kw):
+    """The GQA sweep over frames kp/vp of a one-layer plane."""
+    kpl, vpl = _one_layer(kp, vp)
+    return pul_paged_sweep_decode_attention(
+        q, kpl, vpl, 0, pt + RESERVED_FRAMES, lengths, kn, vn,
+        *_trash_commits(q.shape[0]), **kw)[0]
 
 
 @pytest.mark.parametrize("gqa", [1, 4])
 @pytest.mark.parametrize("distance", [1, 3])
-def test_pul_paged_decode_attention(gqa, distance):
+def test_pul_paged_sweep_decode_attention(gqa, distance):
     """Decode attention straight over scattered pages == dense oracle over
-    the assembled contiguous cache (mixed fill levels incl. partial pages)."""
+    the assembled contiguous cache, the current token at its position
+    (mixed fill levels incl. partial pages)."""
     B, K, P, npg, hd = 2, 2, 8, 4, 16
     H, S, NP = K * gqa, P * npg, 11
     kp = _rand(jax.random.PRNGKey(1), (NP, K, P, hd), jnp.float32) * 0.4
     vp = _rand(jax.random.PRNGKey(2), (NP, K, P, hd), jnp.float32)
+    kn = _rand(jax.random.PRNGKey(4), (B, K, hd), jnp.float32) * 0.4
+    vn = _rand(jax.random.PRNGKey(6), (B, K, hd), jnp.float32)
     pt = jnp.asarray(np.random.default_rng(0).permutation(NP)[:B * npg]
                      .reshape(B, npg), jnp.int32)
     q = _rand(jax.random.PRNGKey(3), (B, H, hd), jnp.float32) * 0.4
     lengths = jnp.asarray([S, S // 2 + 3], jnp.int32)
-    got = pul_paged_decode_attention(q, kp, vp, pt, lengths,
-                                     cfg=PULConfig(distance=distance))
-    kd = kp[pt].transpose(0, 2, 1, 3, 4).reshape(B, K, S, hd)
-    vd = vp[pt].transpose(0, 2, 1, 3, 4).reshape(B, K, S, hd)
-    want = ref.decode_attention_ref(q, kd, vd, lengths)
+    got = _gqa_sweep(q, kp, vp, pt, lengths, kn, vn,
+                     cfg=PULConfig(distance=distance))
+
+    def dense(pages, new):              # (B, K, S + 1, hd), new at length
+        d = pages[pt].transpose(0, 2, 1, 3, 4).reshape(B, K, S, hd)
+        d = jnp.pad(d, ((0, 0), (0, 0), (0, 1), (0, 0)))
+        return d.at[jnp.arange(B), :, lengths].set(new)
+    want = ref.decode_attention_ref(q, dense(kp, kn), dense(vp, vn),
+                                    lengths + 1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)
 
 
 @pytest.mark.parametrize("window", [None, 11, 24])
 @pytest.mark.parametrize("softcap", [None, 8.0])
-def test_pul_paged_decode_attention_window_and_self_merge(window, softcap):
+def test_pul_paged_sweep_decode_attention_window_and_self_merge(window,
+                                                                softcap):
     """Sliding-window masking + current-token (k_new, v_new) merge: the
     kernel over scattered pages == dense oracle over [assembled cache ;
     current token], with the window anchored at the query position."""
@@ -193,34 +210,17 @@ def test_pul_paged_decode_attention_window_and_self_merge(window, softcap):
     pt = jnp.asarray(np.random.default_rng(0).permutation(NP)[:B * npg]
                      .reshape(B, npg) % NP, jnp.int32)
     lengths = jnp.asarray([S - 2, 13], jnp.int32)
-    got = pul_paged_decode_attention(q, kp, vp, pt, lengths,
-                                     cfg=PULConfig(distance=2),
-                                     softcap=softcap, window=window,
-                                     k_new=kn, v_new=vn)
-    # oracle: assembled dense cache + current token appended at position len
-    kd = kp[pt].transpose(0, 2, 1, 3, 4).reshape(B, K, S, hd)
-    vd = vp[pt].transpose(0, 2, 1, 3, 4).reshape(B, K, S, hd)
-    kk = jnp.repeat(jnp.concatenate([kd, kn[:, :, None]], 2), gqa, 1)
-    vv = jnp.repeat(jnp.concatenate([vd, vn[:, :, None]], 2), gqa, 1)
-    logits = jnp.einsum("bhd,bhsd->bhs", q, kk) / (hd ** 0.5)
-    if softcap is not None:
-        logits = softcap * jnp.tanh(logits / softcap)
-    jk = jnp.arange(S + 1)[None, None, :]
-    L = lengths[:, None, None]
-    msk = (jk < L) | (jk == S)                    # cached rows + current token
-    if window is not None:
-        # query sits at absolute position L; the current token (logical
-        # position L, stored at column S) is always inside the window
-        msk &= (jk > L - window) | (jk == S)
-    logits = jnp.where(msk, logits, -2.0e38)
-    p = jax.nn.softmax(logits, axis=-1)
-    want = jnp.einsum("bhs,bhsd->bhd", p, vv)
+    got = _gqa_sweep(q, kp, vp, pt, lengths, kn, vn,
+                     cfg=PULConfig(distance=2), softcap=softcap,
+                     window=window)
+    want = ref.paged_decode_ref(q, kp, vp, pt, lengths, kn, vn,
+                                softcap=softcap, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)
 
 
 @pytest.mark.parametrize("distance", [1, 3])
-def test_pul_paged_mla_decode_attention(distance):
+def test_pul_paged_sweep_mla_decode_attention(distance):
     """Absorbed MLA decode over compressed-KV pages == dense oracle (the
     compressed cache doubles as the value stream), mixed fill levels."""
     B, H, kvr, dr, P, npg, NP = 2, 4, 32, 8, 8, 4, 11
@@ -236,23 +236,17 @@ def test_pul_paged_mla_decode_attention(distance):
                      .reshape(B, npg), jnp.int32)
     lengths = jnp.asarray([S, 11], jnp.int32)
     scale = 1.0 / (kvr + dr) ** 0.5
-    got = pul_paged_mla_decode_attention(qa, qr, cp, rp, pt, lengths, cn, rn,
-                                         scale=scale,
-                                         cfg=PULConfig(distance=distance))
-    cd = jnp.concatenate([cp[pt].reshape(B, S, kvr), cn[:, None]], 1)
-    rd = jnp.concatenate([rp[pt].reshape(B, S, dr), rn[:, None]], 1)
-    logits = (jnp.einsum("bhr,bsr->bhs", qa, cd)
-              + jnp.einsum("bhd,bsd->bhs", qr, rd)) * scale
-    jk = jnp.arange(S + 1)[None, None, :]
-    msk = (jk < lengths[:, None, None]) | (jk == S)
-    logits = jnp.where(msk, logits, -2.0e38)
-    p = jax.nn.softmax(logits, axis=-1)
-    want = jnp.einsum("bhs,bsr->bhr", p, cd)
+    got = pul_paged_sweep_mla_decode_attention(
+        qa, qr, *_one_layer(cp, rp), 0, pt + RESERVED_FRAMES, lengths, cn,
+        rn, *_trash_commits(B), scale=scale,
+        cfg=PULConfig(distance=distance))[0]
+    want = ref.paged_mla_decode_ref(qa, qr, cp, rp, pt, lengths, cn, rn,
+                                    scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)
 
 
-def test_pul_paged_decode_attention_empty_cache():
+def test_pul_paged_sweep_decode_attention_empty_cache():
     """length 0: only the current token is visible (empty-prompt decode)."""
     B, K, P, npg, hd = 1, 2, 8, 2, 16
     kp = _rand(jax.random.PRNGKey(1), (3, K, P, hd), jnp.float32)
@@ -261,8 +255,7 @@ def test_pul_paged_decode_attention_empty_cache():
     vn = _rand(jax.random.PRNGKey(4), (B, K, hd), jnp.float32)
     q = _rand(jax.random.PRNGKey(5), (B, K, hd), jnp.float32)
     pt = jnp.zeros((B, npg), jnp.int32)
-    got = pul_paged_decode_attention(q, kp, vp, pt, jnp.zeros((B,), jnp.int32),
-                                     k_new=kn, v_new=vn)
+    got = _gqa_sweep(q, kp, vp, pt, jnp.zeros((B,), jnp.int32), kn, vn)
     # softmax over a single visible position == v_new itself
     np.testing.assert_allclose(np.asarray(got), np.asarray(vn),
                                rtol=1e-5, atol=1e-5)

@@ -60,12 +60,11 @@ def _pick_bucket(buckets, n, max_seq=64):
     return max(max_seq, buckets[-1])
 
 
-def dense_reference(model, params, prompt, max_new, bucket, *, B, max_seq):
-    """Per-request greedy decode over a monolithic dense cache — the oracle.
-
-    Uses the same compiled shapes as the engine (batch B, right-padded
-    bucket prefill, per-slot idx), so row 0's math is bitwise identical and
-    token streams must match exactly."""
+def _dense_decode(model, params, prompt, max_new, bucket, *, B, max_seq,
+                  forced=None):
+    """Per-request decode over a monolithic dense cache: `max_new` tokens,
+    each the argmax of its logits or, with `forced`, forced's token there.
+    Returns (tokens, the logits of each)."""
     prompt = prompt[-bucket:]
     toks = np.zeros((B, bucket), np.int32)
     toks[0, :len(prompt)] = prompt
@@ -74,7 +73,14 @@ def dense_reference(model, params, prompt, max_new, bucket, *, B, max_seq):
     logits, caches = jax.jit(
         lambda p, b: model.prefill(p, b, max_seq=max_seq))(
         params, {"tokens": jnp.asarray(toks), "lengths": jnp.asarray(lengths)})
-    out = [int(np.argmax(np.asarray(logits)[0]))]
+    seen, out = [], []
+
+    def pick(logits):
+        seen.append(np.asarray(logits, np.float32)[0])
+        out.append(int(np.argmax(seen[-1])) if forced is None
+                   else forced[len(out)])
+
+    pick(logits)
     pos = np.zeros((B,), np.int32)
     pos[0] = len(prompt)
     caches = _set_idx(caches, pos)
@@ -86,14 +92,48 @@ def dense_reference(model, params, prompt, max_new, bucket, *, B, max_seq):
                                       "pos0": jnp.asarray(pos)}, caches)
         pos = pos + 1
         caches = _set_idx(caches, pos)
-        out.append(int(np.argmax(np.asarray(logits)[0])))
-    return out
+        pick(logits)
+    return out, seen
+
+
+def dense_reference(model, params, prompt, max_new, bucket, *, B, max_seq):
+    """Per-request greedy decode over a monolithic dense cache — the oracle.
+
+    Uses the same compiled shapes as the engine (batch B, right-padded
+    bucket prefill, per-slot idx), so row 0's math matches the engine's
+    up to the sweep kernel's rounding."""
+    return _dense_decode(model, params, prompt, max_new, bucket, B=B,
+                         max_seq=max_seq)[0]
+
+
+# the sweep's logits and the dense path's agree within this (the decode
+# parity tolerance below); two tokens whose logits tie within it may come
+# out in either order
+TIE = 0.05
+
+
+def assert_reference_stream(model, params, prompt, got, bucket, *, B,
+                            max_seq):
+    """`got` is the dense reference's greedy stream wherever the
+    reference's logits single out one token: decoding `got` itself on the
+    dense path, each of its tokens is the best there or ties with the best
+    within TIE."""
+    _, logits = _dense_decode(model, params, prompt, len(got), bucket, B=B,
+                              max_seq=max_seq, forced=got)
+    for k, ref in enumerate(logits):
+        below = float(ref.max() - ref[got[k]])
+        assert below <= TIE, (f"token {k} of {got}: {got[k]} is {below} "
+                              "below the reference's best")
 
 
 # --------------------------------------------------------------------------
 # differential: paged engine == dense reference
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("arch", ZOO_SUBSET)
+@pytest.mark.parametrize("arch", ZOO_SUBSET + (
+    "deepseek-v2-236b",     # MLA: the sweep over compressed-KV planes
+    "zamba2-7b",            # hybrid: the sweep plus recurrent `resident`
+    "rwkv6-7b",             # no pageable KV: the plain decode of `resident`
+))
 def test_paged_engine_matches_dense_reference(arch):
     """Mixed prompt lengths, more requests than slots (mid-stream refills):
     greedy token streams match the dense-cache reference exactly."""
@@ -109,6 +149,14 @@ def test_paged_engine_matches_dense_reference(arch):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
     got = eng.run()
     assert eng.metrics.prefills >= 3     # slots refilled mid-stream
+    # the sweep decoded wherever there is pageable KV, and `resident` holds
+    # no copy of it: each pageable leaf is a placeholder
+    assert (eng.metrics.sweep_pages > 0) == bool(eng.layout.features)
+    for e in eng.layout.entries:
+        leaf = eng.resident
+        for k in e.keys:
+            leaf = leaf[k]
+        assert leaf.size <= e.shape[0]
     for i, p in enumerate(prompts):
         want = dense_reference(model, params, p, 6,
                                _pick_bucket(buckets, len(p)),
@@ -310,8 +358,7 @@ def test_sampling_uses_model_distribution():
 
 
 # --------------------------------------------------------------------------
-# kernel-true paged decode (use_paged_kernel=True): attention streams
-# straight over page frames — no dense per-slot KV view is ever assembled
+# decode over the page planes: partial tail pages, window wrap, MLA
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("arch,sizes", [
     ("qwen3-1.7b", (3, 17, 8)),          # GQA + qk_norm
@@ -319,14 +366,14 @@ def test_sampling_uses_model_distribution():
     ("qwen2.5-32b", (3, 17, 8)),         # GQA + qkv bias
 ])
 def test_paged_kernel_decode_matches_dense_reference(arch, sizes):
-    """With use_paged_kernel=True, greedy token streams are identical to the
-    dense-cache reference: mixed prompt lengths, mid-stream slot refills,
-    partial tail pages, and window wrap (gemma2's 40 > window 16)."""
+    """Greedy token streams of the sweep over page planes are identical to
+    the dense-cache reference: mixed prompt lengths, mid-stream slot
+    refills, partial tail pages, and window wrap (gemma2's 40 > window
+    16)."""
     cfg, model, params = _model(arch)
     buckets = (8, 16, 32)
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(
-        batch_slots=2, max_seq=64, page_tokens=8, prefill_buckets=buckets,
-        use_paged_kernel=True))
+        batch_slots=2, max_seq=64, page_tokens=8, prefill_buckets=buckets))
     rng = np.random.default_rng(42)
     prompts = [rng.integers(1, cfg.vocab_size, size=int(n)).tolist()
                for n in sizes]
@@ -355,7 +402,7 @@ def test_paged_kernel_mla_matches_dense_reference():
             1, cfg.vocab_size, size=plen).tolist()
         eng = PagedServingEngine(cfg, params, PagedEngineConfig(
             batch_slots=2, max_seq=64, page_tokens=8,
-            prefill_buckets=buckets, use_paged_kernel=True))
+            prefill_buckets=buckets))
         eng.submit(Request(rid=0, prompt=list(p), max_new_tokens=10))
         got = eng.run()[0]
         want = dense_reference(model, params, p, 10,
@@ -363,15 +410,14 @@ def test_paged_kernel_mla_matches_dense_reference():
         assert got == want, f"len {plen}: {got} != {want}"
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_empty_prompt_admission(use_kernel):
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "deepseek-v2-236b"])
+def test_empty_prompt_admission(arch):
     """Empty-prompt requests admit cleanly (no pages at prefill, tail page
-    on the first decode step) and match the dense reference, on both the
-    assembly and the kernel-true decode paths."""
-    cfg, model, params = _model("qwen3-1.7b")
+    on the first decode step) and match the dense reference, on the GQA and
+    the MLA sweep."""
+    cfg, model, params = _model(arch)
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(
-        batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(8,),
-        use_paged_kernel=use_kernel))
+        batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(8,)))
     eng.submit(Request(rid=0, prompt=[], max_new_tokens=5))
     got = eng.run()[0]
     assert len(got) == 5
@@ -382,16 +428,16 @@ def test_empty_prompt_admission(use_kernel):
 # --------------------------------------------------------------------------
 # prefix-cache COMPUTE reuse: fully-shared prompts skip prefill entirely
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_fully_shared_prompt_skips_prefill_compute(use_kernel):
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "deepseek-v2-236b"])
+def test_fully_shared_prompt_skips_prefill_compute(arch):
     """A request whose whole (page-aligned) prompt is already resident as
     shared pages admits with ZERO prefill compute: the prefill counter is
     unchanged, the shared pages are ref'd, and the stream is identical to
-    an undisturbed solo run — on both decode paths."""
-    cfg, model, params = _model("qwen3-1.7b")
+    an undisturbed solo run — on the GQA and the MLA sweep."""
+    cfg, model, params = _model(arch)
     prompt = list(range(5, 21))                     # 16 tokens = 2 full pages
     ecfg = dict(batch_slots=2, max_seq=64, page_tokens=8,
-                prefill_buckets=(16,), use_paged_kernel=use_kernel)
+                prefill_buckets=(16,))
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(**ecfg))
     eng.submit(Request(rid=0, prompt=list(prompt), max_new_tokens=12))
     for _ in range(3):
@@ -501,16 +547,30 @@ def test_pool_alloc_preserves_step_working_set():
 
 
 def test_write_rows_validates_before_scatter():
-    """Regression: the zero-frame invariant is checked BEFORE the scatter —
-    a bad frame vector must leave the reserved all-zeros frame untouched."""
-    from repro.serving.kv_pages import KVPagePool, PageConfig, ZERO_FRAME
-    import jax.numpy as jnp
-    pool = KVPagePool(PageConfig(page_tokens=8, hot_frames=4), features=4)
-    with pytest.raises(AssertionError):
-        pool.write_rows(np.asarray([ZERO_FRAME], np.int32),
-                        np.asarray([0], np.int32),
-                        jnp.ones((1, 4), jnp.float32))
-    assert not np.asarray(pool.store[ZERO_FRAME]).any()   # still all-zeros
+    """Regression: the zero-frame invariant is checked BEFORE the row
+    write — a commit routed to the reserved all-zeros frame stops the step
+    before the sweep launches, so the frame stays all-zeros."""
+    from repro.serving.kv_pages import ZERO_FRAME
+    cfg, _, params = _model("qwen3-1.7b")
+    eng = PagedServingEngine(cfg, params, PagedEngineConfig(
+        batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,)))
+    launches, sweep = [], eng._sweep_decode
+
+    def counted(*args):
+        launches.append(1)
+        return sweep(*args)
+
+    eng._sweep_decode = counted
+    eng.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=4))
+    eng.step()                           # prefill, then one sweep
+    assert len(launches) == 1
+    (pid,) = eng.slot_pages[0]           # the tail page of the next commit
+    eng.pool.pages[pid].frame = ZERO_FRAME
+    with pytest.raises(AssertionError, match="zero frame"):
+        eng.step()
+    assert len(launches) == 1            # no launch behind the bad commit
+    for plane in eng.pool.planes.values():
+        assert not np.asarray(plane[:, ZERO_FRAME]).any()   # still zeros
 
 
 def test_sampling_differential_across_engines():
@@ -537,36 +597,8 @@ def test_sampling_differential_across_engines():
 
 
 # --------------------------------------------------------------------------
-# Pallas page-gather assembly path
-# --------------------------------------------------------------------------
-def test_pallas_page_gather_assembly_matches_default():
-    cfg, model, params = _model("qwen3-1.7b")
-    prompt = list(range(3, 15))
-    outs = []
-    for use_pallas in (False, True):
-        eng = PagedServingEngine(cfg, params, PagedEngineConfig(
-            batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,),
-            use_pallas_gather=use_pallas))
-        eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=4))
-        outs.append(eng.run()[0])
-    assert outs[0] == outs[1]
-
-
-# --------------------------------------------------------------------------
 # one decode step in flight: greedy tokens stay on the device
 # --------------------------------------------------------------------------
-_REFS = {}
-
-
-def _ref(model, params, prompt, max_new, bucket, *, B, max_seq):
-    """dense_reference, memoized across the in-flight cases."""
-    key = (model.cfg.name, tuple(prompt), max_new, bucket, B, max_seq)
-    if key not in _REFS:
-        _REFS[key] = dense_reference(model, params, prompt, max_new, bucket,
-                                     B=B, max_seq=max_seq)
-    return _REFS[key]
-
-
 def _inflight_case(case, vocab):
     """(engine knobs, [(rid, prompt, max_new, priority, submit tick)], a
     check of the engine after the run) for one in-flight scenario."""
@@ -580,7 +612,9 @@ def _inflight_case(case, vocab):
                 (2, list(shared), 5, 0, 2), (3, draw(62, 3), 6, 0, 4)]
 
         def check(eng):
-            assert eng.metrics.prefill_skips >= 1
+            # pages cannot rebuild recurrent state: such a model never
+            # skips a prefill
+            assert (eng.metrics.prefill_skips >= 1) != eng._has_recurrent
             assert eng.pool.metrics.shared_hits > 0
         return dict(max_seq=64), reqs, check
     if case == "max_seq":
@@ -610,22 +644,23 @@ def _inflight_case(case, vocab):
     return dict(max_seq=64, prefill_chunk_tokens=8), reqs, check
 
 
-@pytest.mark.parametrize("use_kernel", [False, True],
-                         ids=["default", "kernel-sweep"])
+# the sweep alone, and the sweep beside recurrent state in `resident` (the
+# MLA one comes with MoE, whose capacity dispatch makes a stream depend on
+# its batch-mates)
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "zamba2-7b"])
 @pytest.mark.parametrize("case", ["admissions", "max_seq", "preempt",
                                   "chunked"])
-def test_inflight_greedy_matches_dense_reference(case, use_kernel):
+def test_inflight_greedy_matches_dense_reference(case, arch):
     """With one decode step in flight (the host dispatches step t+1 before
     it reads step t's tokens), greedy streams equal the dense reference
-    token for token: admissions mid-run (shared-prefix and fully shared
-    ones included), finishes at max_new_tokens and at max_seq, a policy
+    token for token: admissions mid-run (shared-prefix and fully shared ones
+    included), finishes at max_new_tokens and at max_seq, a policy
     preemption through the cold tier and back, and chunked prefill."""
-    cfg, model, params = _model("qwen3-1.7b")
+    cfg, model, params = _model(arch)
     buckets = (8, 16, 32)
     knobs, reqs, check = _inflight_case(case, cfg.vocab_size)
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(
-        batch_slots=2, page_tokens=8, prefill_buckets=buckets,
-        use_paged_kernel=use_kernel, **knobs))
+        batch_slots=2, page_tokens=8, prefill_buckets=buckets, **knobs))
     tick = 0
     for when in sorted({r[4] for r in reqs}):
         while tick < when:
@@ -642,9 +677,10 @@ def test_inflight_greedy_matches_dense_reference(case, use_kernel):
                                         + eng.metrics.settles)
     assert all(r is None for r in eng.slot_req) and not eng.pool.pages
     for rid, prompt, new, _, _ in reqs:
-        want = _ref(model, params, prompt, new,
-                    _pick_bucket(buckets, len(prompt), knobs["max_seq"]),
-                    B=2, max_seq=knobs["max_seq"])
+        want = dense_reference(
+            model, params, prompt, new,
+            _pick_bucket(buckets, len(prompt), knobs["max_seq"]),
+            B=2, max_seq=knobs["max_seq"])
         assert got[rid] == want, f"{case} rid {rid}: {got[rid]} != {want}"
 
 
@@ -702,14 +738,14 @@ def test_inflight_counters_and_settles():
     assert m.inflight_steps == m.decode_steps - m.settles
 
 
-@pytest.mark.parametrize("use_kernel", [False, True],
-                         ids=["default", "kernel-sweep"])
-def test_greedy_decode_brings_no_logits_to_the_host(monkeypatch, use_kernel):
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "rwkv6-7b"])
+def test_greedy_decode_brings_no_logits_to_the_host(monkeypatch, arch):
     """On the greedy path every array the engine brings to the host during
     decode is a (B, 1) int32 token array; no (B, V) logits ever leave the
-    device. The sampling path, which draws on the host, copies them."""
+    device, whether the step is the sweep or (no pageable KV) the plain
+    decode. The sampling path, which draws on the host, copies them."""
     from repro.serving import engine as engine_mod
-    cfg, _, params = _model("qwen3-1.7b")
+    cfg, _, params = _model(arch)
     fetched = []
 
     class Recorder:
@@ -728,7 +764,7 @@ def test_greedy_decode_brings_no_logits_to_the_host(monkeypatch, use_kernel):
         fetched.clear()
         eng = PagedServingEngine(cfg, params, PagedEngineConfig(
             batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,),
-            use_paged_kernel=use_kernel, greedy=greedy))
+            greedy=greedy))
         eng.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=6))
         eng.submit(Request(rid=1, prompt=[2, 7, 1, 8], max_new_tokens=4))
         eng.run()
@@ -741,22 +777,28 @@ def test_greedy_decode_brings_no_logits_to_the_host(monkeypatch, use_kernel):
             assert len(full) == 5
 
 
-@pytest.mark.parametrize("use_kernel", [False, True],
-                         ids=["default", "kernel-sweep"])
-def test_sampled_decode_draws_as_before(use_kernel):
+# the streams the sweep drew before greedy decode kept a step in flight
+_SAMPLED = {
+    "qwen3-1.7b": {0: [21, 205, 23, 121, 187, 100, 108, 188],
+                   1: [56, 147, 110, 38, 27, 130, 149, 245],
+                   2: [73, 168, 179, 78, 0]},
+    "deepseek-v2-236b": {0: [20, 205, 23, 122, 185, 99, 109, 187],
+                         1: [60, 149, 110, 41, 29, 131, 149, 244],
+                         2: [70, 165, 176, 74, 0]},
+}
+
+
+@pytest.mark.parametrize("arch", sorted(_SAMPLED))
+def test_sampled_decode_draws_as_before(arch):
     """greedy=False keeps the serial loop: a fixed seed draws the streams
     the engine drew before greedy decode kept a step in flight."""
-    cfg, _, params = _model("qwen3-1.7b")
+    cfg, _, params = _model(arch)
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(
         batch_slots=2, max_seq=32, page_tokens=8, prefill_buckets=(16,),
-        greedy=False, sample_seed=3, use_paged_kernel=use_kernel))
+        greedy=False, sample_seed=3))
     eng.submit(Request(rid=0, prompt=[3, 1, 4, 1, 5], max_new_tokens=8))
     eng.submit(Request(rid=1, prompt=[2, 7, 1, 8], max_new_tokens=8))
     eng.step()
     eng.submit(Request(rid=2, prompt=[9, 9, 8], max_new_tokens=5))
-    got = eng.run()
-    assert got == {0: [21, 205, 23, 121, 187, 100, 108, 188],
-                   1: [56, 147, 110, 38, 27, 130,
-                       149 if use_kernel else 150, 245],
-                   2: [73, 168, 179, 78, 0]}
+    assert eng.run() == _SAMPLED[arch]
     assert eng.metrics.inflight_steps == eng.metrics.settles == 0

@@ -339,7 +339,7 @@ def test_priority_preemption_mla_single_slot():
     buckets = (8, 16, 32)
     eng = PagedServingEngine(cfg, params, PagedEngineConfig(
         batch_slots=1, max_seq=64, page_tokens=8, prefill_buckets=buckets,
-        policy="priority", use_paged_kernel=True))
+        policy="priority"))
     low = _rand_prompt(0, 13, cfg.vocab_size)
     eng.submit(Request(rid=0, prompt=list(low), max_new_tokens=10,
                        priority=0))

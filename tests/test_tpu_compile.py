@@ -5,8 +5,10 @@ not tile-aligned, a 64-lane rope stream), so every CPU test can pass while
 the chip cannot run the kernel. These tests compile with `interpret=False`
 against a described (not attached) v5e, from shapes alone:
 
-  * the GQA kernels at qwen3-1.7b widths (H=16, K=8, hd=128, 28 layers);
-  * the MLA kernels at deepseek-v2-236b widths (H=128, kvr=512, dr=64);
+  * the GQA sweep at qwen3-1.7b widths (H=16, K=8, hd=128, 28 layers), at
+    qwen2.5-32b's (five query heads per KV head) and on gemma2-27b's
+    softcapped sliding-window layers;
+  * the MLA sweep at deepseek-v2-236b widths (H=128, kvr=512, dr=64);
   * the whole full-width qwen3-1.7b sweep decode step the engine jits.
 
 Plane shapes come from `PackedKVLayout`, so the lane padding of the store
@@ -25,8 +27,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import PULConfig
 from repro.kernels import (
-    pul_paged_decode_attention,
-    pul_paged_mla_decode_attention,
     pul_paged_sweep_decode_attention,
     pul_paged_sweep_mla_decode_attention,
 )
@@ -93,16 +93,20 @@ def test_gqa_sweep_kernel_compiles(spec, window):
              spec((B, K, hd), BF16), vec, vec)
 
 
-def test_gqa_per_layer_kernel_compiles(spec):
-    cfg, (kp, _) = _planes("qwen3-1.7b", spec)
+@pytest.mark.parametrize("arch", ["qwen2.5-32b", "gemma2-27b"])
+def test_gqa_sweep_kernel_compiles_at_published_widths(spec, arch):
+    """qwen2.5-32b's five query heads per KV head, and gemma2-27b's
+    softcapped sliding-window layers (their planes sort first)."""
+    cfg, (kp, vp, *_) = _planes(arch, spec)
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    pages = spec(kp.shape[1:], BF16)
-    new = spec((B, K, hd), BF16)
-    _compile(lambda q, k, v, pt, ln, kn, vn: pul_paged_decode_attention(
-                 q, k, v, pt, ln, k_new=kn, v_new=vn, cfg=PUL,
-                 interpret=False),
-             spec((B, H, hd), BF16), pages, pages,
-             spec((B, N_PAGES), I32), spec((B,), I32), new, new)
+    assert kp.shape[1:] == (NF, K, P, hd)
+    vec = spec((B,), I32)
+    _compile(functools.partial(pul_paged_sweep_decode_attention, cfg=PUL,
+                               softcap=cfg.attn_softcap,
+                               window=cfg.sliding_window, interpret=False),
+             spec((B, H, hd), BF16), kp, vp, spec((), I32),
+             spec((B, N_PAGES), I32), vec, spec((B, K, hd), BF16),
+             spec((B, K, hd), BF16), vec, vec)
 
 
 def test_mla_sweep_kernel_compiles(spec):
@@ -118,17 +122,6 @@ def test_mla_sweep_kernel_compiles(spec):
              spec((B, kvr), BF16), spec((B, dr), BF16), vec, vec)
 
 
-def test_mla_per_layer_kernel_compiles(spec):
-    cfg, planes = _planes("deepseek-v2-236b", spec)
-    H, kvr, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    cp, rp = (spec(p.shape[1:], BF16) for p in planes if p.shape[0] > 1)
-    _compile(functools.partial(pul_paged_mla_decode_attention, scale=0.07,
-                               cfg=PUL, interpret=False),
-             spec((B, H, kvr), BF16), spec((B, H, dr), BF16), cp, rp,
-             spec((B, N_PAGES), I32), spec((B,), I32),
-             spec((B, kvr), BF16), spec((B, dr), BF16))
-
-
 def test_full_width_sweep_decode_step_compiles_in_place(spec, monkeypatch):
     """The engine's jitted single-sweep step for full qwen3-1.7b: the
     kernel is in the program and the donated planes are updated in place
@@ -141,8 +134,8 @@ def test_full_width_sweep_decode_step_compiles_in_place(spec, monkeypatch):
     params = jax.tree.map(lambda s: spec(s.shape, s.dtype),
                           model.abstract_params())
     cache, _ = model.cache_specs(B, MAX_SEQ)
-    # pageable leaves are placeholders on the sweep path (engine
-    # `_sweep_cache_tree`); only idx is read
+    # pageable leaves are placeholders (the engine's `resident`); only idx
+    # is read
     tree = jax.tree.map(
         lambda s: spec((s.shape[0], 1) if s.ndim > 2 else s.shape, s.dtype),
         cache)
